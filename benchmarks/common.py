@@ -72,6 +72,26 @@ def build_cluster(num_nodes: int, table: Table, *, rows_per_file: int,
     return fs
 
 
+def bytes_identical(a: Table, b: Table) -> bool:
+    """Bit-exact table equality (stricter than Table.equals): names,
+    dtypes, value bits (strings by value) and validity, column by
+    column."""
+    if a.schema.names != b.schema.names or len(a) != len(b):
+        return False
+    for ca, cb in zip(a.columns, b.columns):
+        if ca.field.type == "string":
+            if list(map(str, ca.values)) != list(map(str, cb.values)):
+                return False
+        elif (ca.values.dtype != cb.values.dtype
+              or ca.values.tobytes() != cb.values.tobytes()):
+            return False
+        if (ca.validity is None) != (cb.validity is None) or (
+                ca.validity is not None
+                and not np.array_equal(ca.validity, cb.validity)):
+            return False
+    return True
+
+
 def save_result(name: str, payload: dict):
     os.makedirs(RESULTS_DIR, exist_ok=True)
     with open(os.path.join(RESULTS_DIR, f"{name}.json"), "w") as f:

@@ -25,12 +25,12 @@ from __future__ import annotations
 
 import time
 
-from benchmarks.common import (build_cluster, save_result,
+from benchmarks.common import (build_cluster, bytes_identical, save_result,
                                selectivity_predicate, taxi_like_table)
 from repro.aformat.decode import NumPyBackend, PallasBackend
 from repro.dataset import AdaptiveFormat, dataset
 from repro.dataset.scheduler import ScanScheduler
-from repro.launch.mesh import HBM_BW
+from repro.launch.mesh import TARGET_KIND, device_peaks
 
 ROWS = 100_000
 ROWS_PER_FILE = 4_096
@@ -45,24 +45,10 @@ TENANT_SWEEP = (0, 1, 3, 7, 15, 31, 63, 127)
 # Roofline for the kernel decode path (stored bytes -> decoded values on
 # an accelerator): per stored DICT code the gather reads 4 B (code) +
 # writes 4 B (value) and the fused predicate + pack re-read ~8 B more —
-# call it 4x HBM traffic per stored byte.  v5e HBM at ``HBM_BW`` then
-# sustains HBM_BW/4 stored bytes per second; the shipped prior is ~50x
+# call it 4x HBM traffic per stored byte.  v5e HBM then sustains a
+# quarter of its bandwidth in stored bytes per second; the shipped prior is ~50x
 # under that (kernel-launch, padding, and host-staging slack).
-MODELED_PALLAS_RATE = HBM_BW / 4
-
-
-def _identical(a, b) -> bool:
-    """Bit-exact table equality (stricter than Table.equals)."""
-    if a.schema.names != b.schema.names or len(a) != len(b):
-        return False
-    for ca, cb in zip(a.columns, b.columns):
-        if ca.field.type == "string":
-            if list(map(str, ca.values)) != list(map(str, cb.values)):
-                return False
-        elif (ca.values.dtype != cb.values.dtype
-              or ca.values.tobytes() != cb.values.tobytes()):
-            return False
-    return True
+MODELED_PALLAS_RATE = device_peaks(TARGET_KIND).hbm_bw / 4
 
 
 def _timed_scan(ds, backend, predicate):
@@ -90,7 +76,7 @@ def run() -> dict:
     for name, p in (("full", None), ("selective", pred)):
         t_np, w_np, in_bytes, cpu_np = _timed_scan(ds, "numpy", p)
         t_pl, w_pl, _, _ = _timed_scan(ds, "pallas", p)
-        same = _identical(t_np, t_pl)
+        same = bytes_identical(t_np, t_pl)
         identical &= same
         cells.append({"scan": name, "rows_out": len(t_np),
                       "identical": same,

@@ -20,7 +20,11 @@ from repro.kernels.dict_decode.ops import decode_dictionary
 from repro.kernels.predicate_fused.ops import build_program, fused_predicate
 from repro.kernels.token_pack.ops import pack_tokens
 
-from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.launch.mesh import TARGET_KIND, device_peaks
+
+# modeled rooflines of the target chip (this script times no device)
+_PEAKS = device_peaks(TARGET_KIND)
+HBM_BW, PEAK_FLOPS_BF16 = _PEAKS.hbm_bw, _PEAKS.bf16_flops
 
 
 def _time(fn, *a, reps=3):

@@ -15,6 +15,7 @@ from benchmarks import (adaptive_scan, compaction, decode_backend,
                         encoding_advisor, fig5_latency_scaling,
                         fig6_cpu_utilization, ingest_train, kernel_bench,
                         layout_compare, multi_tenant, semi_join)
+from repro.launch.compile_cache import use_compile_cache
 
 BENCHES = {
     "fig5": fig5_latency_scaling.main,
@@ -33,6 +34,7 @@ BENCHES = {
 
 def main() -> int:
     names = sys.argv[1:] or list(BENCHES)
+    use_compile_cache()
     failed = []
     for name in names:
         print(f"\n=== {name} " + "=" * (68 - len(name)), flush=True)

@@ -5,25 +5,25 @@ Walks the four recovery mechanisms end to end on the simulated cluster:
   2. a straggling OSD -> hedged scan beats the tail;
   3. training state restored from object-store checkpoints after a crash;
   4. elastic downsize: lose half the fleet, re-mesh, keep training
-     (runs in a subprocess with 8 simulated devices).
+     (on 8 virtual CPU devices, in this same process).
 
     PYTHONPATH=src python examples/fault_tolerance.py
 """
 
-import subprocess
-import sys
-import textwrap
-import time
+import os
 
-import jax
-import jax.numpy as jnp
-import numpy as np
+# 8 virtual CPU devices for the elastic demo; must precede the jax import
+os.environ["XLA_FLAGS"] = " ".join(filter(None, (
+    os.environ.get("XLA_FLAGS"), "--xla_force_host_platform_device_count=8")))
 
-from repro.aformat.expressions import field
-from repro.core import dataset, make_cluster
-from repro.data import synth_corpus, write_corpus
-from repro.dataset import PushdownParquetFormat
-from repro.distrib import CheckpointManager, HealthMonitor
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+import numpy as np  # noqa: E402
+
+from repro.core import dataset, make_cluster  # noqa: E402
+from repro.data import synth_corpus, write_corpus  # noqa: E402
+from repro.dataset import PushdownParquetFormat  # noqa: E402
+from repro.distrib import CheckpointManager, HealthMonitor  # noqa: E402
 
 
 def demo_failover():
@@ -80,29 +80,22 @@ def demo_checkpoint_restore():
 
 def demo_elastic():
     print("=== 4. Elastic downsize: 8 devices -> lose 4 -> re-mesh ===")
-    code = textwrap.dedent("""
-        import os
-        os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-        import jax, jax.numpy as jnp, numpy as np
-        from repro.distrib import elastic_downsize
-        from repro.sharding import default_rules, tree_shardings
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
-        rules = default_rules()
-        state = {"w": jnp.arange(4096.0).reshape(64, 64)}
-        specs = {"w": ("embed", "mlp")}
-        state = jax.device_put(state, tree_shardings(mesh, rules, state, specs))
-        new_mesh, new_state, plan = elastic_downsize(
-            state, specs, mesh, rules, list(jax.devices())[:4])
-        assert np.array_equal(np.asarray(new_state["w"]),
-                              np.arange(4096.0).reshape(64, 64))
-        print(f"  mesh {plan.old_shape} -> {plan.new_shape}, "
-              f"state bitwise intact on {plan.devices_kept} devices")
-    """)
-    out = subprocess.run([sys.executable, "-c", code],
-                         capture_output=True, text=True,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                         cwd=".")
-    print(out.stdout or out.stderr)
+    from repro.distrib import elastic_downsize
+    from repro.launch.mesh import make_mesh
+    from repro.sharding import default_rules, tree_shardings
+
+    cpus = jax.devices("cpu")
+    mesh = make_mesh((4, 2), ("data", "model"), devices=cpus[:8])
+    rules = default_rules()
+    state = {"w": np.arange(4096.0, dtype=np.float32).reshape(64, 64)}
+    specs = {"w": ("embed", "mlp")}
+    state = jax.device_put(state, tree_shardings(mesh, rules, state, specs))
+    new_mesh, new_state, plan = elastic_downsize(
+        state, specs, mesh, rules, cpus[:4])
+    assert np.array_equal(np.asarray(new_state["w"]),
+                          np.arange(4096.0).reshape(64, 64))
+    print(f"  mesh {plan.old_shape} -> {plan.new_shape}, "
+          f"state bitwise intact on {plan.devices_kept} devices")
 
 
 if __name__ == "__main__":

@@ -19,11 +19,13 @@ comes out.  Two backends implement that contract:
     ``pack_tokens``.  Everything the kernels cannot express — RLE/DELTA
     byte streams, strings, float64, integers outside the f32-exact
     domain, IsIn/Bloom/mixed-logic expression nodes — falls back
-    per-column / per-predicate to the host path.  Off-accelerator the
-    kernels run ``interpret=True`` (see ``repro.kernels.*.ops``), so the
-    two backends are byte-identical everywhere; ``tests/test_decode.py``
-    pins that equivalence across the encoding x dtype x validity x
-    predicate grid.
+    per-column / per-predicate to the host path.  The kernels compile
+    for the TPU; on the CPU they run in Pallas's interpreter, which
+    exists for tests and rehearsals and is no measure of speed.  Any
+    other platform raises (``repro.kernels.platform``).  Every route
+    is bit-exact, so the two backends are byte-identical;
+    ``tests/test_decode.py`` pins that equivalence across the encoding
+    x dtype x validity x predicate grid.
 
 The scheduler prices the two regimes separately: each backend carries a
 ``decode_rate_prior`` (stored bytes per second of decode+filter) that
@@ -267,13 +269,11 @@ class PallasBackend(DecodeBackend):
                     buf[1:], chunk.num_rows, buf[0]).astype(np.int32)
             dic = np.frombuffer(chunk.data_bufs[1],
                                 chunk.field.numpy_dtype)
-            try:
-                # raises ValueError when an int dictionary leaves the
-                # f32-exact domain — exactly the host-fallback condition
+            # an int dictionary outside the f32-exact domain is the
+            # host-fallback condition; a kernel error propagates
+            if _f32_exact_values(dic):
                 values = np.asarray(decode_dictionary(codes, dic))
                 route = "kernel"
-            except ValueError:
-                values = None
         if values is None:
             values = encodings.decode(chunk.field.type, chunk.encoding,
                                       chunk.data_bufs, chunk.num_rows,

@@ -1,7 +1,5 @@
 from repro.data.corpus import CORPUS_SCHEMA, synth_corpus, write_corpus
-from repro.data.pipeline import (PipelineConfig, Prefetcher, TokenPipeline,
-                                 device_put_batch)
+from repro.data.pipeline import PipelineConfig, Prefetcher, TokenPipeline
 
 __all__ = ["CORPUS_SCHEMA", "synth_corpus", "write_corpus",
-           "PipelineConfig", "Prefetcher", "TokenPipeline",
-           "device_put_batch"]
+           "PipelineConfig", "Prefetcher", "TokenPipeline"]

@@ -89,17 +89,3 @@ class TokenPipeline:
         return {k: d[k] for k in ("fragments_scanned", "client_cpu_s",
                                   "osd_cpu_s", "wire_bytes", "rows")}
 
-
-def device_put_batch(batch: dict[str, np.ndarray], mesh, rules):
-    """Host batch -> sharded global jax.Arrays (batch over (pod, data))."""
-    import jax
-    from jax.sharding import NamedSharding
-
-    from repro.sharding import resolve_spec
-
-    out = {}
-    for k, v in batch.items():
-        logical = ("batch",) + (None,) * (v.ndim - 1)
-        sh = NamedSharding(mesh, resolve_spec(mesh, rules, logical, v.shape))
-        out[k] = jax.device_put(v, sh)
-    return out

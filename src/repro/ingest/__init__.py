@@ -1,6 +1,6 @@
-from repro.ingest.reader import (Prefetcher, ReaderConfig, ShardedReader,
-                                 epoch_order, reshard_states)
+from repro.ingest.reader import (MeshReader, Prefetcher, ReaderConfig,
+                                 ShardedReader, epoch_order, reshard_states)
 from repro.ingest.state import STATE_VERSION, ReaderState
 
-__all__ = ["Prefetcher", "ReaderConfig", "ReaderState", "STATE_VERSION",
+__all__ = ["MeshReader", "Prefetcher", "ReaderConfig", "ReaderState", "STATE_VERSION",
            "ShardedReader", "epoch_order", "reshard_states"]

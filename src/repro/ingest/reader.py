@@ -247,20 +247,6 @@ class ShardedReader:
             q = q.filter(cfg.predicate)
         return q.select(cfg.column).physical_plan()
 
-    @classmethod
-    def for_mesh(cls, source, cfg: ReaderConfig, mesh, *,
-                 axis: str = "data", dp_rank: int | None = None,
-                 state: ReaderState | None = None) -> "ShardedReader":
-        """Shard over a mesh axis: ``dp_size`` is the axis size and
-        ``dp_rank`` defaults to this process's position on it."""
-        dp_size = int(mesh.shape[axis])
-        if dp_rank is None:
-            import jax
-
-            dp_rank = jax.process_index() % dp_size
-        return cls(source, cfg, dp_rank=dp_rank, dp_size=dp_size,
-                   state=state)
-
     @property
     def shard_tasks(self):
         """This rank's FragmentTasks, plan order."""
@@ -400,6 +386,71 @@ class ShardedReader:
         d["batches"] = self._nbatches
         d["epochs"] = self._state.epoch
         return d
+
+
+class MeshReader:
+    """A mesh's global batches, one :class:`ShardedReader` per position
+    of its ``"data"`` axis.
+
+    Position ``r`` of the data axis reads shard ``r`` of ``dp_size``; its
+    ``(local_batch, seq_len)`` batch lands on the devices at that
+    position (replicated over the other axes), and the global
+    ``(dp_size * local_batch, seq_len)`` array is assembled from those
+    per-device pieces.  A process reads only the positions its own
+    devices hold, so one process driving every chip of a host reads
+    every shard, and a multi-host job splits the positions by host.
+    ``checkpoint()`` returns one :class:`ReaderState` per position read
+    here; pass them back as ``states`` to resume."""
+
+    def __init__(self, source, cfg: ReaderConfig, mesh, *,
+                 states: Sequence[ReaderState] | None = None):
+        from jax.sharding import NamedSharding, PartitionSpec
+
+        self.cfg = cfg
+        self.dp_size = int(mesh.shape["data"])
+        self.sharding = NamedSharding(mesh, PartitionSpec("data"))
+        self.shape = (self.dp_size * cfg.local_batch, cfg.seq_len)
+        ranks = sorted({self._rank(idx) for idx in
+                        self.sharding.addressable_devices_indices_map(
+                            self.shape).values()})
+        by_rank = {s.dp_rank: s for s in states or ()}
+        self.readers = {
+            r: ShardedReader(source, cfg, dp_rank=r, dp_size=self.dp_size,
+                             state=by_rank.get(r))
+            for r in ranks}
+
+    def _rank(self, index) -> int:
+        """Data-axis position of one device's block of the batch."""
+        return (index[0].start or 0) // self.cfg.local_batch
+
+    def __iter__(self):
+        return self
+
+    def __next__(self) -> dict:
+        import jax
+
+        host = {r: next(rd) for r, rd in self.readers.items()}
+        return {k: jax.make_array_from_callback(
+                    self.shape, self.sharding,
+                    lambda idx, k=k: host[self._rank(idx)][k])
+                for k in ("tokens", "labels")}
+
+    def checkpoint(self) -> list[ReaderState]:
+        return [rd.checkpoint() for rd in self.readers.values()]
+
+    def stats(self) -> dict:
+        """Per-rank ``ShardedReader.stats`` summed over this process's
+        positions (epochs: the furthest position)."""
+        out: dict = {}
+        for rd in self.readers.values():
+            for k, v in rd.stats().items():
+                out[k] = max(out.get(k, 0), v) if k == "epochs" \
+                    else out.get(k, 0) + v
+        return out
+
+    def close(self):
+        for rd in self.readers.values():
+            rd.close()
 
 
 def reshard_states(source, cfg: ReaderConfig,

@@ -15,8 +15,11 @@ chunks through ``decode_dictionary``, lowers flat AND/OR comparison
 predicates to ``build_program``/``fused_predicate`` so mask evaluation
 fuses across columns, and compacts selections with ``pack_tokens``; the
 adaptive scheduler prices client placement with the backend's decode
-rate.  Off-accelerator the ops run ``interpret=True``, so results stay
-byte-identical to the host path (pinned by ``tests/test_decode.py``).
+rate.  Every route is bit-exact, so results are byte-identical to the
+host path (pinned by ``tests/test_decode.py``).  The kernels compile for
+the TPU and run in Pallas's interpreter on the CPU, which serves tests
+and rehearsals only; ``repro.kernels.platform`` decides per call and
+raises on any other platform.
 
 Each package: <name>.py (pl.pallas_call + BlockSpec), ops.py (jit'd public
 wrapper with padding), ref.py (pure-jnp oracle for the allclose tests).

@@ -8,17 +8,14 @@ f32-exactness domain (|int| < 2**24) covers every corpus column we emit.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.predicate_fused.predicate_fused import (TILE, Program,
                                                            Term,
                                                            predicate_mask)
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 def build_program(terms: list[tuple[int, str, float]], combine: str = "and",
@@ -27,18 +24,11 @@ def build_program(terms: list[tuple[int, str, float]], combine: str = "and",
                    combine, negate)
 
 
-@functools.partial(jax.jit, static_argnames=("prog",))
-def _stack(cols, prog):
-    return jnp.stack([c.astype(jnp.float32) for c in cols])
-
-
 def fused_predicate(cols: list[jax.Array | np.ndarray], prog: Program
                     ) -> jax.Array:
     """cols: list of (N,) arrays -> (N,) bool mask."""
     n = int(np.shape(cols[0])[0])
-    stacked = _stack([jnp.asarray(c) for c in cols], prog)
     pad = (-n) % TILE
-    if pad:
-        stacked = jnp.pad(stacked, ((0, 0), (0, pad)))
-    mask = predicate_mask(stacked, prog, interpret=_INTERPRET)
+    padded = [jnp.pad(jnp.asarray(c, jnp.float32), (0, pad)) for c in cols]
+    mask = predicate_mask(padded, prog, interpret=interpret_mode())
     return mask[:n].astype(bool)

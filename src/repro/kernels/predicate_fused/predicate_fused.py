@@ -3,9 +3,9 @@
 The scan hot loop evaluates ``(col_a OP c_a) COMBINE (col_b OP c_b) ...``
 over millions of rows.  On CPU Arrow does this one compare kernel at a
 time, materializing an intermediate mask per term; on TPU we fuse every
-term into one VMEM pass: the C predicate columns arrive as a (C, N) stack,
-each grid step streams a (C, TILE) block into VMEM, evaluates all compares
-on the VPU and combines them in registers, emitting one (TILE,) byte mask.
+term into one VMEM pass: each grid step streams a (1, TILE) block of each
+of the C predicate columns into VMEM, evaluates all compares on the VPU
+and combines them in registers, emitting one (1, TILE) int32 0/1 mask.
 Arithmetic intensity is (C compares + C-1 logicals) per C·4 bytes — memory
 bound, which is exactly why fusing (one pass, no intermediate masks)
 matters.
@@ -19,6 +19,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+from typing import Sequence
 
 import jax
 import jax.numpy as jnp
@@ -32,7 +33,7 @@ OPS = ("lt", "le", "gt", "ge", "eq", "ne")
 
 @dataclasses.dataclass(frozen=True)
 class Term:
-    col: int          # row index into the (C, N) column stack
+    col: int          # index into the kernel's column list
     op: str           # one of OPS
     value: float      # compare constant (f32-exact domain: ints < 2**24)
 
@@ -61,8 +62,9 @@ def _apply_term(cols, t: Term):
     }[t.op]()
 
 
-def _kernel(cols_ref, out_ref, *, prog: Program):
-    cols = cols_ref[...]                       # (C, TILE) f32 in VMEM
+def _kernel(*refs, prog: Program):
+    *col_refs, out_ref = refs
+    cols = [r[...] for r in col_refs]          # C x (1, TILE) f32 in VMEM
     acc = _apply_term(cols, prog.terms[0])
     for t in prog.terms[1:]:
         m = _apply_term(cols, t)
@@ -70,22 +72,24 @@ def _kernel(cols_ref, out_ref, *, prog: Program):
             else jnp.logical_or(acc, m)
     if prog.negate:
         acc = jnp.logical_not(acc)
-    out_ref[...] = acc.astype(jnp.uint8)
+    out_ref[...] = acc.astype(jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("prog", "interpret"))
-def predicate_mask(cols: jax.Array, prog: Program, *,
+def predicate_mask(cols: Sequence[jax.Array], prog: Program, *,
                    interpret: bool = False) -> jax.Array:
-    """cols: (C, N) float32 (N a multiple of TILE) -> (N,) uint8 mask."""
-    c, n = cols.shape
+    """cols: C float32 arrays of shape (N,) (N a multiple of TILE) ->
+    (N,) int32 0/1 mask."""
+    c, n = len(cols), cols[0].shape[0]
     if n % TILE:
         raise ValueError(f"N={n} not a multiple of {TILE}; pad in ops.py")
-    grid = (n // TILE,)
+    spec = pl.BlockSpec((1, TILE), lambda i: (0, i))
     return pl.pallas_call(
         functools.partial(_kernel, prog=prog),
-        grid=grid,
-        in_specs=[pl.BlockSpec((c, TILE), lambda i: (0, i))],
-        out_specs=pl.BlockSpec((TILE,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), jnp.uint8),
+        grid=(n // TILE,),
+        in_specs=[spec] * c,
+        out_specs=spec,
+        out_shape=jax.ShapeDtypeStruct((1, n), jnp.int32),
         interpret=interpret,
-    )(cols)
+        name="predicate_mask",
+    )(*[col.reshape(1, n) for col in cols]).reshape(n)

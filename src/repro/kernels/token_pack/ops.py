@@ -14,18 +14,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.platform import interpret_mode
 from repro.kernels.token_pack.token_pack import TILE, tile_pack
-
-_INTERPRET = jax.default_backend() == "cpu"
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))
-def _pack(values, mask, capacity: int, interpret: bool):
-    n = values.shape[0]
+def _pack(bits, mask, capacity: int, interpret: bool):
+    n = bits.shape[0]
     pad = (-n) % TILE
-    v = jnp.pad(values.astype(jnp.float32), (0, pad))
-    m = jnp.pad(mask.astype(jnp.uint8), (0, pad))
-    packed_tiles, counts = tile_pack(v, m, interpret=interpret)
+    b = jnp.pad(bits, (0, pad))
+    m = jnp.pad(mask.astype(jnp.int32), (0, pad))
+    packed_tiles = tile_pack(b, m, interpret=interpret)
+    counts = m.reshape(-1, TILE).sum(axis=1)
 
     offsets = jnp.cumsum(counts) - counts            # tile -> global base
     total = jnp.minimum(jnp.sum(counts), capacity)
@@ -37,17 +37,26 @@ def _pack(values, mask, capacity: int, interpret: bool):
     t = jnp.minimum(t, counts.shape[0] - 1)
     local = j - offsets[t]
     flat = packed_tiles.reshape(-1)
-    out = jnp.where(j < total, flat[t * TILE + local], 0.0)
+    out = jnp.where(j < total, flat[t * TILE + local], 0)
     return out, total
 
 
 def pack_tokens(values, mask, capacity: int):
     """values (N,), mask (N,) -> (packed (capacity,), count scalar).
 
-    Integer inputs must be f32-exact (< 2**24): true for token ids."""
-    values = jnp.asarray(values)
+    Bit-exact for 32-bit values and bools; 64-bit integers must lie in
+    the f32-exact domain (< 2**24), as token ids do, and come back as
+    numpy arrays of their dtype."""
+    values = np.asarray(values)
     out_dtype = values.dtype
-    out, total = _pack(values, jnp.asarray(mask), capacity, _INTERPRET)
-    if jnp.issubdtype(out_dtype, jnp.integer):
-        out = jnp.round(out).astype(out_dtype)
-    return out, total
+    if out_dtype.kind == "f":
+        bits = values.astype(np.float32).view(np.int32)
+    else:
+        bits = values.astype(np.int32)
+    out, total = _pack(jnp.asarray(bits), jnp.asarray(mask), capacity,
+                       interpret_mode())
+    if out_dtype.kind == "f":
+        out = jax.lax.bitcast_convert_type(out, jnp.float32)
+    if out_dtype.itemsize == 8:                     # non-canonical in jax
+        return np.asarray(out).astype(out_dtype), total
+    return out.astype(out_dtype), total

@@ -349,13 +349,13 @@ def collective_summary(colls: list[Collective]) -> dict[str, Any]:
 
 def roofline_terms(flops_per_dev: float, bytes_per_dev: float,
                    colls: list[Collective]) -> dict[str, float]:
-    compute_s = flops_per_dev / mesh_mod.PEAK_FLOPS_BF16
-    memory_s = bytes_per_dev / mesh_mod.HBM_BW
+    peaks = mesh_mod.device_peaks(mesh_mod.TARGET_KIND)
+    compute_s = flops_per_dev / peaks.bf16_flops
+    memory_s = bytes_per_dev / peaks.hbm_bw
     ici = sum(c.wire_bytes for c in colls if not c.crosses_pod)
     dcn = sum(c.wire_bytes for c in colls if c.crosses_pod)
-    collective_s = (ici / (mesh_mod.ICI_BW_PER_LINK *
-                           mesh_mod.ICI_LINKS_PER_AXIS)
-                    + dcn / mesh_mod.DCN_BW)
+    collective_s = (ici / (peaks.ici_bw_per_link * peaks.ici_links_per_axis)
+                    + dcn / peaks.dcn_bw)
     terms = {
         "compute_s": compute_s,
         "memory_s": memory_s,

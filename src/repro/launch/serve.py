@@ -22,6 +22,7 @@ from repro.aformat.expressions import field
 from repro.aformat.table import Table
 from repro.configs import get_config, smoke_config
 from repro.core import dataset, make_cluster, write_flat
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_local_mesh, make_production_mesh
 from repro.serve import Request, ServeEngine, init_serve_params
 from repro.sharding import default_rules
@@ -53,6 +54,7 @@ def main() -> int:
     ap.add_argument("--max-batch", type=int, default=4)
     ap.add_argument("--osds", type=int, default=4)
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.smoke:
         cfg = smoke_config(args.arch)

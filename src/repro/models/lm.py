@@ -32,7 +32,6 @@ from repro.models import attention as attn
 from repro.models import ssd
 from repro.models.common import (Initializer, apply_rope, gelu,
                                  rms_norm, rope_at, split_tree, swiglu)
-from repro.sharding import shard_map
 
 # ---------------------------------------------------------------------------
 # Parameter construction
@@ -315,7 +314,7 @@ def _mlp_out_rs(ctx, act, w):
                                     tiled=True)
 
     bd = _bd(ctx)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(bd, None, "model"), P("model", "data")),
         out_specs=P(bd, "model", None), check_vma=False)(act, w)
@@ -332,7 +331,7 @@ def _attn_out_rs(ctx, o, w):
                                     tiled=True)
 
     bd = _bd(ctx)
-    return shard_map(
+    return jax.shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(bd, None, "model", None), P("model", None, "data")),
         out_specs=P(bd, "model", None), check_vma=False)(o, w)
@@ -579,7 +578,7 @@ def moe_block(cfg, ctx, p, x):
         out_spec = P(bd, "model", None) if seq_ok else P(bd, None, None)
         body = tp_body
 
-    y = shard_map(
+    y = jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_spec,
         check_vma=False,
     )(h_in, p["router"], p["wi"], p["wg"], p["wo"])

@@ -19,17 +19,6 @@ import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:  # jax >= 0.4.38 exposes shard_map at the top level
-    shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental namespace; the replica
-    # check kwarg is spelled check_rep there instead of check_vma
-    from jax.experimental.shard_map import shard_map as _shard_map_compat
-
-    def shard_map(f, /, **kwargs):
-        if "check_vma" in kwargs:
-            kwargs["check_rep"] = kwargs.pop("check_vma")
-        return _shard_map_compat(f, **kwargs)
-
 # ---------------------------------------------------------------------------
 # Logical axis vocabulary
 # ---------------------------------------------------------------------------

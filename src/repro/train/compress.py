@@ -25,7 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from repro.sharding import shard_map
 
 
 def _quantize(t):
@@ -79,7 +78,7 @@ def make_compressed_grad_fn(loss_fn, mesh, *, axis: str = "pod"):
         return loss, grads, err
 
     batch_spec = jax.tree.map(lambda _: P(axis), {"tokens": 0, "labels": 0})
-    return shard_map(
+    return jax.shard_map(
         per_pod, mesh=mesh,
         in_specs=(P(), batch_spec, P()),
         out_specs=(P(), P(), P()),

@@ -1,8 +1,8 @@
 """int8 error-feedback gradient compression: wire dtype + convergence."""
 
 import pytest
-import subprocess
-import sys
+
+from subproc import run_python
 
 # slow lane: jax/pallas compile-heavy; skipped by `make test-fast` / CI per-push
 pytestmark = pytest.mark.slow
@@ -12,9 +12,10 @@ CODE = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
+    from repro.launch.mesh import make_mesh
     from repro.train import compress
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
 
     # --- quadratic regression: compressed DP matches exact DP -------------
     rng = np.random.default_rng(0)
@@ -54,9 +55,6 @@ CODE = textwrap.dedent("""
 
 
 def test_compressed_allreduce():
-    out = subprocess.run([sys.executable, "-c", CODE], capture_output=True,
-                         text=True,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                         cwd="/root/repo")
+    out = run_python(["-c", CODE])
     assert "COMPRESS_OK" in out.stdout, (out.stdout[-1000:],
                                          out.stderr[-2500:])

@@ -4,8 +4,9 @@ The contract under test is byte-identity: for any row group the
 ``PallasBackend`` must return exactly what the ``NumPyBackend`` returns —
 same dtypes, same bits, same validity — whether a column/predicate routed
 through the accelerator kernels or fell back to the host path
-(``interpret=True`` off-accelerator makes the kernels exact, and the
-f32-domain gates keep everything else on the host).  The grid here spans
+(every kernel route is bit-exact; on the CPU the kernels run in Pallas's
+interpreter, on the chip compiled, through the same code; the f32-domain
+gates keep everything else on the host).  The grid here spans
 encoding x dtype x validity x predicate, including fallback mixes inside
 one row group.
 
@@ -160,6 +161,41 @@ def test_fallback_mix_within_one_row_group():
     assert report["predicate"] == "kernel"
     assert report["compact"]["cat"] == "kernel"
     assert report["compact"]["pay"] == "host"
+
+
+def test_large_dictionaries_and_dict_predicate_stay_on_kernels():
+    """The chip's scan path at row-group scale: a DICT int32 column with a
+    dictionary above ONEHOT_MAX (the gather route), a DICT float32 column
+    likewise, next to a small one-hot DICT column; the predicate ANDs
+    comparisons on the DICT columns and the selection compacts on the
+    kernel.  Byte-identical to NumPy, and every route reports kernel."""
+    from repro.kernels.dict_decode.dict_decode import ONEHOT_MAX
+
+    rng = np.random.default_rng(7)
+    n = 60_000
+    tbl = Table.from_pydict({
+        "id": rng.integers(0, 5_000, n).astype(np.int32),
+        "amount": rng.choice(rng.gamma(1.5, 2.0, 3_000).astype(np.float32),
+                             n),
+        "count": rng.integers(1, 7, n).astype(np.int32),
+    })
+    data = parquet.write_table(tbl, row_group_rows=n)
+    meta = parquet.read_footer(parquet.BytesSource(data))
+    for name in ("id", "amount"):
+        chunk = meta.row_groups[0].chunks[meta.schema.index(name)]
+        assert chunk.encoding == encodings.DICT
+        # the dictionary is the column's distinct values
+        assert len(np.unique(tbl.column(name).values)) > ONEHOT_MAX
+    q = float(np.float32(np.quantile(tbl.column("amount").values, 0.8)))
+    pred = ((field("id") < 4_000) & (field("amount") > q)
+            & (field("count") >= 2))
+    _, out, report = scan_both(tbl, predicate=pred, row_group_rows=n)
+    assert report["columns"] == {"id": "kernel", "amount": "kernel",
+                                 "count": "kernel"}
+    assert report["predicate"] == "kernel"
+    assert report["compact"] == {"id": "kernel", "amount": "kernel",
+                                 "count": "kernel"}
+    assert 0 < len(out) < n
 
 
 @pytest.mark.parametrize("pred_name,reason", [

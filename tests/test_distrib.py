@@ -135,15 +135,18 @@ def test_plan_downsize_shrinks_data_axis_pow2():
 
 def test_elastic_downsize_end_to_end_subprocess():
     """Real 8-device resharding (device count needs its own process)."""
-    import subprocess, sys, textwrap
+    import textwrap
+
+    from subproc import run_python
 
     code = textwrap.dedent("""
         import os
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import jax, jax.numpy as jnp, numpy as np
         from repro.distrib import elastic_downsize
+        from repro.launch.mesh import make_mesh
         from repro.sharding import default_rules
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         rules = default_rules()
         state = {"w": jnp.arange(64.0).reshape(8, 8)}
         specs = {"w": ("embed", "mlp")}
@@ -160,7 +163,5 @@ def test_elastic_downsize_end_to_end_subprocess():
         assert ns.mesh.shape["data"] == 2
         print("ELASTIC_OK")
     """)
-    out = subprocess.run([sys.executable, "-c", code], capture_output=True,
-                         text=True, env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                         cwd="/root/repo")
+    out = run_python(["-c", code])
     assert "ELASTIC_OK" in out.stdout, out.stderr[-2000:]

@@ -433,3 +433,84 @@ def test_reader_context_manager(corpus_fs):
         next(rd)
         thread = rd._prefetcher._thread
     assert not thread.is_alive()  # close() joined the prefetch thread
+
+
+# ---------------------------------------------------------------------------
+# one process, four devices: MeshReader reads every rank
+# ---------------------------------------------------------------------------
+
+MESH_READER_CODE = """
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import numpy as np
+from repro.aformat import parquet
+from repro.aformat.decode import NumPyBackend
+from repro.aformat.expressions import field
+from repro.core import dataset, make_cluster
+from repro.data import synth_corpus, write_corpus
+from repro.ingest import MeshReader, ReaderConfig, ShardedReader, epoch_order
+from repro.launch.mesh import make_local_mesh
+
+fs = make_cluster(4)
+write_corpus(fs, "/c", synth_corpus(40, mean_doc_len=150, vocab_size=3000,
+                                    seed=5),
+             num_shards=4, row_group_rows=512)
+ds = dataset(fs, "/c")
+pred = field("quality") > 0.3
+cfg = ReaderConfig(seq_len=31, local_batch=2, predicate=pred,
+                   format="parquet", decode_backend="pallas", num_threads=2)
+reader = MeshReader(ds, cfg, make_local_mesh(4, 1))
+assert sorted(reader.readers) == [0, 1, 2, 3], sorted(reader.readers)
+n_tasks = len(reader.readers[0].tasks)
+owned = sorted(i for rd in reader.readers.values() for i in rd.shard)
+assert owned == list(range(n_tasks)), owned     # every fragment, one rank
+
+
+def tokens_of(task):
+    # independent of the reader and the executor: the fragment's row group
+    # decoded and filtered on the host
+    frag = task.fragment
+    src = parquet.BytesSource(fs.read_file(frag.path))
+    rg = frag.client_meta.row_groups[frag.client_rg_index]
+    return NumPyBackend().scan_row_group(
+        src, frag.client_meta, rg, ["token"], pred).column("token").values
+
+
+epoch0 = {}
+for r, rd in reader.readers.items():
+    order = epoch_order(rd.checkpoint(), rd.shards)
+    epoch0[r] = np.concatenate([tokens_of(rd.tasks[i]) for i in order])
+ranks = [ShardedReader(ds, cfg, dp_rank=r, dp_size=4) for r in range(4)]
+streams = {r: [] for r in range(4)}
+for step in range(1000):
+    if all(sum(map(len, streams[r])) >= len(epoch0[r]) for r in range(4)):
+        break
+    got = next(reader)
+    host = [next(rd) for rd in ranks]
+    for k in ("tokens", "labels"):
+        want = np.concatenate([h[k] for h in host])
+        assert np.asarray(got[k]).tobytes() == want.tobytes(), (step, k)
+    toks, labs = np.asarray(got["tokens"]), np.asarray(got["labels"])
+    for r in range(4):
+        for row in range(r * 2, r * 2 + 2):     # rank r's rows
+            streams[r].append(np.append(toks[row], labs[row][-1]))
+for r in range(4):
+    stream = np.concatenate(streams[r])
+    # each fragment of rank r's shard read once, in its epoch order
+    assert np.array_equal(stream[:len(epoch0[r])], epoch0[r]), r
+reader.close()
+for rd in ranks:
+    rd.close()
+print("MESH_READER_OK", step, n_tasks)
+"""
+
+
+def test_mesh_reader_reads_every_rank_on_four_devices():
+    """One process, four (virtual) devices: the global batch holds all
+    four ranks' batches in order, and over one epoch each rank reads
+    every fragment of its shard exactly once."""
+    from subproc import run_python
+
+    out = run_python(["-c", MESH_READER_CODE], timeout=900)
+    assert "MESH_READER_OK" in out.stdout, (out.stdout[-1500:],
+                                            out.stderr[-3000:])

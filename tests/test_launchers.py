@@ -2,21 +2,17 @@
 own XLA_FLAGS / argv)."""
 
 import pytest
-import subprocess
-import sys
+
+from subproc import run_python
 
 # slow lane: jax/pallas compile-heavy; skipped by `make test-fast` / CI per-push
 pytestmark = pytest.mark.slow
-
-ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
 
 
 def _run(args):
     # generous timeout: CI containers can be CPU-throttled ~10x, and the
     # launcher subprocesses re-pay jax compilation from scratch
-    return subprocess.run([sys.executable, "-m", *args],
-                          capture_output=True, text=True, env=ENV,
-                          cwd="/root/repo", timeout=1800)
+    return run_python(["-m", *args], timeout=1800)
 
 
 def test_train_launcher_smoke():

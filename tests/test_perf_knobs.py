@@ -3,8 +3,8 @@ same loss/gradients as the baseline path, only placement/precision of the
 TP epilogue boundary changes (bf16 reduce-scatter, documented)."""
 
 import pytest
-import subprocess
-import sys
+
+from subproc import run_python
 
 # slow lane: jax/pallas compile-heavy; skipped by `make test-fast` / CI per-push
 pytestmark = pytest.mark.slow
@@ -16,6 +16,7 @@ CODE = textwrap.dedent("""
     import dataclasses
     import jax, jax.numpy as jnp, numpy as np
     from repro.configs import smoke_config
+    from repro.launch.mesh import make_mesh
     from repro.sharding import default_rules
     from repro.train import optim, step as step_mod
 
@@ -23,7 +24,7 @@ CODE = textwrap.dedent("""
     base = dataclasses.replace(
         base, num_layers=2, d_model=64, d_ff=128, num_heads=8,
         num_kv_heads=4, head_dim=16, vocab_size=128, remat=False)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     rules = default_rules()
     opt = optim.OptConfig(warmup_steps=0)
     key = jax.random.key(0)
@@ -54,9 +55,6 @@ CODE = textwrap.dedent("""
 
 
 def test_perf_knobs_preserve_semantics():
-    out = subprocess.run([sys.executable, "-c", CODE], capture_output=True,
-                         text=True,
-                         env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                         cwd="/root/repo")
+    out = run_python(["-c", CODE])
     assert "PERF_KNOBS_OK" in out.stdout, (out.stdout[-1500:],
                                            out.stderr[-3000:])
