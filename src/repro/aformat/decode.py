@@ -27,6 +27,13 @@ comes out.  Two backends implement that contract:
     ``tests/test_decode.py`` pins that equivalence across the encoding
     x dtype x validity x predicate grid.
 
+Each stage is a host span (``repro.trace``): ``repro.decode.decompress``
+per buffer, ``repro.decode.host`` per host route (a column's decode, a
+predicate, a host ``take``), ``repro.kernel.dict_decode`` /
+``repro.kernel.predicate`` / ``repro.kernel.pack`` per kernel call, from
+the host casts to the NumPy result, and nested in those
+``repro.kernel.fetch``, the blocking read of the result from the device.
+
 The scheduler prices the two regimes separately: each backend carries a
 ``decode_rate_prior`` (stored bytes per second of decode+filter) that
 seeds the client-side EWMA in ``repro.dataset.scheduler``.
@@ -43,6 +50,7 @@ from repro.aformat import compression, encodings
 from repro.aformat.expressions import And, Cmp, Expr, Not, Or
 from repro.aformat.schema import Field
 from repro.aformat.table import Column, Table
+from repro.trace import span
 
 #: |integers| below this round-trip float32 exactly — the kernels compute
 #: in f32, so columns/constants outside the domain stay on the host path.
@@ -101,9 +109,19 @@ def read_chunk(src, meta, rg, name: str) -> ChunkData:
     bufs = []
     off = chunk.offset
     for ln in chunk.buffer_lengths:
-        bufs.append(compression.decompress(chunk.codec, src.read(off, ln)))
+        raw = src.read(off, ln)
+        with span("repro.decode.decompress"):
+            bufs.append(compression.decompress(chunk.codec, raw))
         off += ln
     return ChunkData(field, chunk.encoding, bufs, rg.num_rows)
+
+
+def _host_decode(chunk: ChunkData) -> np.ndarray:
+    """A column chunk's values decoded on the host."""
+    with span("repro.decode.host"):
+        return encodings.decode(chunk.field.type, chunk.encoding,
+                                chunk.data_bufs, chunk.num_rows,
+                                chunk.field.numpy_dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -174,25 +192,34 @@ class NumPyBackend(DecodeBackend):
     decode_rate_prior = 150e6    # matches the paper-testbed Xeon prior
 
     def decode_column(self, chunk: ChunkData) -> Column:
-        values = encodings.decode(chunk.field.type, chunk.encoding,
-                                  chunk.data_bufs, chunk.num_rows,
-                                  chunk.field.numpy_dtype)
-        return Column(chunk.field, values, chunk.validity())
+        return Column(chunk.field, _host_decode(chunk), chunk.validity())
 
     def evaluate_predicate(self, tbl, predicate, report=None):
         if report is not None:
             report["predicate"] = "host"
-        return predicate.evaluate(tbl)
+        with span("repro.decode.host"):
+            return predicate.evaluate(tbl)
 
     def compact(self, tbl, mask, report=None):
         if report is not None:
             report["compact"] = "host"
-        return tbl.filter(mask)
+        with span("repro.decode.host"):
+            return tbl.filter(mask)
 
 
 # ---------------------------------------------------------------------------
 # Pallas backend
 # ---------------------------------------------------------------------------
+
+
+def _fetch(result) -> np.ndarray:
+    """A kernel's result on the host: the blocking read from the device,
+    or the array itself where the kernel's entry already made that read
+    (64-bit results, widened on the host)."""
+    if isinstance(result, np.ndarray):
+        return result
+    with span("repro.kernel.fetch"):
+        return np.asarray(result)
 
 
 def _f32_exact_values(values: np.ndarray) -> bool:
@@ -272,12 +299,11 @@ class PallasBackend(DecodeBackend):
             # an int dictionary outside the f32-exact domain is the
             # host-fallback condition; a kernel error propagates
             if _f32_exact_values(dic):
-                values = np.asarray(decode_dictionary(codes, dic))
+                with span("repro.kernel.dict_decode"):
+                    values = _fetch(decode_dictionary(codes, dic))
                 route = "kernel"
         if values is None:
-            values = encodings.decode(chunk.field.type, chunk.encoding,
-                                      chunk.data_bufs, chunk.num_rows,
-                                      chunk.field.numpy_dtype)
+            values = _host_decode(chunk)
         col = Column(chunk.field, values, chunk.validity())
         col._decode_route = route        # scraped into the scan report
         return col
@@ -318,12 +344,14 @@ class PallasBackend(DecodeBackend):
         if lowered is None:
             if report is not None:
                 report["predicate"] = f"host:{reason}"
-            return predicate.evaluate(tbl)
+            with span("repro.decode.host"):
+                return predicate.evaluate(tbl)
         from repro.kernels import fused_predicate
 
         prog, cols = lowered
-        mask = np.asarray(fused_predicate(
-            [np.asarray(c.values, np.float32) for c in cols], prog))
+        with span("repro.kernel.predicate"):
+            mask = _fetch(fused_predicate(
+                [np.asarray(c.values, np.float32) for c in cols], prog))
         for c in cols:
             if c.validity is not None:     # AND-combine only (see _lower)
                 mask = mask & c.validity
@@ -347,14 +375,15 @@ class PallasBackend(DecodeBackend):
         for c in tbl.columns:
             if (capacity and c.field.type in _KERNEL_TYPES
                     and _f32_exact_values(c.values)):
-                packed, _ = pack_tokens(c.values, mask, capacity)
+                with span("repro.kernel.pack"):
+                    packed, _ = pack_tokens(c.values, mask, capacity)
+                    values = _fetch(packed)[:n_sel]
                 validity = None if c.validity is None else c.validity[idx]
-                out_cols.append(Column(c.field,
-                                       np.asarray(packed)[:n_sel],
-                                       validity))
+                out_cols.append(Column(c.field, values, validity))
                 routes[c.field.name] = "kernel"
             else:
-                out_cols.append(c.take(idx))
+                with span("repro.decode.host"):
+                    out_cols.append(c.take(idx))
                 routes[c.field.name] = "host"
         if report is not None:
             report["compact"] = routes

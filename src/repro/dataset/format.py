@@ -42,17 +42,27 @@ from repro.aformat.table import Table
 from repro.dataset.fragment import Fragment
 from repro.dataset.qos import TaskContext, resolve_context
 from repro.storage.cephfs import CephFS, DirectObjectAccess, FileSource
+from repro.trace import span
 
 
 @dataclasses.dataclass
 class TaskRecord:
-    """Per-fragment accounting — feeds the Fig. 5/6 performance model."""
+    """Per-fragment accounting — feeds the Fig. 5/6 performance model.
+
+    The seconds are wall time on the host's clock, not CPU time.  On the
+    client path (``ParquetFormat``) ``cpu_s`` and ``client_cpu_s`` are
+    both the admitted task's wall time: storage reads, decompression,
+    decode, filter, waits on the accelerator and waits for the GIL
+    behind other scan threads.  On the storage path ``cpu_s`` is the
+    node's elapsed time for the cls call and ``client_cpu_s`` the
+    client's wall time decoding the reply."""
 
     where: str            # "client" or "osd"
     node: int             # osd id (-1 for client-only work)
-    cpu_s: float          # decode/filter CPU burned at `where`
+    cpu_s: float          # wall time of the scan at `where`
     wire_bytes: int       # bytes that crossed the network to the client
-    client_cpu_s: float   # residual client CPU (IPC decode / materialize)
+    client_cpu_s: float   # wall time on the client (the whole task on the
+                          # client path; the reply's decode on the osd's)
     rows_out: int
     hedged: bool = False
     cached: bool = False  # served from the columnar result cache
@@ -248,13 +258,19 @@ def aggregate_client(fmt: FileFormat, fs: CephFS, frag: Fragment,
     return state, rec
 
 
+@contextlib.contextmanager
 def _admit_fragment(fs: CephFS, frag: Fragment, ctx: TaskContext):
     """Slot on the OSD this fragment's bytes live on (no-op without an
-    admission controller on the context)."""
+    admission controller on the context); the wait for it is the host
+    span ``repro.storage.admit``."""
     if ctx.admission is None:
-        return contextlib.nullcontext()
-    name = fs.object_names(frag.path)[frag.obj_idx]
-    return ctx.admission.admit_object(name, ctx)
+        yield
+        return
+    with contextlib.ExitStack() as slot:
+        with span("repro.storage.admit"):
+            name = fs.object_names(frag.path)[frag.obj_idx]
+            slot.enter_context(ctx.admission.admit_object(name, ctx))
+        yield
 
 
 class ParquetFormat(FileFormat):
@@ -271,6 +287,10 @@ class ParquetFormat(FileFormat):
 
     def scan_fragment(self, fs, frag, columns, predicate, ctx=None,
                       **legacy):
+        """Scan one fragment on the client.  The record's ``cpu_s`` and
+        ``client_cpu_s`` are the admitted body's wall time, the host span
+        ``repro.scan.task``: storage reads, decompression, decode and
+        filter, waits on the accelerator and for the GIL."""
         ctx = resolve_context(ctx, legacy)
         wire = 0
 
@@ -279,7 +299,7 @@ class ParquetFormat(FileFormat):
             wire += n
 
         src = FileSource(fs, frag.path, on_read=on_read)
-        with _admit_fragment(fs, frag, ctx):
+        with _admit_fragment(fs, frag, ctx), span("repro.scan.task"):
             t0 = time.perf_counter()
             meta = frag.client_meta
             if meta is None:

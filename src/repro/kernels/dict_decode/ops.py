@@ -10,6 +10,7 @@ from repro.kernels.dict_decode.dict_decode import (ONEHOT_MAX, TILE,
                                                    dict_decode,
                                                    padded_dict_size)
 from repro.kernels.platform import interpret_mode
+from repro.trace import span
 
 
 def decode_dictionary(codes, dictionary):
@@ -44,5 +45,6 @@ def decode_dictionary(codes, dictionary):
     if out_dtype.kind == "f":
         out = jax.lax.bitcast_convert_type(out, jnp.float32)
     if out_dtype.itemsize == 8:                     # non-canonical in jax
-        return np.asarray(out).astype(out_dtype)
+        with span("repro.kernel.fetch"):
+            out = np.asarray(out)
     return out.astype(out_dtype)

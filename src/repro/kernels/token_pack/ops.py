@@ -16,6 +16,7 @@ import numpy as np
 
 from repro.kernels.platform import interpret_mode
 from repro.kernels.token_pack.token_pack import TILE, tile_pack
+from repro.trace import span
 
 
 @functools.partial(jax.jit, static_argnames=("capacity", "interpret"))
@@ -58,5 +59,6 @@ def pack_tokens(values, mask, capacity: int):
     if out_dtype.kind == "f":
         out = jax.lax.bitcast_convert_type(out, jnp.float32)
     if out_dtype.itemsize == 8:                     # non-canonical in jax
-        return np.asarray(out).astype(out_dtype), total
+        with span("repro.kernel.fetch"):
+            out = np.asarray(out)
     return out.astype(out_dtype), total

@@ -16,6 +16,7 @@ from concurrent.futures import wait as futures_wait
 from typing import Any, Callable
 
 from repro.storage.objstore import ObjectNotFound, ObjectStore
+from repro.trace import span
 
 DEFAULT_STRIPE_UNIT = 4 * 1024 * 1024
 
@@ -119,19 +120,22 @@ class CephFS:
         return b"".join(parts)[: ino.size]
 
     def read_range(self, path: str, offset: int, length: int) -> bytes:
-        """Random-access read through the striping map."""
-        ino = self.stat(path)
-        su = ino.stripe_unit
-        end = min(offset + length, ino.size)
-        out = bytearray()
-        idx = offset // su
-        while offset < end:
-            within = offset - idx * su
-            take = min(su - within, end - offset)
-            out += self.store.get(self.object_name(ino, idx), within, take)
-            offset += take
-            idx += 1
-        return bytes(out)
+        """Random-access read through the striping map (host span
+        ``repro.storage.read``)."""
+        with span("repro.storage.read"):
+            ino = self.stat(path)
+            su = ino.stripe_unit
+            end = min(offset + length, ino.size)
+            out = bytearray()
+            idx = offset // su
+            while offset < end:
+                within = offset - idx * su
+                take = min(su - within, end - offset)
+                out += self.store.get(self.object_name(ino, idx), within,
+                                      take)
+                offset += take
+                idx += 1
+            return bytes(out)
 
     def file_size(self, path: str) -> int:
         return self.stat(path).size
