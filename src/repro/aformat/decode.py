@@ -27,12 +27,25 @@ comes out.  Two backends implement that contract:
     ``tests/test_decode.py`` pins that equivalence across the encoding
     x dtype x validity x predicate grid.
 
+A row group's column chunks are read on the task's thread, in column
+order (``read_chunks``).  Where the source is client-side
+(``FileSource.client_side``), every buffer of at least
+``POOL_MIN_BYTES`` is handed to one process-wide pool of threads as soon
+as it is read, and a column is decoded as soon as its own buffers are
+inflated, while the later columns' buffers still inflate on the pool.
+An OSD's object-class call inflates every buffer on its own thread: that
+thread is the OSD's CPU budget, and its wall time is the OSD's CPU.
+
 Each stage is a host span (``repro.trace``): ``repro.decode.decompress``
-per buffer, ``repro.decode.host`` per host route (a column's decode, a
+per buffer, on the thread that inflates it (a pool thread or the task's),
+``repro.decode.wait`` per pooled buffer where the task's thread blocks on
+its inflate, ``repro.decode.host`` per host route (a column's decode, a
 predicate, a host ``take``), ``repro.kernel.dict_decode`` /
 ``repro.kernel.predicate`` / ``repro.kernel.pack`` per kernel call, from
 the host casts to the NumPy result, and nested in those
 ``repro.kernel.fetch``, the blocking read of the result from the device.
+A client task's wall time thus holds the decompression that the pool did
+not hide (``repro.decode.wait``), not the inflate of every buffer.
 
 The scheduler prices the two regimes separately: each backend carries a
 ``decode_rate_prior`` (stored bytes per second of decode+filter) that
@@ -42,7 +55,10 @@ seeds the client-side EWMA in ``repro.dataset.scheduler``.
 from __future__ import annotations
 
 import dataclasses
-from typing import Sequence
+import os
+import threading
+from concurrent.futures import Future, ThreadPoolExecutor, wait
+from typing import Iterator, Sequence
 
 import numpy as np
 
@@ -64,6 +80,14 @@ _KERNEL_OPS = {"<": "lt", "<=": "le", ">": "gt", ">=": "ge",
 #: and f32 always, 32/64-bit ints only inside the f32-exact domain —
 #: checked against the live values.  float64 would truncate, so: host.
 _KERNEL_TYPES = ("int32", "int64", "float32", "bool")
+
+#: Compressed size from which a buffer of a client-side read is inflated on
+#: the pool rather than on the task's thread.  A hand-off to an idle pool
+#: and back costs about 56 us, and one core inflates about 65 MB of ZLIB
+#: input a second (both measured on an 8-core Xeon host): at 64 KiB the
+#: hand-off is about 5% of the inflate it moves, and the smaller buffers
+#: (dictionaries, validity bitmaps) stay on the task's thread.
+POOL_MIN_BYTES = 64 << 10
 
 
 def n_data_buffers(field_type: str, encoding: str) -> int:
@@ -100,20 +124,94 @@ class ChunkData:
                              )[:self.num_rows].astype("?")
 
 
-def read_chunk(src, meta, rg, name: str) -> ChunkData:
-    """Read + decompress one column chunk (``meta``/``rg`` are the
-    ``parquet.FileMeta``/``RowGroupMeta`` footer objects, duck-typed so
-    this module never imports the file format)."""
-    field = meta.schema.field(name)
-    chunk = rg.chunks[meta.schema.index(name)]
-    bufs = []
-    off = chunk.offset
-    for ln in chunk.buffer_lengths:
-        raw = src.read(off, ln)
-        with span("repro.decode.decompress"):
-            bufs.append(compression.decompress(chunk.codec, raw))
-        off += ln
-    return ChunkData(field, chunk.encoding, bufs, rg.num_rows)
+def _inflate(codec: str, raw: bytes) -> bytes:
+    with span("repro.decode.decompress"):
+        return compression.decompress(codec, raw)
+
+
+_pool: ThreadPoolExecutor | None = None
+_pool_lock = threading.Lock()
+
+
+def decompress_pool() -> ThreadPoolExecutor:
+    """The process-wide pool that inflates client-side buffers, one thread
+    per core this process may run on; made on first use."""
+    global _pool
+    with _pool_lock:
+        if _pool is None:
+            _pool = ThreadPoolExecutor(len(os.sched_getaffinity(0)),
+                                       thread_name_prefix="repro-inflate")
+        return _pool
+
+
+def _forget_pool():
+    # a forked child has none of its parent's pool threads
+    global _pool, _pool_lock
+    _pool, _pool_lock = None, threading.Lock()
+
+
+os.register_at_fork(after_in_child=_forget_pool)
+
+
+def _pooled(chunk, ln: int) -> bool:
+    return chunk.codec != compression.NONE and ln >= POOL_MIN_BYTES
+
+
+def read_chunks(src, meta, rg, names: Sequence[str],
+                report: dict | None = None) -> Iterator[ChunkData]:
+    """Read + decompress the column chunks ``names`` of one row group,
+    yielding each in that order as soon as its buffers are inflated
+    (``meta``/``rg`` are the ``parquet.FileMeta``/``RowGroupMeta`` footer
+    objects, duck-typed so this module never imports the file format).
+
+    Every byte range is read on this thread first.  Where ``src`` is
+    client-side and at least two buffers reach ``POOL_MIN_BYTES``, those
+    are inflated on ``decompress_pool()``, each submitted as it is read;
+    every other buffer is inflated here when its column is yielded.
+    ``report``, when given, gets ``"decompress"``: the compressed bytes
+    inflated on the pool and here.  An inflate's error is raised here;
+    the row group's other buffers are then cancelled or waited for, so
+    none is still inflating when the error (or an early ``close()``)
+    leaves this generator."""
+    chunks = [rg.chunks[meta.schema.index(n)] for n in names]
+    pool = None
+    if getattr(src, "client_side", False) and sum(
+            _pooled(c, ln) for c in chunks for ln in c.buffer_lengths) > 1:
+        pool = decompress_pool()
+    sizes = {"pool_bytes": 0, "inline_bytes": 0}
+    pending: list[list] = []        # per chunk, per buffer: bytes or Future
+    try:
+        for c in chunks:
+            bufs = []
+            off = c.offset
+            for ln in c.buffer_lengths:
+                raw = src.read(off, ln)
+                off += ln
+                if pool is not None and _pooled(c, ln):
+                    bufs.append(pool.submit(_inflate, c.codec, raw))
+                    sizes["pool_bytes"] += ln
+                else:
+                    bufs.append(raw)
+                    sizes["inline_bytes"] += ln
+            pending.append(bufs)
+        if report is not None:
+            report["decompress"] = sizes
+        for name, c, bufs in zip(names, chunks, pending):
+            out = []
+            for b in bufs:
+                if isinstance(b, Future):
+                    with span("repro.decode.wait"):
+                        out.append(b.result())
+                else:
+                    out.append(_inflate(c.codec, b))
+            yield ChunkData(meta.schema.field(name), c.encoding, out,
+                            rg.num_rows)
+    finally:
+        futures = [b for bufs in pending for b in bufs
+                   if isinstance(b, Future)]
+        for f in futures:
+            f.cancel()
+        wait(futures)
 
 
 def _host_decode(chunk: ChunkData) -> np.ndarray:
@@ -156,14 +254,18 @@ class DecodeBackend:
                        report: dict | None = None) -> Table:
         """Decode + filter + project one row group (the scan_op payload).
         ``report``, when given, is filled with the per-column / predicate
-        routing this call actually took (kernel vs host fallback)."""
+        routing this call actually took (kernel vs host fallback) and
+        with where its buffers were inflated (``read_chunks``)."""
         names = list(columns) if columns is not None else meta.schema.names
         needed = set(names)
         if predicate is not None:
             needed |= predicate.columns()
         order = sorted(needed, key=meta.schema.index)
-        cols = {n: self.decode_column(read_chunk(src, meta, rg, n))
-                for n in order}
+        chunks = read_chunks(src, meta, rg, order, report)
+        try:
+            cols = {c.field.name: self.decode_column(c) for c in chunks}
+        finally:
+            chunks.close()
         if report is not None:
             report["columns"] = {n: getattr(cols[n], "_decode_route",
                                             "host") for n in order}

@@ -217,7 +217,13 @@ def write_table(table: Table, *, row_group_rows: int = 65536,
 
 
 class RandomAccessSource:
-    """Interface: read(offset, length) -> bytes; size() -> int."""
+    """Interface: read(offset, length) -> bytes; size() -> int.
+
+    ``client_side`` says whether a scan of it runs in a client's task,
+    which may inflate its buffers on the decode plane's shared pool
+    (``decode.read_chunks``); anything else inflates on its own thread."""
+
+    client_side = False
 
     def read(self, offset: int, length: int) -> bytes:
         raise NotImplementedError
@@ -250,8 +256,8 @@ def read_column(src: RandomAccessSource, meta: FileMeta, rg: RowGroupMeta,
                 name: str, backend=None) -> Column:
     """Decode one column chunk through a decode backend (host by
     default — see ``repro.aformat.decode``)."""
-    return decode_mod.resolve_backend(backend).decode_column(
-        decode_mod.read_chunk(src, meta, rg, name))
+    chunk, = decode_mod.read_chunks(src, meta, rg, [name])
+    return decode_mod.resolve_backend(backend).decode_column(chunk)
 
 
 def _n_data_buffers(field_type: str, encoding: str) -> int:

@@ -289,8 +289,11 @@ class ParquetFormat(FileFormat):
                       **legacy):
         """Scan one fragment on the client.  The record's ``cpu_s`` and
         ``client_cpu_s`` are the admitted body's wall time, the host span
-        ``repro.scan.task``: storage reads, decompression, decode and
-        filter, waits on the accelerator and for the GIL."""
+        ``repro.scan.task``: storage reads, the waits for the buffers
+        that the decode plane's pool inflates (``repro.decode.wait``) and
+        the inflates of the small ones, decode and filter, waits on the
+        accelerator and for the GIL.  The pool's inflates run on its own
+        threads, outside this wall time."""
         ctx = resolve_context(ctx, legacy)
         wire = 0
 

@@ -144,6 +144,8 @@ class CephFS:
 class FileSource:
     """RandomAccessSource over a CephFS file (client-side scan path)."""
 
+    client_side = True
+
     def __init__(self, fs: CephFS, path: str,
                  on_read: Callable[[int], None] | None = None):
         self.fs = fs

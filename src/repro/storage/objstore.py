@@ -497,7 +497,11 @@ class ObjectStore:
 class ObjectHandle:
     """File-like random-access view of one object on one OSD — the
     RandomAccessObject of the paper: lets the embedded access library run
-    unmodified against object bytes (implements RandomAccessSource)."""
+    unmodified against object bytes (implements RandomAccessSource).
+    Not ``client_side``: a scan of it runs in the OSD's object-class call
+    and inflates on that call's thread, the OSD's CPU budget."""
+
+    client_side = False
 
     def __init__(self, osd: OSD, name: str):
         self._osd = osd

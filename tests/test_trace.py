@@ -1,7 +1,8 @@
 """Host spans of the scan path (``repro.trace``): the host-only modules
 stay free of jax, and a client scan through the Pallas decode plane,
 traced by the JAX profiler, shows every stage's span nested under its
-task on the scanning thread."""
+task on the scanning thread, but for the inflates of the decode plane's
+pool, which run on the pool's own threads."""
 
 import sys
 from pathlib import Path
@@ -24,7 +25,7 @@ from repro.trace import span  # noqa: E402
 
 #: every span the scan path writes, by layer
 SPANS = {"repro.scan.task", "repro.storage.admit", "repro.storage.read",
-         "repro.decode.decompress", "repro.decode.host",
+         "repro.decode.decompress", "repro.decode.wait", "repro.decode.host",
          "repro.kernel.dict_decode", "repro.kernel.predicate",
          "repro.kernel.pack", "repro.kernel.fetch"}
 
@@ -49,14 +50,18 @@ def test_span_is_the_profilers_annotation_once_jax_is_imported():
 
 @pytest.fixture(scope="module")
 def traced_scans(tmp_path_factory):
-    """Two scans of one row group, a DICT int64 column and a float64
-    one, under the profiler: the float64 predicate stays on the host,
-    the int64 one lowers to the predicate kernel."""
+    """Two scans of one row group, a DICT int64 column and three float64
+    ones, under the profiler: the float64 predicate stays on the host,
+    the int64 one lowers to the predicate kernel.  Two of the float64
+    columns are high-entropy, so their buffers are inflated on the
+    decode plane's pool."""
     rng = np.random.default_rng(3)
-    n = 2_000
+    n = 12_000
     tbl = Table.from_pydict({
         "vendor": rng.integers(1, 7, n).astype(np.int64),
         "distance": np.round(rng.gamma(2.0, 1.5, n), 2),
+        "fare": rng.random(n),
+        "tip": rng.random(n),
     })
     fs = make_cluster(4)
     write_flat(fs, "/t/part.arw", tbl, row_group_rows=n)
@@ -68,7 +73,7 @@ def traced_scans(tmp_path_factory):
     try:
         for pred in (field("distance") > 3.0, field("vendor") >= 3):
             q = ds.query(format="parquet", decode_backend="pallas") \
-                .filter(pred).select("vendor", "distance")
+                .filter(pred).select("vendor", "distance", "fare", "tip")
             out = q.to_table()
             want = pred.evaluate(tbl)
             assert np.array_equal(out.column("vendor").values,
@@ -79,12 +84,23 @@ def traced_scans(tmp_path_factory):
     return [s for s in host if s.name.startswith("repro.")]
 
 
+def on_task_threads(host):
+    threads = {s.thread for s in host if s.name == spans.TASK}
+    return [s for s in host if s.thread in threads]
+
+
 def test_every_stage_span_nests_under_its_task(traced_scans):
     host = traced_scans
     assert {s.name for s in host} == SPANS
     tasks = [s for s in host if s.name == spans.TASK]
     assert len(tasks) == 2
-    for s in host:
+    # the pool's inflates open and close on its own threads, which run
+    # no task, and nothing else does
+    on_tasks = on_task_threads(host)
+    pool = [s for s in host if s not in on_tasks]
+    assert {s.name for s in pool} == {"repro.decode.decompress"}
+    assert len(pool) == 4           # two buffers a scan
+    for s in on_tasks:
         if s.name in (spans.TASK, "repro.storage.admit"):
             continue
         assert any(t.thread == s.thread and t.start_ns <= s.start_ns
@@ -103,12 +119,13 @@ def test_every_stage_span_nests_under_its_task(traced_scans):
 
 
 def test_stage_self_times_add_up_to_the_task(traced_scans):
-    host = traced_scans
+    host = on_task_threads(traced_scans)
     lo = min(s.start_ns for s in host)
     hi = max(s.end_ns for s in host)
     rows = 1_000_000
     got = spans.self_s_per_mrow(
-        host, lo, hi, rows, spans.STAGES | {"task": (spans.TASK,)})
+        host, lo, hi, rows, spans.STAGES | {"task": (spans.TASK,),
+                                            "wait": ("repro.decode.wait",)})
     assert all(v is not None and v >= 0 for v in got.values()), got
     task_s = sum(s.end_ns - s.start_ns for s in host
                  if s.name == spans.TASK) / 1e9
