@@ -7,7 +7,9 @@ sides run (the same-code-at-both-placements principle of ``scan_op``):
 
 ``AggSpec``
     One aggregate: ``(op, column)`` with op in sum/min/max/mean/count
-    (``column=None`` means COUNT(*)).
+    (``column=None`` means COUNT(*)).  The column may be a measure,
+    ``field(a) * field(b)`` (``expressions.Product``): its rows'
+    products, exact in int64 for integer and decimal columns.
 
 ``partial_aggregate(table, specs, group_by=...)``
     Fold a decoded fragment into an :class:`AggState` — optionally hash
@@ -24,6 +26,10 @@ sides run (the same-code-at-both-placements principle of ``scan_op``):
     yields the same result for count/min/max/sum-of-int/mean-of-int;
     float sums can differ in the last ulp across merge orders (inherent
     to float addition, same as any parallel aggregation engine).
+    Decimals are their unscaled integers: a sum of ``decimal64(p,s)`` is a
+    ``decimal64(18,s)``, of a product of ``decimal64(p1,s1)`` and
+    ``decimal64(p2,s2)`` a ``decimal64(18,s1+s2)``, exact, and one that
+    does not fit 18 digits raises ``OverflowError``.
 
 ``partial_from_stats``
     The zero-I/O path: ungrouped, predicate-free count/min/max are
@@ -46,7 +52,9 @@ from typing import Any, Mapping, Sequence
 
 import numpy as np
 
-from repro.aformat.schema import Field, Schema
+from repro.aformat.expressions import Product
+from repro.aformat.schema import (MAX_DECIMAL_PRECISION, Field, Schema,
+                                  decimal64, decimal_params, physical_type)
 from repro.aformat.statistics import ColumnStats
 from repro.aformat.table import Column, Table
 
@@ -64,10 +72,11 @@ class CardinalityError(ValueError):
 
 @dataclasses.dataclass(frozen=True)
 class AggSpec:
-    """One aggregate: op in sum/min/max/mean/count; column=None => rows."""
+    """One aggregate: op in sum/min/max/mean/count over a column name or
+    a ``Product`` measure; column=None => rows."""
 
     op: str
-    column: str | None = None
+    column: str | Product | None = None
 
     def __post_init__(self):
         if self.op not in AGG_OPS:
@@ -77,14 +86,28 @@ class AggSpec:
 
     @property
     def name(self) -> str:
-        return f"{self.op}_{self.column}" if self.column else "count"
+        if self.column is None:
+            return "count"
+        col = self.column.name if isinstance(self.column, Product) \
+            else self.column
+        return f"{self.op}_{col}"
+
+    def columns(self) -> set[str]:
+        if isinstance(self.column, Product):
+            return self.column.columns()
+        return {self.column} if self.column is not None else set()
 
     def to_json(self) -> dict:
-        return {"op": self.op, "column": self.column}
+        col = self.column.to_json() if isinstance(self.column, Product) \
+            else self.column
+        return {"op": self.op, "column": col}
 
     @staticmethod
     def from_json(d: dict) -> "AggSpec":
-        return AggSpec(d["op"], d.get("column"))
+        col = d.get("column")
+        if isinstance(col, dict):
+            col = Product.from_json(col)
+        return AggSpec(d["op"], col)
 
 
 def parse_aggs(aggs) -> list[AggSpec]:
@@ -113,7 +136,7 @@ def needed_columns(specs: Sequence[AggSpec], group_by: str | None,
     in schema order.  A pure COUNT(*) needs one column only to carry the
     row count: a predicate column if filtering, else the narrowest-by-
     position first field."""
-    names = {s.column for s in specs if s.column is not None}
+    names = set().union(*(s.columns() for s in specs))
     if group_by is not None:
         names.add(group_by)
     if not names:
@@ -160,13 +183,82 @@ def _py(v):
     return v
 
 
+def _is_int(type_name: str) -> bool:
+    """Integer-valued storage: ints, bools, dates and decimals."""
+    return type_name != "string" and physical_type(type_name) in _INT_TYPES
+
+
+def _absmax(vals: np.ndarray) -> int:
+    return max(abs(int(vals.min())), abs(int(vals.max()))) if len(vals) \
+        else 0
+
+
+def _int_sum(vals: np.ndarray) -> int:
+    """The exact sum of integer values, as a Python int: int64 sums over
+    blocks too short to overflow."""
+    vals = vals.astype(np.int64, copy=False)
+    block = max(1, (2 ** 63 - 1) // max(_absmax(vals), 1))
+    return sum(int(np.sum(vals[i:i + block], dtype=np.int64))
+               for i in range(0, len(vals), block))
+
+
+def product_type(lhs: str, rhs: str) -> str:
+    """The type of a product of a ``lhs`` and a ``rhs`` column: float64
+    with a float, a ``decimal64(18, s1 + s2)`` with a decimal, else
+    int64."""
+    for t in (lhs, rhs):
+        if t in ("string", "date32"):
+            raise TypeError(f"a measure cannot multiply a {t} column")
+    if "float" in physical_type(lhs) + physical_type(rhs):
+        return "float64"
+    scales = [decimal_params(t) for t in (lhs, rhs)]
+    if scales == [None, None]:
+        return "int64"
+    s = sum(p[1] for p in scales if p is not None)
+    if s > MAX_DECIMAL_PRECISION:
+        raise TypeError(f"product of {lhs} and {rhs}: scale {s} exceeds "
+                        f"{MAX_DECIMAL_PRECISION}")
+    return decimal64(MAX_DECIMAL_PRECISION, s)
+
+
+def measure_type(column, schema: Schema) -> str:
+    """The type of an aggregate's column or ``Product`` measure."""
+    if isinstance(column, Product):
+        return product_type(schema.field(column.lhs).type,
+                            schema.field(column.rhs).type)
+    return schema.field(column).type
+
+
+def _measure(table: Table, column) -> tuple[np.ndarray, np.ndarray | None,
+                                            str]:
+    """(values, validity, type) of an aggregate's column or measure over
+    every row of ``table``.  A product of integer or decimal columns is
+    exact in int64 and raises ``OverflowError`` where it might not fit."""
+    if not isinstance(column, Product):
+        col = table.column(column)
+        return col.values, col.validity, col.field.type
+    a, b = table.column(column.lhs), table.column(column.rhs)
+    ptype = product_type(a.field.type, b.field.type)
+    if ptype == "float64":
+        vals = a.values.astype(np.float64) * b.values.astype(np.float64)
+    else:
+        x, y = a.values.astype(np.int64), b.values.astype(np.int64)
+        if _absmax(x) * _absmax(y) >= 2 ** 63:
+            raise OverflowError(f"{column.name} may overflow int64")
+        vals = x * y
+    valid = a.validity
+    if b.validity is not None:
+        valid = b.validity if valid is None else valid & b.validity
+    return vals, valid, ptype
+
+
 def _sum_scalar(vals: np.ndarray, field_type: str):
     """Exact sums: integer columns accumulate into Python int (no float
     rounding, so merge order can never change the result)."""
     if len(vals) == 0:
         return 0
-    if field_type in _INT_TYPES:
-        return int(np.sum(vals, dtype=np.int64))
+    if _is_int(field_type):
+        return _int_sum(vals)
     return float(np.sum(vals))
 
 
@@ -174,8 +266,10 @@ def _cell_from_values(spec: AggSpec, vals: np.ndarray, field_type: str):
     """One partial cell from the *valid* values of one column."""
     if spec.op == "count":
         return int(len(vals))
-    if field_type == "string" and spec.op not in ("min", "max"):
-        raise TypeError(f"{spec.op} over string column {spec.column!r}")
+    if field_type in ("string", "date32") and spec.op not in ("min",
+                                                               "max"):
+        raise TypeError(f"{spec.op} over {field_type} column "
+                        f"{spec.column!r}")
     if spec.op == "sum":
         return _sum_scalar(vals, field_type)
     if spec.op == "mean":
@@ -275,8 +369,12 @@ class AggState:
             cols.append(_key_column(fields[0], keys))
             fi = 1
         for j, spec in enumerate(self.specs):
+            scale = 0
+            if spec.op == "mean":
+                params = decimal_params(measure_type(spec.column, schema))
+                scale = params[1] if params else 0
             cols.append(_agg_column(fields[fi + j],
-                                    [r[j] for r in rows], spec))
+                                    [r[j] for r in rows], spec, scale))
         return Table(Schema(tuple(fields)), cols)
 
 
@@ -298,12 +396,18 @@ def result_fields(specs: Sequence[AggSpec], group_by: str | None,
         elif s.op == "mean":
             t = "float64"
         elif s.op == "sum":
-            t = "int64" if schema.field(s.column).type in _INT_TYPES \
-                else "float64"
+            t = _sum_type(measure_type(s.column, schema))
         else:
-            t = schema.field(s.column).type
+            t = measure_type(s.column, schema)
         fields.append(Field(s.name, t, nullable=True))
     return fields
+
+
+def _sum_type(type_name: str) -> str:
+    params = decimal_params(type_name)
+    if params is not None:
+        return decimal64(MAX_DECIMAL_PRECISION, params[1])
+    return "int64" if _is_int(type_name) else "float64"
 
 
 def _key_column(field: Field, keys: list) -> Column:
@@ -312,17 +416,24 @@ def _key_column(field: Field, keys: list) -> Column:
     return Column(field, np.asarray(keys, field.numpy_dtype))
 
 
-def _agg_column(field: Field, cells: list, spec: AggSpec) -> Column:
+def _agg_column(field: Field, cells: list, spec: AggSpec,
+                scale: int = 0) -> Column:
+    """The result column of one aggregate; ``scale`` is a mean's decimal
+    scale, by which its unscaled sum is divided."""
     n = len(cells)
     if spec.op == "mean":
         vals = np.empty(n, np.float64)
         valid = np.ones(n, "?")
         for i, (s, c) in enumerate(cells):
             if c:
-                vals[i] = s / c
+                vals[i] = s / c / 10 ** scale
             else:
                 vals[i], valid[i] = 0.0, False
         return Column(field, vals, valid)
+    params = decimal_params(field.type)
+    if params is not None and any(c is not None and abs(c) >= 10 ** params[0]
+                                  for c in cells):
+        raise OverflowError(f"{spec.name} exceeds {field.type}")
     if spec.op in ("min", "max"):
         valid = np.asarray([c is not None for c in cells], "?")
         if field.type == "string":
@@ -356,11 +467,10 @@ def partial_aggregate(table: Table, specs: Sequence[AggSpec],
             if s.column is None:
                 cells.append(int(len(table)))
                 continue
-            col = table.column(s.column)
-            vals = col.values
-            if col.validity is not None:
-                vals = vals[col.validity]
-            cells.append(_cell_from_values(s, vals, col.field.type))
+            vals, validity, ftype = _measure(table, s.column)
+            if validity is not None:
+                vals = vals[validity]
+            cells.append(_cell_from_values(s, vals, ftype))
         return AggState(specs, None, cells=cells, rows=len(table))
 
     key_col = table.column(group_by)
@@ -387,17 +497,22 @@ def _grouped_cells(table: Table, spec: AggSpec, inv: np.ndarray,
     """Per-group partial cells for one aggregate over one fragment."""
     if spec.column is None:             # COUNT(*)
         return np.bincount(inv, minlength=n_groups).tolist()
-    col = table.column(spec.column)
-    vals, ginv = col.values, inv
-    if col.validity is not None:
-        vals, ginv = vals[col.validity], inv[col.validity]
-    ftype = col.field.type
+    vals, validity, ftype = _measure(table, spec.column)
+    ginv = inv
+    if validity is not None:
+        vals, ginv = vals[validity], inv[validity]
     if spec.op == "count":
         return np.bincount(ginv, minlength=n_groups).tolist()
-    if ftype == "string" and spec.op not in ("min", "max"):
-        raise TypeError(f"{spec.op} over string column {spec.column!r}")
+    if ftype in ("string", "date32") and spec.op not in ("min", "max"):
+        raise TypeError(f"{spec.op} over {ftype} column {spec.column!r}")
     if spec.op in ("sum", "mean"):
-        if ftype in _INT_TYPES:
+        if _is_int(ftype) and _absmax(vals) * len(vals) >= 2 ** 63:
+            # int64 partial sums might wrap: exact sums group by group
+            order = np.argsort(ginv, kind="stable")
+            ends = np.searchsorted(ginv[order], np.arange(n_groups + 1))
+            sums = [_int_sum(vals[order[ends[g]:ends[g + 1]]])
+                    for g in range(n_groups)]
+        elif _is_int(ftype):
             acc = np.zeros(n_groups, np.int64)
             np.add.at(acc, ginv, vals.astype(np.int64, copy=False))
             sums = [int(v) for v in acc]
@@ -438,10 +553,13 @@ def stats_answerable(spec: AggSpec, schema: Schema) -> bool:
     min/max except over floats (footer stats skip non-finite values, so
     they cannot speak for a column that may hold ±inf); sum/mean never
     (stats carry no sums)."""
+    if isinstance(spec.column, Product):
+        return False
     if spec.op == "count":
         return True
     if spec.op in ("min", "max"):
-        return schema.field(spec.column).type not in ("float32", "float64")
+        return schema.field(spec.column).physical not in ("float32",
+                                                          "float64")
     return False
 
 

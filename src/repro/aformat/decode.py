@@ -64,7 +64,7 @@ import numpy as np
 
 from repro.aformat import compression, encodings
 from repro.aformat.expressions import And, Cmp, Expr, Not, Or
-from repro.aformat.schema import Field
+from repro.aformat.schema import Field, to_physical
 from repro.aformat.table import Column, Table
 from repro.trace import span
 
@@ -80,6 +80,14 @@ _KERNEL_OPS = {"<": "lt", "<=": "le", ">": "gt", ">=": "ge",
 #: and f32 always, 32/64-bit ints only inside the f32-exact domain —
 #: checked against the live values.  float64 would truncate, so: host.
 _KERNEL_TYPES = ("int32", "int64", "float32", "bool")
+
+
+def _kernel_type(field: Field) -> bool:
+    """Whether a column's stored type can take a kernel route: the one
+    gate of every route, on the physical type, so a date32 or decimal64
+    column routes as the int32 or int64 it is stored as."""
+    return field.physical in _KERNEL_TYPES
+
 
 #: Compressed size from which a buffer of a client-side read is inflated on
 #: the pool rather than on the task's thread.  A hand-off to an idle pool
@@ -217,7 +225,7 @@ def read_chunks(src, meta, rg, names: Sequence[str],
 def _host_decode(chunk: ChunkData) -> np.ndarray:
     """A column chunk's values decoded on the host."""
     with span("repro.decode.host"):
-        return encodings.decode(chunk.field.type, chunk.encoding,
+        return encodings.decode(chunk.field.physical, chunk.encoding,
                                 chunk.data_bufs, chunk.num_rows,
                                 chunk.field.numpy_dtype)
 
@@ -273,11 +281,12 @@ class DecodeBackend:
                 if hasattr(cols[n], "_decode_route"):
                     del cols[n]._decode_route
         tbl = Table(meta.schema.select(order), [cols[n] for n in order])
-        if predicate is not None:
-            mask = np.asarray(self.evaluate_predicate(tbl, predicate,
-                                                      report), "?")
-            tbl = self.compact(tbl, mask, report)
-        return tbl.select(names)
+        if predicate is None:
+            return tbl.select(names)
+        mask = np.asarray(self.evaluate_predicate(tbl, predicate, report),
+                          "?")
+        # a column the predicate alone reads is not compacted
+        return self.compact(tbl.select(names), mask, report)
 
     def describe(self, meta, rg, columns: Sequence[str] | None,
                  predicate: Expr | None) -> str:
@@ -384,7 +393,7 @@ class PallasBackend(DecodeBackend):
         route = "host"
         values = None
         if (chunk.encoding in (encodings.DICT, encodings.DICTP)
-                and chunk.field.type in ("int32", "int64", "float32")):
+                and _kernel_type(chunk.field)):
             from repro.kernels import decode_dictionary
 
             if chunk.encoding == encodings.DICT:
@@ -422,9 +431,10 @@ class PallasBackend(DecodeBackend):
         terms = []
         for leaf in leaves:
             col = tbl.column(leaf.column)
-            if col.field.type not in _KERNEL_TYPES:
+            if not _kernel_type(col.field):
                 return None, f"{leaf.column}:{col.field.type}"
-            if not _f32_exact_scalar(leaf.value):
+            value = to_physical(col.field.type, leaf.value)
+            if not _f32_exact_scalar(value):
                 return None, f"{leaf.column}:value"
             if not _f32_exact_values(col.values):
                 return None, f"{leaf.column}:f32-domain"
@@ -436,7 +446,7 @@ class PallasBackend(DecodeBackend):
                 col_idx[leaf.column] = len(cols)
                 cols.append(col)
             terms.append((col_idx[leaf.column], _KERNEL_OPS[leaf.op],
-                          float(leaf.value)))
+                          float(value)))
         from repro.kernels import build_program
 
         return (build_program(terms, combine, negate), cols), None
@@ -475,7 +485,7 @@ class PallasBackend(DecodeBackend):
         routes = {}
         out_cols = []
         for c in tbl.columns:
-            if (capacity and c.field.type in _KERNEL_TYPES
+            if (capacity and _kernel_type(c.field)
                     and _f32_exact_values(c.values)):
                 with span("repro.kernel.pack"):
                     packed, _ = pack_tokens(c.values, mask, capacity)
@@ -505,8 +515,8 @@ class PallasBackend(DecodeBackend):
             field = meta.schema.field(n)
             chunk = rg.chunks[meta.schema.index(n)]
             ok = (chunk.encoding in (encodings.DICT, encodings.DICTP)
-                  and field.type in ("int32", "int64", "float32"))
-            if ok and field.type != "float32":
+                  and _kernel_type(field))
+            if ok and field.physical != "float32":
                 st = chunk.stats
                 ok = (st.min is not None
                       and max(abs(int(st.min)), abs(int(st.max)))

@@ -4,16 +4,27 @@
 ``Expr.prune(stats)``    -> {ALL, NONE, SOME}: whether a row group can be
 skipped (NONE) or fully taken (ALL) from its footer min/max statistics —
 Parquet predicate pushdown (paper §2.3).
+``Expr.bind(schema)``    -> the same expression with every ``datetime.date``
+or ``decimal.Decimal`` constant converted, exactly, to its column's stored
+value (``schema.to_physical``); a query binds its predicate when it is
+built, so pruning, the wire and the kernels compare plain numbers.
+
+``field(a) * field(b)`` is a :class:`Product`, a measure that an
+aggregate sums (``repro.aformat.aggregate``), not a predicate.
 """
 
 from __future__ import annotations
 
 import base64
 import dataclasses
+import datetime
+import decimal
 import hashlib
 from typing import Any, Mapping
 
 import numpy as np
+
+from repro.aformat.schema import to_physical
 
 ALL, SOME, NONE = "all", "some", "none"
 
@@ -27,6 +38,9 @@ class Expr:
 
     def columns(self) -> set[str]:
         return set()
+
+    def bind(self, schema) -> "Expr":
+        return self
 
     # sugar
     def __and__(self, o):
@@ -75,6 +89,10 @@ _OPS = {
 }
 
 
+#: constants that stand for a stored value of another type
+_LOGICAL = (datetime.date, decimal.Decimal)
+
+
 @dataclasses.dataclass
 class Cmp(Expr):
     op: str
@@ -86,14 +104,22 @@ class Cmp(Expr):
         vals = col.values
         if col.field.type == "string":
             vals = np.asarray([str(v) for v in vals])
-        mask = _OPS[self.op](vals, self.value)
+        mask = _OPS[self.op](vals, to_physical(col.field.type, self.value))
         if col.validity is not None:
             mask = mask & col.validity
         return np.asarray(mask, "?")
 
+    def bind(self, schema):
+        if self.column not in schema.names:
+            return self
+        value = to_physical(schema.field(self.column).type, self.value)
+        return self if value is self.value else Cmp(self.op, self.column,
+                                                    value)
+
     def prune(self, stats):
         st = stats.get(self.column)
-        if st is None:
+        if st is None or isinstance(self.value, _LOGICAL):
+            # stats hold stored values; an unbound constant is not one
             return SOME
         if st.min is not None:
             lo, hi, v = st.min, st.max, self.value
@@ -352,6 +378,10 @@ class And(Expr):
     def columns(self):
         return self.lhs.columns() | self.rhs.columns()
 
+    def bind(self, schema):
+        lhs, rhs = self.lhs.bind(schema), self.rhs.bind(schema)
+        return self if (lhs, rhs) == (self.lhs, self.rhs) else And(lhs, rhs)
+
     def to_json(self):
         return {"kind": "and", "lhs": self.lhs.to_json(),
                 "rhs": self.rhs.to_json()}
@@ -376,6 +406,10 @@ class Or(Expr):
     def columns(self):
         return self.lhs.columns() | self.rhs.columns()
 
+    def bind(self, schema):
+        lhs, rhs = self.lhs.bind(schema), self.rhs.bind(schema)
+        return self if (lhs, rhs) == (self.lhs, self.rhs) else Or(lhs, rhs)
+
     def to_json(self):
         return {"kind": "or", "lhs": self.lhs.to_json(),
                 "rhs": self.rhs.to_json()}
@@ -399,12 +433,43 @@ class Not(Expr):
     def columns(self):
         return self.expr.columns()
 
+    def bind(self, schema):
+        expr = self.expr.bind(schema)
+        return self if expr is self.expr else Not(expr)
+
     def to_json(self):
         return {"kind": "not", "expr": self.expr.to_json()}
 
 
+@dataclasses.dataclass(frozen=True)
+class Product:
+    """A measure: the row-wise product of two columns,
+    ``field(a) * field(b)``, which an aggregate sums exactly
+    (``repro.aformat.aggregate``)."""
+
+    lhs: str
+    rhs: str
+
+    @property
+    def name(self) -> str:
+        return f"{self.lhs}*{self.rhs}"
+
+    def columns(self) -> set[str]:
+        return {self.lhs, self.rhs}
+
+    def to_json(self) -> dict:
+        return {"kind": "mul", "columns": [self.lhs, self.rhs]}
+
+    @staticmethod
+    def from_json(d: dict) -> "Product":
+        if d.get("kind") != "mul":
+            raise ValueError(f"not a measure: {d!r}")
+        return Product(*d["columns"])
+
+
 def field(name: str):
-    """field("x") > 3  -> Cmp(">", "x", 3)."""
+    """field("x") > 3  -> Cmp(">", "x", 3); field("a") * field("b") ->
+    Product("a", "b")."""
     return _FieldRef(name)
 
 
@@ -432,3 +497,9 @@ class _FieldRef:
 
     def isin(self, values):
         return IsIn(self.name, list(values))
+
+    def __mul__(self, other):
+        if not isinstance(other, _FieldRef):
+            raise TypeError("a measure multiplies two columns: "
+                            "field(a) * field(b)")
+        return Product(self.name, other.name)

@@ -99,18 +99,19 @@ class ColumnIndex:
         column, *, bits_per_distinct: int = BITS_PER_DISTINCT
     ) -> "ColumnIndex":
         """Build the index for one column chunk (``column`` is any object
-        with ``.values``, ``.validity`` and ``.field.type``)."""
+        with ``.values``, ``.validity`` and ``.field.physical``)."""
         vals = np.asarray(column.values)
         if column.validity is not None:
             vals = vals[column.validity]
-        kind = value_kind(column.field.type)
+        kind = value_kind(column.field.physical)
         # vectorized canonicalization: schema-typed arrays coerce exactly
         if kind == "i":
             words = _key_words(vals.astype(np.int64))
         elif kind == "f":
             words = _key_words(vals.astype(np.float64))
         else:
-            words = _key_words(np.asarray([str(v) for v in vals], object))
+            # only the distinct words matter: hash each distinct string once
+            words = _key_words(np.asarray(list(set(map(str, vals))), object))
         uniq = np.unique(words)
         distinct = int(len(uniq))
         n = max(1, distinct)

@@ -142,15 +142,16 @@ def encode_row_group(part: Table, codec: str, *, build_indexes: bool = True,
             from repro.aformat import advisor as advisor_mod
 
             advice = advisor_mod.advise_column(
-                col.field.type, col.values, codec)
+                col.field.physical, col.values, codec)
             enc, bufs = advice.encoding, list(advice.buffers)
         else:
-            enc = encodings.choose_encoding(col.field.type, col.values)
+            ptype = col.field.physical
+            enc = encodings.choose_encoding(ptype, col.values)
             try:
-                bufs = encodings.encode(col.field.type, enc, col.values)
+                bufs = encodings.encode(ptype, enc, col.values)
             except ValueError:  # e.g. DELTA overflow found on full data
                 enc = encodings.PLAIN
-                bufs = encodings.encode(col.field.type, enc, col.values)
+                bufs = encodings.encode(ptype, enc, col.values)
         if col.validity is not None:
             bufs.append(np.packbits(col.validity).tobytes())
         comp = [compression.compress(codec, b) for b in bufs]

@@ -239,7 +239,8 @@ def aggregate_client(fmt: FileFormat, fs: CephFS, frag: Fragment,
                      **legacy) -> "tuple[AggState, TaskRecord]":
     """Client-side aggregation over any format's scan path: pull only the
     referenced columns through ``scan_fragment`` and fold them locally
-    (no cardinality bound — the client owns its memory)."""
+    (no cardinality bound — the client owns its memory).  The fold is the
+    host span ``repro.agg.fold``, on the task's thread."""
     ctx = resolve_context(ctx, legacy)
     cols = needed_columns(specs, group_by, schema, predicate)
     # an aggregate folds the fragment's full matching rows — the scan
@@ -247,7 +248,8 @@ def aggregate_client(fmt: FileFormat, fs: CephFS, frag: Fragment,
     scan_ctx = dataclasses.replace(ctx, limit=None)
     tbl, rec = _call_scan(fmt, fs, frag, cols, predicate, scan_ctx)
     t0 = time.perf_counter()
-    state = partial_aggregate(tbl, specs, group_by)
+    with span("repro.agg.fold"):
+        state = partial_aggregate(tbl, specs, group_by)
     fold = time.perf_counter() - t0
     # the fold burns client CPU; it counts toward cpu_s only when the
     # record's `where` IS the client (a pushdown spill keeps its cpu_s as

@@ -49,6 +49,7 @@ from repro.aformat.aggregate import (
     AggSpec,
     AggState,
     DEFAULT_MAX_GROUPS,
+    measure_type,
     needed_columns,
     parse_aggs,
     partial_from_stats,
@@ -1295,10 +1296,15 @@ class Query:
         return self._derive(Project(self._root, tuple(columns)))
 
     def filter(self, predicate: Expr) -> "Query":
-        """Keep rows matching ``predicate``; chained filters AND."""
+        """Keep rows matching ``predicate``; chained filters AND.  A date
+        or decimal constant is converted here to its column's stored
+        value (``Expr.bind``), and one that does not convert exactly
+        raises."""
         self._require_relational("filter()")
         if not isinstance(predicate, Expr):
             raise TypeError("filter() takes an Expr predicate")
+        if self.ds.schema is not None:
+            predicate = predicate.bind(self.ds.schema)
         return self._derive(Filter(self._root, predicate))
 
     def limit(self, n: int) -> "Query":
@@ -1332,7 +1338,7 @@ class Query:
             )
         for s in specs:
             if s.column is not None:
-                self.ds.schema.field(s.column)
+                measure_type(s.column, self.ds.schema)  # validate early
         if group_by is not None:
             self.ds.schema.field(group_by)
         return self._derive(

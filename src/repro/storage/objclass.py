@@ -27,6 +27,7 @@ from repro.aformat.aggregate import (AggSpec, AggState, CardinalityError,
 from repro.aformat.expressions import Expr, NONE
 from repro.aformat.table import Table
 from repro.storage.objstore import ObjectStore, ObjectHandle
+from repro.trace import span
 
 #: agg_op's reply when the group-by bound is exceeded: the client must
 #: fall back to a scan (spill-to-scan).
@@ -127,7 +128,8 @@ def _run_agg(obj: ObjectHandle, meta: parquet.FileMeta,
     """The shared storage-side aggregation kernel: per row group, answer
     from footer stats where provable (ungrouped + no predicate), else
     decode only the referenced columns, filter, and fold into the partial
-    state.  Raises CardinalityError past ``max_groups``."""
+    state (the host span ``repro.agg.fold``).  Raises CardinalityError
+    past ``max_groups``."""
     state = AggState.empty(specs, group_by)
     cols = needed_columns(specs, group_by, meta.schema, pred)
     for rg in metas:
@@ -138,8 +140,9 @@ def _run_agg(obj: ObjectHandle, meta: parquet.FileMeta,
                                       rg.num_rows, meta.schema)
         if part is None:
             t = parquet.scan_row_group(obj, meta, rg, cols, pred)
-            part = partial_aggregate(t, specs, group_by,
-                                     max_groups=max_groups)
+            with span("repro.agg.fold"):
+                part = partial_aggregate(t, specs, group_by,
+                                         max_groups=max_groups)
         state.merge(part)
         if max_groups is not None and state.num_groups > max_groups:
             raise CardinalityError(

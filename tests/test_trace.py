@@ -130,3 +130,39 @@ def test_stage_self_times_add_up_to_the_task(traced_scans):
     task_s = sum(s.end_ns - s.start_ns for s in host
                  if s.name == spans.TASK) / 1e9
     assert sum(got.values()) == pytest.approx(task_s, rel=1e-9)
+
+
+def test_a_traced_aggregate_folds_on_the_tasks_thread(tmp_path):
+    """A client aggregate's fold of its partial state is the span
+    ``repro.agg.fold``, after the scan's task span on the same thread."""
+    from repro.aformat.schema import decimal64, schema
+
+    rng = np.random.default_rng(5)
+    n = 6_000
+    tbl = Table.from_pydict(
+        {"price": rng.integers(90_000, 10_000_000, n),
+         "disc": rng.integers(0, 11, n)},
+        schema(("price", decimal64(15, 2)), ("disc", decimal64(15, 2))))
+    fs = make_cluster(4)
+    for i in range(2):
+        write_flat(fs, f"/agg/{i}/part.arw", tbl, row_group_rows=n)
+    q = dataset(fs, "/agg").query(format="parquet", decode_backend="pallas") \
+        .filter(field("disc") >= 5) \
+        .aggregate([("sum", field("price") * field("disc"))])
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        out = q.to_table()
+    finally:
+        jax.profiler.stop_trace()
+    keep = tbl.column("disc").values >= 5
+    assert out.columns[0].values.tolist() == [2 * int(np.sum(
+        tbl.column("price").values[keep] * tbl.column("disc").values[keep]))]
+    _, host = spans.load(str(tmp_path))
+    folds = [s for s in host if s.name == "repro.agg.fold"]
+    tasks = [s for s in host if s.name == spans.TASK]
+    assert len(folds) == len(tasks) == 2
+    for f in folds:
+        assert any(t.thread == f.thread and t.end_ns <= f.start_ns
+                   for t in tasks), f
