@@ -2,8 +2,11 @@
 
 ``pack_tokens`` is the full TPU Filter analogue: (values, mask, capacity)
 -> (packed[capacity], count).  The expensive data-dependent compaction
-runs in the Pallas kernel per tile; the inter-tile merge is one gather
-computed from the tile-count prefix sum (plain XLA, bandwidth-bound).
+runs in the Pallas kernel per tile; the inter-tile merge (plain XLA) is
+one gather per output slot.  Its source index comes without a search:
+each tile's shift (its flat start less its output base, from the prefix
+sum of the tile counts) is scattered to the slot where its output
+begins, and a running maximum carries it to the tile's other slots.
 """
 
 from __future__ import annotations
@@ -30,15 +33,19 @@ def _pack(bits, mask, capacity: int, interpret: bool):
 
     offsets = jnp.cumsum(counts) - counts            # tile -> global base
     total = jnp.minimum(jnp.sum(counts), capacity)
-    # output slot j comes from tile t(j) = searchsorted(cum, j, right),
-    # local slot j - offsets[t]
+    # Output slot j lies in the last tile t that starts at or before it,
+    # at local slot j - offsets[t], so it reads flat[j + shift[t]] with
+    # shift[t] = t*TILE - offsets[t].  shift never falls from one tile to
+    # the next (a tile holds at most TILE kept rows), so a scatter of the
+    # shifts to their tiles' starts and a running maximum give each slot
+    # its tile's shift: empty tiles share their successor's start and
+    # lose the maximum to it; starts at or past capacity are dropped.
+    shift = jnp.arange(counts.shape[0], dtype=jnp.int32) * TILE - offsets
+    shift = jax.lax.cummax(jnp.zeros(capacity, jnp.int32)
+                           .at[offsets].max(shift, mode="drop"))
     j = jnp.arange(capacity, dtype=jnp.int32)
-    cum = jnp.cumsum(counts)
-    t = jnp.searchsorted(cum, j, side="right").astype(jnp.int32)
-    t = jnp.minimum(t, counts.shape[0] - 1)
-    local = j - offsets[t]
     flat = packed_tiles.reshape(-1)
-    out = jnp.where(j < total, flat[t * TILE + local], 0)
+    out = jnp.where(j < total, flat[j + shift], 0)
     return out, total
 
 

@@ -10,9 +10,11 @@ on TPU, where every shape is static.  The TPU-idiomatic equivalent returns
     packed  = bytes(values) @ sel^T             # MXU matmul compaction
 
   across tiles (ops.py epilogue, plain XLA):
-    per-tile counts are a reduction of the mask; the packed buffers are
-    gathered to their global offsets (cumsum of counts) with one take —
-    cheap, bandwidth-bound.
+    per-tile counts are a reduction of the mask, their exclusive cumsum
+    each tile's output base; each tile's shift (t*TILE - base) is
+    scattered to its base and a running maximum spreads it over the
+    tile's slots, so one take of the packed buffers fills every slot —
+    no per-slot search.
 
 The matmul trick turns data-dependent scatter (which the MXU cannot do)
 into a dense systolic op.  Values travel as the four byte planes of

@@ -151,6 +151,62 @@ def test_pack_tokens_property(n, density, cap):
     np.testing.assert_allclose(np.asarray(got), exp, rtol=1e-6)
 
 
+def _tile_mask(n, densities, seed):
+    """Mask over n rows whose tile t keeps rows at densities[t]."""
+    rng = np.random.default_rng(seed)
+    tiles = -(-n // TP_TILE)
+    dens = np.repeat(np.resize(np.asarray(densities, float), tiles), TP_TILE)
+    return rng.random(n) < dens[:n]
+
+
+def _rows_mask(n, rows):
+    mask = np.zeros(n, bool)
+    mask[list(rows)] = True
+    return mask
+
+
+# (n, mask, capacity): tiles with no kept rows share their successor's
+# start; starts at or past capacity fall outside the output
+_MERGE_EDGES = {
+    "empty_leading": (4 * TP_TILE, _tile_mask(4 * TP_TILE, [0, 0, .5, .5], 1),
+                      4 * TP_TILE),
+    "empty_middle": (5 * TP_TILE, _tile_mask(5 * TP_TILE, [.5, 0, 0, 0, .3], 2),
+                     5 * TP_TILE // 2),
+    "empty_trailing": (4 * TP_TILE + 100,
+                       _tile_mask(4 * TP_TILE + 100, [.5, .5, 0, 0, 0], 3),
+                       4 * TP_TILE + 100),
+    "all_kept_cap_below": (4 * TP_TILE, np.ones(4 * TP_TILE, bool),
+                           TP_TILE + 3),
+    "all_kept_cap_at_tile_start": (4 * TP_TILE, np.ones(4 * TP_TILE, bool),
+                                   2 * TP_TILE),
+    "half_kept_cap_below": (4 * TP_TILE, _tile_mask(4 * TP_TILE, [.5], 8),
+                            3 * TP_TILE // 4),
+    "capacity_1": (3 * TP_TILE, _tile_mask(3 * TP_TILE, [.3], 4), 1),
+    "capacity_1_empty_leading": (3 * TP_TILE,
+                                 _tile_mask(3 * TP_TILE, [0, 0, .3], 5), 1),
+    "n_multiple_of_tile": (3 * TP_TILE, _tile_mask(3 * TP_TILE, [.5], 6),
+                           3 * TP_TILE),
+    "n_tile_minus_1": (TP_TILE - 1, _tile_mask(TP_TILE - 1, [.5], 7), TP_TILE),
+    "n_tile_plus_1": (TP_TILE + 1, _rows_mask(TP_TILE + 1, [0, 7, TP_TILE]),
+                      64),
+    "one_row_last_slot_of_tile": (3 * TP_TILE,
+                                  _rows_mask(3 * TP_TILE, [2 * TP_TILE - 1]),
+                                  4),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_MERGE_EDGES))
+def test_pack_tokens_merge_edges(case):
+    n, mask, cap = _MERGE_EDGES[case]
+    vals = np.random.default_rng(n).integers(-2 ** 31, 2 ** 31, n,
+                                             dtype=np.int64).astype(np.int32)
+    got, cnt = tp_ops.pack_tokens(vals, mask, cap)
+    exp, exp_cnt = pack_ref(vals, mask, cap)
+    assert int(cnt) == exp_cnt
+    got = np.asarray(got)
+    assert got.dtype == exp.dtype and got.tobytes() == exp.tobytes()
+
+
 def test_pack_preserves_order():
     vals = np.arange(2000, dtype=np.int32)
     mask = vals % 3 == 0

@@ -12,6 +12,8 @@ one process at a time may load the TPU library, and every test worker
 imports this file.
 """
 
+import re
+
 import pytest
 
 import jax
@@ -87,3 +89,15 @@ def test_pack_tokens_compiles(one_chip):
                              capacity=1 << 21,
                              interpret=False).compile().as_text()
     assert "tpu_custom_call" in hlo
+
+
+def test_pack_tokens_merge_has_no_loop(one_chip):
+    # the taxi cell's shape: one 900,790-row row group packed to 131,072
+    # slots; the inter-tile merge finds each slot's tile without a search
+    rows = 900_790
+    hlo = tp_ops._pack.lower(_struct((rows,), jnp.int32, one_chip),
+                             _struct((rows,), jnp.bool_, one_chip),
+                             capacity=1 << 17,
+                             interpret=False).compile().as_text()
+    assert "tpu_custom_call" in hlo
+    assert not re.search(r"\swhile\(", hlo)
