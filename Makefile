@@ -1,12 +1,10 @@
-# Tier-1 verification and benchmark entry points (see ROADMAP.md).
+# Tier-1 verification, coverage and lint entry points (see ROADMAP.md).
+# The benchmark is perfbench/run.py (BENCHMARK.json), run on the chip.
 
 PYTHON ?= python
 export PYTHONPATH := src:.:$(PYTHONPATH)
 
-.PHONY: test test-fast test-cov lint bench bench-adaptive bench-aggregate \
-	bench-compact bench-decode bench-encoding bench-fig5 bench-fig6 \
-	bench-hedged \
-	bench-ingest bench-join bench-limit bench-qos bench-smoke deps
+.PHONY: test test-fast test-cov lint deps
 
 test:
 	$(PYTHON) -m pytest -x -q
@@ -30,59 +28,8 @@ test-cov:
 # ruff config lives in ruff.toml (correctness rules everywhere; the
 # format gate ratchets over files added after the lint lane landed)
 lint:
-	ruff check src tests benchmarks
-	ruff format --check src tests benchmarks
+	ruff check src tests
+	ruff format --check src tests
 
 deps:
 	$(PYTHON) -m pip install -r requirements-dev.txt
-
-# CI per-push benchmark lane: small configs, BENCH_*.json artifacts,
-# wall-time regression gate vs benchmarks/bench_baseline.json
-bench-smoke:
-	$(PYTHON) benchmarks/bench_smoke.py
-
-bench: bench-fig5 bench-fig6 bench-adaptive bench-hedged bench-aggregate \
-	bench-limit bench-compact bench-join bench-decode bench-qos \
-	bench-ingest bench-encoding
-
-# multi-tenant QoS: interactive p99 under a hostile bulk fleet, with and
-# without the shared weighted-fair admission plane
-bench-qos:
-	$(PYTHON) benchmarks/multi_tenant.py
-
-# distributed training ingest: sharded checkpointable readers — host-CPU
-# and wire-byte placement comparison, resume exactness, QoS coexistence
-bench-ingest:
-	$(PYTHON) benchmarks/ingest_train.py
-
-# client decode plane: NumPy vs Pallas backends (byte-identity, roofline
-# rates, placement-crossover shift)
-bench-decode:
-	$(PYTHON) benchmarks/decode_backend.py
-
-bench-aggregate:
-	$(PYTHON) benchmarks/aggregate_pushdown.py
-
-bench-compact:
-	$(PYTHON) benchmarks/compaction.py
-
-bench-encoding:
-	$(PYTHON) benchmarks/encoding_advisor.py
-
-bench-join:
-	$(PYTHON) benchmarks/semi_join.py
-
-bench-limit:
-	$(PYTHON) benchmarks/limit_pushdown.py
-
-bench-hedged:
-	$(PYTHON) benchmarks/hedged_straggler.py
-
-bench-fig5:
-	$(PYTHON) benchmarks/fig5_latency_scaling.py
-
-bench-fig6:
-	$(PYTHON) benchmarks/fig6_cpu_utilization.py
-
-bench-adaptive:
-	$(PYTHON) benchmarks/adaptive_scan.py

@@ -41,18 +41,17 @@ import time
 from pathlib import Path
 
 ROOT = Path(__file__).resolve().parent
-sys.path[:0] = [str(ROOT / "src"), str(ROOT)]
+sys.path.insert(0, str(ROOT / "src"))
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402
 
-from benchmarks.common import (build_cluster, bytes_identical,  # noqa: E402
-                               taxi_like_table)
 from repro.aformat import encodings  # noqa: E402
 from repro.aformat.decode import PallasBackend  # noqa: E402
 from repro.aformat.expressions import field  # noqa: E402
+from repro.aformat.table import Table  # noqa: E402
 from repro.configs import get_config  # noqa: E402
-from repro.core import dataset  # noqa: E402
+from repro.core import dataset, make_cluster, write_flat  # noqa: E402
 from repro.ingest import MeshReader, ShardedReader  # noqa: E402
 from repro.launch import train  # noqa: E402
 from repro.launch.compile_cache import use_compile_cache  # noqa: E402
@@ -70,6 +69,63 @@ COLUMNS = ["trip_id", "vendor_id", "passenger_count", "trip_distance",
 TRAIN_ARGS = ["--arch", "gemma3-1b", "--mesh", "local", "--layers", "6",
               "--seq", "2048", "--format", "parquet",
               "--decode-backend", "pallas", "--ckpt-every", "1000000"]
+
+
+def taxi_like_table(n_rows: int, seed: int = 0) -> Table:
+    """A NYC-taxi-shaped table of 17 columns drawn from ``seed``."""
+    rng = np.random.default_rng(seed)
+    return Table.from_pydict({
+        "trip_id": np.arange(n_rows, dtype=np.int64),
+        "vendor_id": rng.integers(1, 3, n_rows).astype(np.int32),
+        "passenger_count": rng.integers(1, 7, n_rows).astype(np.int32),
+        "trip_distance": rng.gamma(1.5, 2.0, n_rows).astype(np.float32),
+        "rate_code": rng.integers(1, 7, n_rows).astype(np.int32),
+        "pu_location": rng.integers(1, 266, n_rows).astype(np.int32),
+        "do_location": rng.integers(1, 266, n_rows).astype(np.int32),
+        "fare_amount": rng.gamma(2.0, 7.5, n_rows).astype(np.float64),
+        "tip_amount": rng.gamma(1.2, 2.5, n_rows).astype(np.float32),
+        "tolls_amount": (rng.random(n_rows) < 0.05).astype(np.float32)
+        * rng.gamma(2.0, 3.0, n_rows).astype(np.float32),
+        "total_amount": rng.gamma(2.2, 8.0, n_rows).astype(np.float64),
+        "payment_type": rng.integers(1, 5, n_rows).astype(np.int32),
+        "extra": rng.choice([0.0, 0.5, 1.0], n_rows).astype(np.float32),
+        "mta_tax": np.full(n_rows, 0.5, np.float32),
+        "congestion": (rng.random(n_rows) < 0.3).astype(np.float32) * 2.5,
+        "airport_fee": (rng.random(n_rows) < 0.1).astype(np.float32) * 1.75,
+        "duration_s": rng.gamma(2.0, 600.0, n_rows).astype(np.float32),
+    })
+
+
+def build_cluster(num_nodes: int, table: Table, *, rows_per_file: int):
+    """Flat layout: one file, one object and one row group per
+    ``rows_per_file`` rows, under ``/taxi``."""
+    fs = make_cluster(num_nodes)
+    n = len(table)
+    for i, start in enumerate(range(0, n, rows_per_file)):
+        part = table.slice(start, min(rows_per_file, n - start))
+        write_flat(fs, f"/taxi/part{i:05d}.arw", part,
+                   row_group_rows=rows_per_file)
+    return fs
+
+
+def bytes_identical(a: Table, b: Table) -> bool:
+    """Bit-exact table equality (stricter than Table.equals): names,
+    dtypes, value bits (strings by value) and validity, column by
+    column."""
+    if a.schema.names != b.schema.names or len(a) != len(b):
+        return False
+    for ca, cb in zip(a.columns, b.columns):
+        if ca.field.type == "string":
+            if list(map(str, ca.values)) != list(map(str, cb.values)):
+                return False
+        elif (ca.values.dtype != cb.values.dtype
+              or ca.values.tobytes() != cb.values.tobytes()):
+            return False
+        if (ca.validity is None) != (cb.validity is None) or (
+                ca.validity is not None
+                and not np.array_equal(ca.validity, cb.validity)):
+            return False
+    return True
 
 
 class RoutingRecorder(PallasBackend):

@@ -32,10 +32,10 @@ def demo_failover():
     corpus = synth_corpus(200, mean_doc_len=200, vocab_size=500, seed=1)
     write_corpus(fs, "/c", corpus, num_shards=4)
     ds = dataset(fs, "/c")
-    want = ds.scanner(format="pushdown", columns=["token"]).to_table()
+    want = ds.query(format="pushdown").select("token").to_table()
     fs.store.fail_osd(0)
     fs.store.fail_osd(5)
-    got = ds.scanner(format="pushdown", columns=["token"]).to_table()
+    got = ds.query(format="pushdown").select("token").to_table()
     assert len(got) == len(want)
     print(f"  2/8 OSDs down, scan still returned {len(got)} rows\n")
 
@@ -50,11 +50,11 @@ def demo_hedging():
     frag = ds.fragments()[0]
     victim = fs.store.primary_of(fs.object_names(frag.path)[frag.obj_idx])
     victim.straggle_factor = 200.0
-    sc = ds.scanner(format=PushdownParquetFormat(hedge_threshold_s=0.005),
-                    columns=["token"])
-    sc.to_table()
-    hedged = sum(1 for t in sc.metrics.tasks if t.hedged)
-    worst = max(t.cpu_s for t in sc.metrics.tasks)
+    q = ds.query(format=PushdownParquetFormat(hedge_threshold_s=0.005)) \
+        .select("token")
+    q.to_table()
+    hedged = sum(1 for t in q.metrics.tasks if t.hedged)
+    worst = max(t.cpu_s for t in q.metrics.tasks)
     print(f"  {hedged} fragment(s) hedged to replicas; worst winning "
           f"task {worst * 1e3:.1f} ms\n")
 

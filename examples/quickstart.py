@@ -43,11 +43,10 @@ def main():
     adaptive = AdaptiveFormat()        # keep one instance: its result
                                        # cache persists across scans
     for fmt in ("parquet", "pushdown", adaptive, adaptive):
-        scanner = ds.scanner(format=fmt,
-                             columns=["trip_id", "fare_amount"],
-                             predicate=predicate)
-        result = scanner.to_table()
-        m = scanner.metrics
+        q = ds.query(format=fmt).filter(predicate) \
+            .select("trip_id", "fare_amount")
+        result = q.to_table()
+        m = q.metrics
         name = fmt if isinstance(fmt, str) else "adaptive"
         print(f"\n[{name}] rows={len(result)} "
               f"pruned={m.fragments_pruned}/{m.fragments_total} fragments")
@@ -64,12 +63,11 @@ def main():
           "and its second scan was served from the columnar result cache.")
 
     # -- aggregate pushdown: ship partial states, not columns ---------------
-    sc = ds.scanner(format="pushdown", predicate=predicate)
-    stats = sc.aggregate(["count", ("sum", "fare_amount"),
-                          ("mean", "fare_amount"),
-                          ("max", "fare_amount")],
-                         group_by="passenger_count")
-    wire = sum(t.wire_bytes for t in sc.metrics.tasks)
+    q = ds.query(format="pushdown").filter(predicate).aggregate(
+        ["count", ("sum", "fare_amount"), ("mean", "fare_amount"),
+         ("max", "fare_amount")], group_by="passenger_count")
+    stats = q.to_table()
+    wire = sum(t.wire_bytes for t in q.metrics.tasks)
     print(f"\nGROUP BY passenger_count via agg_op "
           f"({wire / 1e3:.1f} KB on the wire):")
     for i in range(len(stats)):

@@ -54,8 +54,8 @@ def main():
     # -- fetch each prompt by pushdown scan, submit, run waves ----------------
     t0 = time.perf_counter()
     for pid in range(N_PROMPTS):
-        out = ds.scanner(format="pushdown", columns=["token"],
-                         predicate=field("prompt_id") == pid).to_table()
+        out = ds.query(format="pushdown").filter(
+            field("prompt_id") == pid).select("token").to_table()
         engine.submit(Request(pid, out.column("token").values.astype(
             np.int32), max_new_tokens=12))
     comps = engine.run()

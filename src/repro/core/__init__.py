@@ -9,14 +9,14 @@ placement behind one API.  The pieces:
   register_default_classes     the ObjectClass SDK methods (scan_op, ...)
   CephFS / DirectObjectAccess  POSIX shim + filename->object translation
   write_striped / write_split / write_flat   self-contained-fragment layouts
-  dataset / Query / Scanner    the Dataset API (lazy query plans)
+  dataset / Query              the Dataset API (lazy query plans)
   ParquetFormat                client-side scan      (their baseline)
   PushdownParquetFormat        storage-side scan     (their RADOS Parquet)
   AdaptiveFormat / ScanScheduler   runtime placement from live OSD load,
                                with hedged scans + a columnar result cache
 
 ``make_cluster`` assembles the standard stack used by the examples, tests
-and benchmarks.
+and the ``perfbench`` benchmark.
 """
 
 from __future__ import annotations
@@ -24,7 +24,7 @@ from __future__ import annotations
 from repro.dataset import (AdaptiveFormat, AggSpec, CommitConflict, Dataset,
                            MutableDataset, ParquetFormat,
                            PushdownParquetFormat, Query, ScanScheduler,
-                           Scanner, Shed, TaskContext, TenantRegistry,
+                           Shed, TaskContext, TenantRegistry,
                            TenantSpec, dataset)
 from repro.storage.cephfs import CephFS, DirectObjectAccess
 from repro.storage.layouts import write_flat, write_split, write_striped
@@ -43,7 +43,7 @@ def make_cluster(num_osds: int = 8, *, replication: int = 3,
 
 __all__ = ["AggSpec", "Dataset", "MutableDataset", "CommitConflict",
            "ParquetFormat", "PushdownParquetFormat", "AdaptiveFormat",
-           "Query", "ScanScheduler", "Scanner", "dataset", "CephFS",
+           "Query", "ScanScheduler", "dataset", "CephFS",
            "DirectObjectAccess", "write_flat", "write_split",
            "write_striped", "register_default_classes", "ObjectStore",
            "make_cluster",

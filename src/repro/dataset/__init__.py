@@ -1,7 +1,7 @@
 from repro.aformat.aggregate import AggSpec
 from repro.dataset.admission import (AdmissionController, AdmissionTimeout,
                                      LANES)
-from repro.dataset.dataset import Dataset, Scanner, dataset
+from repro.dataset.dataset import Dataset, dataset
 from repro.dataset.format import (AdaptiveFormat, FileFormat, ParquetFormat,
                                   PushdownParquetFormat, TaskRecord,
                                   resolve_format)
@@ -18,7 +18,7 @@ from repro.dataset.snapshot import (CommitConflict, CompactionReport,
 
 __all__ = ["AdmissionController", "AdmissionTimeout", "LANES", "AggSpec",
            "Dataset", "ScanMetrics",
-           "Scanner", "dataset", "FileFormat", "ParquetFormat",
+           "dataset", "FileFormat", "ParquetFormat",
            "PushdownParquetFormat", "AdaptiveFormat", "TaskRecord",
            "Fragment", "ResultCache", "ScanScheduler", "modeled_latency",
            "Query", "PlanNode", "Scan", "Filter", "Project", "Aggregate",

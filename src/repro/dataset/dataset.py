@@ -1,4 +1,4 @@
-"""Dataset / Scanner — the Arrow Dataset API analogue (paper §2.2).
+"""Dataset — the Arrow Dataset API analogue (paper §2.2).
 
 Discovery maps a CephFS prefix to a list of self-contained Fragments for
 any of the three layouts (flat single-object files, striped, split).
@@ -17,26 +17,17 @@ caller picked:
   the :class:`~repro.dataset.scheduler.ScanScheduler` from live OSD load,
   with hedged storage scans and an LRU columnar result cache (this repo's
   extension past the paper's static-placement limitation).
-
-:class:`Scanner` survives as the eager compatibility wrapper: each of its
-verbs builds the equivalent lazy query and runs it, so every optimization
-written for the plan layer (pruning, projection/limit pushdown, metadata
-rewrites) applies to all verbs at once.
 """
 
 from __future__ import annotations
 
 import struct
-from typing import Iterator, Sequence
 
 from repro.aformat import parquet
-from repro.aformat.aggregate import DEFAULT_MAX_GROUPS
-from repro.aformat.expressions import Expr
 from repro.aformat.schema import Schema
-from repro.aformat.table import Table
-from repro.dataset.format import FileFormat, resolve_format
+from repro.dataset.format import FileFormat
 from repro.dataset.fragment import Fragment
-from repro.dataset.plan import Query, ScanMetrics
+from repro.dataset.plan import Query
 from repro.storage import layouts
 from repro.storage.cephfs import CephFS
 
@@ -69,35 +60,21 @@ class Dataset:
         """Start a lazy query: ``ds.query().select(...).filter(...)
         .limit(n)`` / ``.aggregate(...)`` / ``.count()``, executed via
         ``to_table`` / ``to_batches`` / ``to_scalar`` and inspectable via
-        ``explain()``.  ``format`` picks the placement exactly as in
-        :meth:`scanner`; ``decode_backend`` picks the client-side decode
-        engine (None/"numpy" for the host path, "pallas" for the
-        ``repro.kernels`` accelerator ops) for the "parquet" and
-        "adaptive" formats.  ``tenant`` tags the run for multi-tenant
-        QoS: a tenant name, a :class:`~repro.dataset.qos.TaskContext`
-        (usually ``TenantRegistry.context(name)`` — weight, lane,
-        deadline), or None for the default tenant."""
+        ``explain()``.  ``format`` is a FileFormat instance or one of
+        "parquet" (client-side), "pushdown" (storage-side), "adaptive"
+        (scheduler-placed; pass an ``AdaptiveFormat`` instance instead to
+        keep its result cache warm across queries).  Each run's
+        accounting is ``Query.metrics``.  ``decode_backend`` picks the
+        client-side decode engine (None/"numpy" for the host path,
+        "pallas" for the ``repro.kernels`` accelerator ops) for the
+        "parquet" and "adaptive" formats.  ``tenant`` tags the run for
+        multi-tenant QoS: a tenant name, a
+        :class:`~repro.dataset.qos.TaskContext` (usually
+        ``TenantRegistry.context(name)`` — weight, lane, deadline), or
+        None for the default tenant."""
         return Query(self, format=format, num_threads=num_threads,
                      queue_depth=queue_depth,
                      decode_backend=decode_backend, tenant=tenant)
-
-    def scanner(self, *, format: FileFormat | str = "pushdown",
-                columns: Sequence[str] | None = None,
-                predicate: Expr | None = None,
-                num_threads: int = 16, queue_depth: int = 4,
-                decode_backend=None, tenant=None) -> "Scanner":
-        """Build a Scanner.  ``format`` is a FileFormat instance or one of
-        "parquet" (client-side), "pushdown" (storage-side), "adaptive"
-        (scheduler-placed; pass an ``AdaptiveFormat`` instance instead to
-        keep its result cache warm across scans).  ``decode_backend``
-        picks the client-side decode engine exactly as in :meth:`query`;
-        ``tenant`` tags every verb's run for multi-tenant QoS exactly as
-        in :meth:`query`."""
-        return Scanner(self,
-                       resolve_format(format,
-                                      decode_backend=decode_backend),
-                       columns, predicate, num_threads=num_threads,
-                       queue_depth=queue_depth, tenant=tenant)
 
 
 def _footer_tail_bytes(fs: CephFS, path: str) -> tuple[parquet.FileMeta, int]:
@@ -216,90 +193,3 @@ def _discover_split(fs, index_paths) -> Dataset:
                 rg["file"], 0, 0, rg["num_rows"], stats=rg["stats"],
                 footer=None, client_meta=None, client_rg_index=0))
     return Dataset(fs, schema, frags, layout="split", discovery_bytes=disc)
-
-
-# ---------------------------------------------------------------------------
-# Scanner — eager compatibility wrappers over the lazy query plan
-# ---------------------------------------------------------------------------
-
-
-class Scanner:
-    """Eager facade over :class:`~repro.dataset.plan.Query`.
-
-    Every verb builds the equivalent lazy query, runs it through the one
-    optimizer + streaming executor, and snapshots that execution's
-    :class:`ScanMetrics` into ``self.metrics`` (the last run's record —
-    re-running a verb on the same Scanner never double-counts).  Prefer
-    ``Dataset.query()`` for new code; these verbs stay for the paper's
-    original API shape.
-    """
-
-    def __init__(self, ds: Dataset, fmt: FileFormat,
-                 columns: Sequence[str] | None, predicate: Expr | None, *,
-                 num_threads: int = 16, queue_depth: int = 4, tenant=None):
-        self.ds = ds
-        self.fmt = fmt
-        self.columns = list(columns) if columns is not None else None
-        self.predicate = predicate
-        self.num_threads = num_threads
-        self.queue_depth = queue_depth
-        self.tenant = tenant
-        self.metrics = ScanMetrics(discovery_bytes=ds.discovery_bytes)
-
-    def query(self) -> Query:
-        """The lazy query equivalent to this Scanner's columns/predicate
-        (the verbs below all lower through it)."""
-        q = Query(self.ds, format=self.fmt, num_threads=self.num_threads,
-                  queue_depth=self.queue_depth, tenant=self.tenant)
-        if self.predicate is not None:
-            q = q.filter(self.predicate)
-        if self.columns is not None:
-            q = q.select(self.columns)
-        return q
-
-    def explain(self) -> str:
-        """Render the plan this Scanner's ``to_table`` would run."""
-        return self.query().explain()
-
-    def _run(self, q: Query, result):
-        self.metrics = q.metrics
-        return result
-
-    def to_batches(self, *, max_inflight: int | None = None
-                   ) -> Iterator[Table]:
-        """Stream the scan as an iterator of per-fragment Tables in
-        completion order.  In-flight work is bounded by ``max_inflight``
-        (default: the scanner's ``num_threads``) and driven by
-        consumption: a paused consumer pauses the scan after at most
-        ``max_inflight`` buffered fragments.  Empty fragments are
-        skipped."""
-        q = self.query()
-        batches = q.to_batches(max_inflight=max_inflight)
-        self.metrics = q.metrics      # mutated live as batches stream
-        return batches
-
-    def to_table(self) -> Table:
-        """Materialize the full result (plan order)."""
-        q = self.query()
-        return self._run(q, q.to_table())
-
-    def aggregate(self, aggs, *, group_by: str | None = None,
-                  max_groups: int = DEFAULT_MAX_GROUPS) -> Table:
-        """SUM/MIN/MAX/MEAN/COUNT — optionally GROUP BY one key column —
-        with storage-side partial aggregation (see ``Query.aggregate``):
-        stats-pruned, footer-metadata-answered where provable, fanned out
-        through the shared executor, partial states merged in completion
-        order."""
-        q = self.query().aggregate(aggs, group_by=group_by,
-                                   max_groups=max_groups)
-        return self._run(q, q.to_table())
-
-    def count_rows(self) -> int:
-        """COUNT(*): the degenerate ungrouped aggregate.  Stats-provable
-        fragments are answered from metadata with zero I/O; the rest ship
-        only integers (``rowcount_op`` for the static pushdown format,
-        placement-priced / hedged / result-cached through the scheduler
-        for ``format="adaptive"``); only the client-side format decodes a
-        column to count it."""
-        q = self.query().count()
-        return self._run(q, int(q.to_scalar()))

@@ -11,26 +11,21 @@
                              (``repro.dataset.scheduler``).
 
 Switching the format argument switches the placement — nothing else in the
-Dataset/Scanner API changes (paper §2.2, RadosParquetFileFormat).
+Dataset query API changes (paper §2.2, RadosParquetFileFormat).
 
 Task options travel on one :class:`~repro.dataset.qos.TaskContext` passed
 as the single ``ctx`` argument of ``scan_fragment`` / ``aggregate_fragment``
 / ``execute_task`` — admission controller, live row budget, selectivity
-hint, and the tenant/lane/deadline identity the QoS machinery reads.  The
-old ``admission=`` / ``limit=`` / ``selectivity_hint=`` kwarg tail and
-pre-TaskContext subclass overrides are adapted by a one-release
-compatibility shim that warns (``repro.dataset.qos.resolve_context``).
+hint, and the tenant/lane/deadline identity the QoS machinery reads.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import inspect
 import json
 import threading
 import time
-import warnings
 from typing import Any, Sequence
 
 from repro.aformat import decode as decode_mod
@@ -40,7 +35,7 @@ from repro.aformat.aggregate import (AggSpec, AggState, DEFAULT_MAX_GROUPS,
 from repro.aformat.expressions import Expr
 from repro.aformat.table import Table
 from repro.dataset.fragment import Fragment
-from repro.dataset.qos import TaskContext, resolve_context
+from repro.dataset.qos import TaskContext
 from repro.storage.cephfs import CephFS, DirectObjectAccess, FileSource
 from repro.trace import span
 
@@ -68,61 +63,13 @@ class TaskRecord:
     cached: bool = False  # served from the columnar result cache
 
 
-# -- one-release override shim ------------------------------------------------
-# Format subclasses written before TaskContext declare the old kwarg tail
-# (`admission=`, `limit=`, ...).  The executor detects them by signature
-# (no `ctx` parameter), warns once per class, and calls them old-style
-# with whatever subset of the tail they accept.
-
-_CTX_AWARE: dict[tuple[type, str], bool] = {}
-_LEGACY_WARNED: set[tuple[type, str]] = set()
-
-
-def _takes_ctx(cls: type, name: str) -> bool:
-    key = (cls, name)
-    hit = _CTX_AWARE.get(key)
-    if hit is None:
-        params = inspect.signature(getattr(cls, name)).parameters
-        hit = "ctx" in params
-        _CTX_AWARE[key] = hit
-    return hit
-
-
-def _legacy_call_kwargs(cls: type, name: str, ctx: TaskContext) -> dict:
-    if (cls, name) not in _LEGACY_WARNED:
-        _LEGACY_WARNED.add((cls, name))
-        warnings.warn(
-            f"{cls.__name__}.{name} overrides the pre-TaskContext "
-            f"signature; adapt it to accept `ctx` (this shim is "
-            f"one release only)", DeprecationWarning, stacklevel=4)
-    params = inspect.signature(getattr(cls, name)).parameters
-    kwargs: dict[str, Any] = {}
-    if "admission" in params:
-        kwargs["admission"] = ctx.admission
-    if ctx.limit is not None and "limit" in params:
-        kwargs["limit"] = ctx.limit
-    if ctx.selectivity_hint is not None and "selectivity_hint" in params:
-        kwargs["selectivity_hint"] = ctx.selectivity_hint
-    return kwargs
-
-
-def _call_scan(fmt: "FileFormat", fs: CephFS, frag: Fragment, columns,
-               predicate, ctx: TaskContext):
-    """Dispatch to ``fmt.scan_fragment`` through the override shim."""
-    if _takes_ctx(type(fmt), "scan_fragment"):
-        return fmt.scan_fragment(fs, frag, columns, predicate, ctx)
-    return fmt.scan_fragment(
-        fs, frag, columns, predicate,
-        **_legacy_call_kwargs(type(fmt), "scan_fragment", ctx))
-
-
 class FileFormat:
     """Scan a fragment; returns (Table, TaskRecord).
 
-    ``ctx`` (a :class:`~repro.dataset.qos.TaskContext` or None) carries
-    every task option: the admission controller bounding in-flight
-    fragment operations per storage node, the live row budget, the
-    selectivity hint, and the tenant/lane/deadline QoS identity.  Every
+    ``ctx`` (a :class:`~repro.dataset.qos.TaskContext`) carries every
+    task option: the admission controller bounding in-flight fragment
+    operations per storage node, the live row budget, the selectivity
+    hint, and the tenant/lane/deadline QoS identity.  Every
     format acquires a slot on the node it is about to touch — storage-side
     cls calls and client-side byte pulls alike."""
 
@@ -131,35 +78,29 @@ class FileFormat:
     def scan_fragment(self, fs: CephFS, frag: Fragment,
                       columns: Sequence[str] | None,
                       predicate: Expr | None,
-                      ctx: TaskContext | None = None,
-                      **legacy) -> tuple[Table, TaskRecord]:
+                      ctx: TaskContext) -> tuple[Table, TaskRecord]:
         raise NotImplementedError
 
     def aggregate_fragment(self, fs: CephFS, frag: Fragment,
                            specs: Sequence[AggSpec], group_by: str | None,
                            predicate: Expr | None, *, schema,
                            max_groups: int = DEFAULT_MAX_GROUPS,
-                           ctx: TaskContext | None = None,
-                           **legacy) -> tuple[AggState, TaskRecord]:
+                           ctx: TaskContext) -> tuple[AggState, TaskRecord]:
         """Partial-aggregate one fragment; returns (AggState, TaskRecord).
         ``schema`` is the dataset schema (split-layout fragments carry no
         client-side footer of their own).  The default is the client-side
         path — scan the needed columns, fold locally — so every format
-        answers ``Scanner.aggregate``."""
-        ctx = resolve_context(ctx, legacy)
+        answers ``Query.aggregate``."""
         return aggregate_client(self, fs, frag, specs, group_by,
                                 predicate, schema=schema, ctx=ctx)
 
-    def execute_task(self, fs: CephFS, task,
-                     ctx: TaskContext | None = None, **legacy):
+    def execute_task(self, fs: CephFS, task, ctx: TaskContext):
         """The single physical-task entry point the shared query executor
         routes through: one ``FragmentTask`` in (see ``dataset.plan``),
         one (Table | AggState, TaskRecord) out.  Dispatches to the
         format's ``scan_fragment`` / ``aggregate_fragment`` placement
-        with the task's own limit / selectivity hint folded into ``ctx``
-        (pre-TaskContext subclass overrides go through the one-release
-        shim)."""
-        ctx = resolve_context(ctx, legacy)
+        with the task's own limit / selectivity hint folded into
+        ``ctx``."""
         if task.kind == "scan":
             hint = getattr(task, "selectivity_hint", None)
             if task.limit is not None or hint is not None:
@@ -169,17 +110,11 @@ class FileFormat:
                     else ctx.limit,
                     selectivity_hint=hint if hint is not None
                     else ctx.selectivity_hint)
-            return _call_scan(self, fs, task.fragment, task.columns,
-                              task.predicate, ctx)
-        if _takes_ctx(type(self), "aggregate_fragment"):
-            return self.aggregate_fragment(
-                fs, task.fragment, task.specs, task.group_by,
-                task.predicate, schema=task.schema,
-                max_groups=task.max_groups, ctx=ctx)
+            return self.scan_fragment(fs, task.fragment, task.columns,
+                                      task.predicate, ctx)
         return self.aggregate_fragment(
             fs, task.fragment, task.specs, task.group_by, task.predicate,
-            schema=task.schema, max_groups=task.max_groups,
-            **_legacy_call_kwargs(type(self), "aggregate_fragment", ctx))
+            schema=task.schema, max_groups=task.max_groups, ctx=ctx)
 
     def explain_task(self, fs: CephFS, task) -> str:
         """One-line placement/cache/hedge annotation for ``explain()``."""
@@ -188,7 +123,7 @@ class FileFormat:
 
 def resolve_format(format: "FileFormat | str",
                    decode_backend=None) -> "FileFormat":
-    """Resolve the Scanner/Query ``format`` argument: a FileFormat
+    """Resolve the ``Dataset.query`` ``format`` argument: a FileFormat
     instance passes through; a known name constructs a fresh instance; an
     unknown value raises a ValueError naming the choices.
 
@@ -235,18 +170,16 @@ def count_state(n: int) -> AggState:
 
 def aggregate_client(fmt: FileFormat, fs: CephFS, frag: Fragment,
                      specs, group_by, predicate, *, schema,
-                     ctx: TaskContext | None = None,
-                     **legacy) -> "tuple[AggState, TaskRecord]":
+                     ctx: TaskContext) -> "tuple[AggState, TaskRecord]":
     """Client-side aggregation over any format's scan path: pull only the
     referenced columns through ``scan_fragment`` and fold them locally
     (no cardinality bound — the client owns its memory).  The fold is the
     host span ``repro.agg.fold``, on the task's thread."""
-    ctx = resolve_context(ctx, legacy)
     cols = needed_columns(specs, group_by, schema, predicate)
     # an aggregate folds the fragment's full matching rows — the scan
     # below must not inherit a row budget from the context
     scan_ctx = dataclasses.replace(ctx, limit=None)
-    tbl, rec = _call_scan(fmt, fs, frag, cols, predicate, scan_ctx)
+    tbl, rec = fmt.scan_fragment(fs, frag, cols, predicate, scan_ctx)
     t0 = time.perf_counter()
     with span("repro.agg.fold"):
         state = partial_aggregate(tbl, specs, group_by)
@@ -287,8 +220,7 @@ class ParquetFormat(FileFormat):
     def __init__(self, *, decode_backend=None):
         self.decode_backend = decode_mod.resolve_backend(decode_backend)
 
-    def scan_fragment(self, fs, frag, columns, predicate, ctx=None,
-                      **legacy):
+    def scan_fragment(self, fs, frag, columns, predicate, ctx):
         """Scan one fragment on the client.  The record's ``cpu_s`` and
         ``client_cpu_s`` are the admitted body's wall time, the host span
         ``repro.scan.task``: storage reads, the waits for the buffers
@@ -296,7 +228,6 @@ class ParquetFormat(FileFormat):
         the inflates of the small ones, decode and filter, waits on the
         accelerator and for the GIL.  The pool's inflates run on its own
         threads, outside this wall time."""
-        ctx = resolve_context(ctx, legacy)
         wire = 0
 
         def on_read(n):
@@ -402,10 +333,8 @@ class PushdownParquetFormat(FileFormat):
     def __init__(self, *, hedge_threshold_s: float | None = None):
         self.hedge_threshold_s = hedge_threshold_s
 
-    def scan_fragment(self, fs, frag, columns, predicate, ctx=None,
-                      **legacy):
+    def scan_fragment(self, fs, frag, columns, predicate, ctx):
         # the hint prices placement choices; a static placement ignores it
-        ctx = resolve_context(ctx, legacy)
         doa = DirectObjectAccess(fs)
         payload = scan_payload(frag, columns, predicate, ctx.limit)
         with _admit_fragment(fs, frag, ctx):
@@ -429,14 +358,13 @@ class PushdownParquetFormat(FileFormat):
 
     def aggregate_fragment(self, fs, frag, specs, group_by, predicate, *,
                            schema, max_groups=DEFAULT_MAX_GROUPS,
-                           ctx=None, **legacy):
+                           ctx):
         """``agg_op`` on the storage node: only the serialized partial
         state crosses the wire.  A SPILL reply (cardinality over
         ``max_groups``) falls back to the storage-side *scan* — filtered
         columns ship, the client folds them (spill-to-scan).  The
         degenerate ungrouped COUNT(*) keeps the historic ``rowcount_op``
         contract: a bare integer on the wire, not a partial state."""
-        ctx = resolve_context(ctx, legacy)
         if is_degenerate_count(specs, group_by):
             return self._count_fragment(fs, frag, predicate, ctx)
         doa = DirectObjectAccess(fs)
@@ -542,16 +470,13 @@ class AdaptiveFormat(FileFormat):
                 self._schedulers[id(fs)] = sched
             return sched
 
-    def scan_fragment(self, fs, frag, columns, predicate, ctx=None,
-                      **legacy):
-        ctx = resolve_context(ctx, legacy)
+    def scan_fragment(self, fs, frag, columns, predicate, ctx):
         return self.scheduler_for(fs).scan_fragment(frag, columns,
                                                     predicate, ctx)
 
     def aggregate_fragment(self, fs, frag, specs, group_by, predicate, *,
                            schema, max_groups=DEFAULT_MAX_GROUPS,
-                           ctx=None, **legacy):
-        ctx = resolve_context(ctx, legacy)
+                           ctx):
         return self.scheduler_for(fs).aggregate_fragment(
             frag, specs, group_by, predicate, schema=schema,
             max_groups=max_groups, ctx=ctx)

@@ -1,9 +1,7 @@
 """Lazy query plans: one logical IR, one optimizer, one executor.
 
-Every Scanner verb used to carry its own prune/fan-out body, so each new
-optimization had to be written three times (``to_table``, ``aggregate``,
-``count_rows``).  This module replaces those verb-private paths with a
-declarative pipeline:
+Every verb (``to_table``, ``aggregate``, ``count``) runs through one
+declarative pipeline, so an optimization is written once:
 
 builder (``Dataset.query()``)
     ``ds.query().select(cols).filter(pred).limit(n)`` /

@@ -27,15 +27,13 @@ from __future__ import annotations
 import dataclasses
 import threading
 import time
-import warnings
 from typing import Any
 
 from repro.dataset.admission import (AdmissionController, DEFAULT_LANE,
                                      LANES)
 
 __all__ = ["INGEST_TENANT", "LANES", "Shed", "TaskContext", "TenantRegistry",
-           "TenantSpec", "as_task_context", "ingest_context",
-           "resolve_context"]
+           "TenantSpec", "as_task_context", "ingest_context"]
 
 _UNSET = object()
 
@@ -243,9 +241,8 @@ def ingest_context(registry: TenantRegistry | None = None, *,
 
 
 def as_task_context(value) -> TaskContext:
-    """Normalize the ``tenant=`` argument of ``Dataset.query`` /
-    ``Scanner``: None (default tenant), a tenant name, or a full
-    TaskContext."""
+    """Normalize the ``tenant=`` argument of ``Dataset.query``: None
+    (default tenant), a tenant name, or a full TaskContext."""
     if value is None:
         return TaskContext()
     if isinstance(value, TaskContext):
@@ -255,37 +252,3 @@ def as_task_context(value) -> TaskContext:
     raise TypeError(
         f"tenant= takes a TaskContext, a tenant name, or None; "
         f"got {type(value).__name__}")
-
-
-def resolve_context(ctx=None, legacy: dict | None = None) -> TaskContext:
-    """The one-release compatibility shim behind every format entry
-    point: normalizes ``ctx`` to a TaskContext and adapts the old kwarg
-    tail (``admission=`` / ``limit=`` / ``selectivity_hint=``) — or an
-    AdmissionController passed positionally where ``ctx`` now lives —
-    with a DeprecationWarning."""
-    if ctx is not None and not isinstance(ctx, TaskContext):
-        if hasattr(ctx, "admit_object"):   # old positional admission=
-            warnings.warn(
-                "passing an AdmissionController positionally is "
-                "deprecated; pass a TaskContext (TaskContext(admission=...))",
-                DeprecationWarning, stacklevel=3)
-            ctx = TaskContext(admission=ctx)
-        else:
-            raise TypeError(
-                f"ctx must be a TaskContext or None, "
-                f"got {type(ctx).__name__}")
-    if legacy:
-        unknown = set(legacy) - {"admission", "limit", "selectivity_hint"}
-        if unknown:
-            raise TypeError(
-                f"unexpected keyword arguments {sorted(unknown)}; task "
-                f"options travel on TaskContext")
-        warnings.warn(
-            "the admission=/limit=/selectivity_hint= kwarg tail is "
-            "deprecated; pass one TaskContext instead "
-            "(repro.dataset.qos.TaskContext)",
-            DeprecationWarning, stacklevel=3)
-        ctx = dataclasses.replace(
-            ctx if ctx is not None else TaskContext(),
-            **{k: v for k, v in legacy.items() if v is not None})
-    return ctx if ctx is not None else TaskContext()

@@ -59,7 +59,7 @@ result cache
     stale result can never be served.
 
 The scheduler is exposed through ``AdaptiveFormat`` (``format="adaptive"``
-on :meth:`Dataset.scanner`), so it drops into the existing Dataset API the
+on :meth:`Dataset.query`), so it drops into the existing Dataset API the
 same way the two static placements do.
 """
 
@@ -83,7 +83,7 @@ from repro.dataset.format import (ParquetFormat, TaskRecord, agg_payload,
                                   count_state, is_degenerate_count,
                                   parse_agg_reply, scan_payload)
 from repro.dataset.fragment import Fragment
-from repro.dataset.qos import TaskContext, resolve_context
+from repro.dataset.qos import TaskContext
 from repro.storage.cephfs import CephFS, DirectObjectAccess
 from repro.storage.objstore import ObjectNotFound, OSDDownError
 
@@ -253,8 +253,8 @@ class ScanScheduler:
     """Feedback-controlled fragment placement over one cluster (CephFS).
 
     Thread-safe; intended to be shared across scans so the latency
-    history, rate estimators, and result cache persist (a Scanner is
-    per-query, the scheduler is per-cluster).
+    history, rate estimators, and result cache persist (a query is
+    per-run, the scheduler is per-cluster).
     """
 
     def __init__(self, fs: CephFS, *, net_bw: float = GBE10,
@@ -450,8 +450,7 @@ class ScanScheduler:
     def scan_fragment(self, frag: Fragment,
                       columns: Sequence[str] | None,
                       predicate: Expr | None,
-                      ctx: TaskContext | None = None,
-                      **legacy) -> tuple[Table, TaskRecord]:
+                      ctx: TaskContext) -> tuple[Table, TaskRecord]:
         """Cache lookup -> placement decision -> (hedged) execution.
 
         Returns the same (Table, TaskRecord) contract as a FileFormat, so
@@ -464,7 +463,6 @@ class ScanScheduler:
         are identical either way, so it stays out of the cache key.  The
         tenant identity keys the cache shard and tags the storage call
         for per-tenant load accounting."""
-        ctx = resolve_context(ctx, legacy)
         key = self.cache_key(frag, columns, predicate, ctx.limit)
         ipc = self.cache.get(key, tenant=ctx.tenant)
         if ipc is not None:
@@ -579,15 +577,13 @@ class ScanScheduler:
         return self.cache_key(frag, self._ROWCOUNT_COLS, predicate)
 
     def count_fragment(self, frag: Fragment, predicate: Expr | None,
-                       ctx: TaskContext | None = None,
-                       **legacy) -> tuple[int, TaskRecord]:
+                       ctx: TaskContext) -> tuple[int, TaskRecord]:
         """COUNT(*) for one fragment with the same placement machinery as
         a scan: priced (with the aggregate's tiny result size), hedged,
-        and result-cached — so ``count_rows`` under ``format="adaptive"``
+        and result-cached — so ``Query.count()`` under ``format="adaptive"``
         ships integers, not materialized tables.
 
         Returns (row count, TaskRecord)."""
-        ctx = resolve_context(ctx, legacy)
         if predicate is None:       # metadata answers; no I/O at all
             return frag.num_rows, TaskRecord("client", -1, 0.0, 0, 0.0,
                                              frag.num_rows, cached=True)
@@ -654,16 +650,15 @@ class ScanScheduler:
     def aggregate_fragment(self, frag: Fragment, specs, group_by,
                            predicate, *, schema,
                            max_groups: int = DEFAULT_MAX_GROUPS,
-                           ctx: TaskContext | None = None,
-                           **legacy) -> "tuple[AggState, TaskRecord]":
+                           ctx: TaskContext
+                           ) -> "tuple[AggState, TaskRecord]":
         """Partial aggregation with the full placement machinery: priced
         with the aggregate's few-byte result size (so pushdown wins
         unless storage is badly saturated), hedged past the straggler
         deadline, and result-cached under the version-keyed LRU keyed by
         the aggregate spec.  Returns (AggState, TaskRecord)."""
-        ctx = resolve_context(ctx, legacy)
         if is_degenerate_count(specs, group_by):
-            # the unified executor lowers count_rows to this degenerate
+            # the unified executor lowers count() to this degenerate
             # aggregate; keep the integer-on-the-wire rowcount machinery
             # (placement-priced, hedged, result-cached)
             n, rec = self.count_fragment(frag, predicate, ctx)

@@ -466,10 +466,6 @@ class MutableDataset:
         landing while it plans or streams are invisible to it."""
         return self.as_of().query(**kwargs)
 
-    def scanner(self, **kwargs):
-        """Eager Scanner over a pinned snapshot (see :meth:`query`)."""
-        return self.as_of().scanner(**kwargs)
-
     # -- compaction --------------------------------------------------------
     def compact(
         self,

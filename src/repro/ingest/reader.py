@@ -1,8 +1,8 @@
 """Sharded, checkpointable, elastic training readers over the query plan.
 
-``ShardedReader`` replaces the old ``TokenPipeline`` private scan path:
-instead of hand-rolled fragment pruning and ``scan_fragment`` calls, one
-reader per data-parallel rank
+``ShardedReader`` is the training input path: rather than prune
+fragments and call ``scan_fragment`` itself, one reader per data-parallel
+rank
 
 1. pins its source — a ``MutableDataset`` is materialized via
    ``as_of()`` so commits landing mid-run stay invisible and a restore
@@ -83,12 +83,14 @@ def epoch_order(state: ReaderState,
 class Prefetcher:
     """Double-buffered background prefetch (compute/IO overlap).
 
-    Unlike its predecessor in ``data/pipeline.py``, an abandoned
-    Prefetcher no longer leaks its thread: ``close()`` (also via
-    ``with`` or GC) wakes a producer parked on a full queue, joins it,
-    and closes the source generator so scan resources unwind."""
+    An abandoned Prefetcher does not leak its thread: ``close()`` (also
+    via ``with`` or GC) wakes a producer parked on a full queue, joins
+    it, and closes the source generator so scan resources unwind.  A
+    consumer blocked in ``next()`` on another thread wakes with
+    ``StopIteration`` within one poll of ``close()``."""
 
     _SENTINEL = object()
+    _POLL_S = 0.05      # how long a blocked put/get waits between checks
 
     def __init__(self, it: Iterator, depth: int = 2):
         self._q: queue.Queue = queue.Queue(maxsize=max(1, depth))
@@ -103,7 +105,7 @@ class Prefetcher:
         """Bounded put that aborts when the consumer closed us."""
         while not self._stop.is_set():
             try:
-                self._q.put(item, timeout=0.05)
+                self._q.put(item, timeout=self._POLL_S)
                 return True
             except queue.Full:
                 continue
@@ -123,14 +125,20 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        if self._stop.is_set():
-            raise StopIteration
-        item = self._q.get()
-        if item is self._SENTINEL:
-            if self._err is not None:
-                raise self._err
-            raise StopIteration
-        return item
+        # short waits, so a close() on another thread (which drains the
+        # queue, sentinel included) cannot leave this consumer parked
+        while not self._stop.is_set():
+            try:
+                item = self._q.get(timeout=self._POLL_S)
+            except queue.Empty:
+                continue
+            if item is self._SENTINEL:
+                self._q.put_nowait(item)   # stays exhausted on the next call
+                if self._err is not None:
+                    raise self._err
+                raise StopIteration
+            return item
+        raise StopIteration
 
     def close(self):
         """Unblock and join the producer thread; idempotent."""

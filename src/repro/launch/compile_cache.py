@@ -1,8 +1,8 @@
 """JAX's persistent compilation cache, at a place the next run finds again.
 
 Every entry point (``chip_smoke.py``, ``repro.launch.train``,
-``repro.launch.serve``, ``benchmarks.run``) calls :func:`use_compile_cache`
-before it compiles anything.  When ``JAX_COMPILATION_CACHE_DIR`` is set,
+``repro.launch.serve``) calls :func:`use_compile_cache` before it compiles
+anything.  When ``JAX_COMPILATION_CACHE_DIR`` is set,
 JAX reads that directory itself and nothing else is set here.  Otherwise
 the cache lives at one fixed, git-ignored path inside the checkout: the
 path is part of the cache key, so a temporary or per-process directory

@@ -3,8 +3,8 @@
 Functions, not module-level constants: importing this module never touches
 jax device state.  The dry-run process sets
 ``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before importing
-jax so 512 placeholder CPU devices exist; smoke tests and benchmarks see
-the default single device.
+jax so 512 placeholder CPU devices exist; smoke tests and the scan path
+see the default single device.
 """
 
 from __future__ import annotations

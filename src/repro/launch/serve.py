@@ -76,10 +76,10 @@ def main() -> int:
     t0 = time.perf_counter()
     wire = 0
     for i in range(args.requests):
-        sc = ds.scanner(format="pushdown", columns=["token"],
-                        predicate=field("prompt_id") == i)
-        prompt = sc.to_table().column("token").values.astype(np.int32)
-        wire += sc.metrics.wire_bytes
+        q = ds.query(format="pushdown").filter(
+            field("prompt_id") == i).select("token")
+        prompt = q.to_table().column("token").values.astype(np.int32)
+        wire += q.metrics.wire_bytes
         engine.submit(Request(i, prompt, max_new_tokens=args.max_new))
     comps = engine.run()
     dt = time.perf_counter() - t0

@@ -129,7 +129,7 @@ class OSD:
                                      # the per-tenant load signal behind
                                      # lane-visible placement pricing
         self.background_load = 0     # simulated external clients' in-flight
-                                     # cls calls (multi-tenant benchmarks)
+                                     # cls calls (saturation in tests)
         self._cls_sem = threading.BoundedSemaphore(max(1, threads))
 
     def _check(self):

@@ -1,4 +1,4 @@
-"""Aggregate pushdown: Scanner.aggregate correctness across every
+"""Aggregate pushdown: Query.aggregate correctness across every
 (layout x format x predicate) cell, vs a NumPy reference on the
 materialized table.
 
@@ -52,6 +52,13 @@ def populated(request, taxi_table):
         WRITERS[request.param](fs, f"/d/part{i}.arw", part,
                                row_group_rows=1024)
     return fs, taxi_table, request.param
+
+
+def _filtered(ds, fmt, name):
+    """A 4-thread query over ``ds`` filtered by ``PREDICATES[name]``."""
+    q = ds.query(format=fmt, num_threads=4)
+    pred = PREDICATES[name]
+    return q if pred is None else q.filter(pred)
 
 
 def _mask(tbl, name):
@@ -109,9 +116,7 @@ def _check_row(out, row, ref):
 def test_ungrouped_matches_numpy(populated, fmt, pred_name):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    sc = ds.scanner(format=fmt, predicate=PREDICATES[pred_name],
-                    num_threads=4)
-    out = sc.aggregate(AGGS)
+    out = _filtered(ds, fmt, pred_name).aggregate(AGGS).to_table()
     assert len(out) == 1
     _check_row(out, 0, _reference_ungrouped(tbl, _mask(tbl, pred_name)))
 
@@ -121,9 +126,8 @@ def test_ungrouped_matches_numpy(populated, fmt, pred_name):
 def test_grouped_matches_numpy(populated, fmt, pred_name):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    sc = ds.scanner(format=fmt, predicate=PREDICATES[pred_name],
-                    num_threads=4)
-    out = sc.aggregate(AGGS, group_by="passenger_count")
+    out = _filtered(ds, fmt, pred_name).aggregate(
+        AGGS, group_by="passenger_count").to_table()
     mask = _mask(tbl, pred_name)
     keys = tbl.column("passenger_count").values[mask]
     uk = np.unique(keys)
@@ -137,8 +141,8 @@ def test_grouped_matches_numpy(populated, fmt, pred_name):
 def test_string_group_key(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    out = ds.scanner(format=fmt, num_threads=4).aggregate(
-        ["count", ("sum", "trip_id")], group_by="payment_type")
+    out = ds.query(format=fmt, num_threads=4).aggregate(
+        ["count", ("sum", "trip_id")], group_by="payment_type").to_table()
     pay = np.asarray([str(v) for v in
                       tbl.column("payment_type").values])
     uk = sorted(set(pay))
@@ -159,15 +163,15 @@ def test_string_group_key(populated, fmt):
 def test_all_pruned_dataset(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    sc = ds.scanner(format=fmt, predicate=field("fare_amount") < -5.0)
-    out = sc.aggregate(AGGS)
-    assert sc.metrics.fragments_pruned == sc.metrics.fragments_total
-    assert not sc.metrics.tasks                  # zero I/O of any kind
+    q = ds.query(format=fmt).filter(field("fare_amount") < -5.0) \
+        .aggregate(AGGS)
+    out = q.to_table()
+    assert q.metrics.fragments_pruned == q.metrics.fragments_total
+    assert not q.metrics.tasks                   # zero I/O of any kind
     _check_row(out, 0, _reference_ungrouped(tbl, np.zeros(len(tbl), "?")))
     # grouped: no rows -> no groups
-    g = ds.scanner(format=fmt,
-                   predicate=field("fare_amount") < -5.0).aggregate(
-        AGGS, group_by="passenger_count")
+    g = ds.query(format=fmt).filter(field("fare_amount") < -5.0) \
+        .aggregate(AGGS, group_by="passenger_count").to_table()
     assert len(g) == 0
     assert g.schema.names[0] == "passenger_count"
 
@@ -179,9 +183,9 @@ def test_empty_after_scan_not_prunable(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     pred = (field("trip_id") > 4998) & (field("trip_id") < 4999)
-    sc = ds.scanner(format=fmt, predicate=pred)
-    out = sc.aggregate(AGGS)
-    assert sc.metrics.tasks                      # something was scanned
+    q = ds.query(format=fmt).filter(pred).aggregate(AGGS)
+    out = q.to_table()
+    assert q.metrics.tasks                       # something was scanned
     _check_row(out, 0, _reference_ungrouped(tbl, np.zeros(len(tbl), "?")))
 
 
@@ -197,12 +201,12 @@ def test_metadata_only_aggregates_never_touch_storage(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     calls_before = sum(o.stats.cls_calls for o in fs.store.osds)
-    sc = ds.scanner(format=fmt)
-    out = sc.aggregate(["count", ("min", "trip_id"), ("max", "trip_id"),
-                        ("max", "payment_type"),
-                        ("count", "fare_amount")])
+    q = ds.query(format=fmt).aggregate(
+        ["count", ("min", "trip_id"), ("max", "trip_id"),
+         ("max", "payment_type"), ("count", "fare_amount")])
+    out = q.to_table()
     assert sum(o.stats.cls_calls for o in fs.store.osds) == calls_before
-    assert all(t.wire_bytes == 0 for t in sc.metrics.tasks)
+    assert all(t.wire_bytes == 0 for t in q.metrics.tasks)
     assert out.column("count").values[0] == len(tbl)
     assert out.column("min_trip_id").values[0] == 0
     assert out.column("max_trip_id").values[0] == len(tbl) - 1
@@ -215,9 +219,9 @@ def test_float_minmax_not_answered_from_stats(populated):
     real data (stats would lie for a column holding inf)."""
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    sc = ds.scanner(format="pushdown")
-    out = sc.aggregate([("min", "fare_amount")])
-    assert sc.metrics.tasks                      # storage was consulted
+    q = ds.query(format="pushdown").aggregate([("min", "fare_amount")])
+    out = q.to_table()
+    assert q.metrics.tasks                       # storage was consulted
     assert out.column("min_fare_amount").values[0] == pytest.approx(
         float(tbl.column("fare_amount").values.min()), rel=1e-12)
 
@@ -227,14 +231,14 @@ def test_grouped_pushdown_ships_partial_states_not_columns(populated):
     magnitude less than materializing the same fragments."""
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    scan = ds.scanner(format="pushdown",
-                      columns=["passenger_count", "fare_amount",
-                               "trip_id"])
+    scan = ds.query(format="pushdown").select(
+        "passenger_count", "fare_amount", "trip_id")
     scan.to_table()
     scan_wire = sum(t.wire_bytes for t in scan.metrics.tasks)
-    agg = ds.scanner(format="pushdown")
-    agg.aggregate(["count", ("sum", "fare_amount"), ("sum", "trip_id")],
-                  group_by="passenger_count")
+    agg = ds.query(format="pushdown").aggregate(
+        ["count", ("sum", "fare_amount"), ("sum", "trip_id")],
+        group_by="passenger_count")
+    agg.to_table()
     agg_wire = sum(t.wire_bytes for t in agg.metrics.tasks)
     assert agg_wire * 20 < scan_wire             # >20x reduction
 
@@ -257,17 +261,17 @@ def test_striped_adaptive_grouped_exact_and_under_5pct_wire(taxi_table):
     mask = tbl.column("fare_amount").values > 20.0
 
     fmt = AdaptiveFormat()
-    full = ds.scanner(format=fmt, predicate=pred, num_threads=4)
+    full = ds.query(format=fmt, num_threads=4).filter(pred)
     full.to_table()
     table_wire = sum(t.wire_bytes for t in full.metrics.tasks)
 
-    sc = ds.scanner(format=AdaptiveFormat(), predicate=pred,
-                    num_threads=4)
-    out = sc.aggregate(["count", ("sum", "trip_id"),
-                        ("mean", "passenger_count"),
-                        ("min", "trip_id"), ("max", "trip_id")],
-                       group_by="passenger_count")
-    agg_wire = sum(t.wire_bytes for t in sc.metrics.tasks)
+    q = ds.query(format=AdaptiveFormat(), num_threads=4).filter(pred) \
+        .aggregate(["count", ("sum", "trip_id"),
+                    ("mean", "passenger_count"),
+                    ("min", "trip_id"), ("max", "trip_id")],
+                   group_by="passenger_count")
+    out = q.to_table()
+    agg_wire = sum(t.wire_bytes for t in q.metrics.tasks)
 
     # exact NumPy reference (integer partials are exact in any order)
     keys = tbl.column("passenger_count").values[mask]
@@ -300,21 +304,21 @@ def test_cardinality_spill_falls_back_to_scan(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     fmt_obj = AdaptiveFormat() if fmt == "adaptive" else fmt
-    sc = ds.scanner(format=fmt_obj, num_threads=4)
-    out = sc.aggregate([("count", None)], group_by="trip_id",
-                       max_groups=64)
+    q = ds.query(format=fmt_obj, num_threads=4).aggregate(
+        [("count", None)], group_by="trip_id", max_groups=64)
+    out = q.to_table()
     assert len(out) == len(tbl)                  # every trip_id distinct
     assert np.array_equal(out.column("trip_id").values,
                           np.arange(len(tbl), dtype=np.int64))
     assert np.all(out.column("count").values == 1)
     # the spill path ran client-side folds, not agg_op replies
     assert any(t.where == "client" or t.wire_bytes > 1000
-               for t in sc.metrics.tasks)
+               for t in q.metrics.tasks)
     if fmt == "adaptive":
         stats = fmt_obj.stats()
         assert stats["spills"] > 0
         # spills book their final placement once, never twice
-        assert sum(stats["decisions"].values()) == len(sc.metrics.tasks)
+        assert sum(stats["decisions"].values()) == len(q.metrics.tasks)
 
 
 # ---------------------------------------------------------------------------
@@ -331,18 +335,22 @@ def test_adaptive_aggregate_result_cached(taxi_table):
     fmt = AdaptiveFormat()
     pred = field("fare_amount") > 25.0
     aggs = [("sum", "fare_amount"), ("count", None)]
-    first = ds.scanner(format=fmt, predicate=pred, num_threads=4)
-    a = first.aggregate(aggs, group_by="passenger_count")
-    second = ds.scanner(format=fmt, predicate=pred, num_threads=4)
-    b = second.aggregate(aggs, group_by="passenger_count")
+    def query():
+        return ds.query(format=fmt, num_threads=4).filter(pred) \
+            .aggregate(aggs, group_by="passenger_count")
+
+    first = query()
+    a = first.to_table()
+    second = query()
+    b = second.to_table()
     assert second.metrics.cache_hits == len(second.metrics.tasks)
     assert all(t.wire_bytes == 0 for t in second.metrics.tasks)
     assert a.equals(b)
     # an overwrite bumps the version: fragments of that object miss
     name = fs.object_names("/d/part0.arw")[0]
     fs.store.put(name, fs.store.get(name))
-    third = ds.scanner(format=fmt, predicate=pred, num_threads=4)
-    c = third.aggregate(aggs, group_by="passenger_count")
+    third = query()
+    c = third.to_table()
     assert 0 < third.metrics.cache_hits < len(third.metrics.tasks)
     assert a.equals(c)
 
@@ -356,9 +364,9 @@ def test_adaptive_aggregate_saturation_goes_client_side(taxi_table):
         osd.background_load = 64 * osd.threads
     ds = dataset(fs, "/d")
     fmt = AdaptiveFormat()
-    sc = ds.scanner(format=fmt, predicate=field("fare_amount") > 25.0,
-                    num_threads=4)
-    out = sc.aggregate([("count", None)], group_by="passenger_count")
+    out = ds.query(format=fmt, num_threads=4).filter(
+        field("fare_amount") > 25.0).aggregate(
+        [("count", None)], group_by="passenger_count").to_table()
     dec = fmt.stats()["decisions"]
     assert dec["osd"] == 0 and dec["client"] > 0
     exp = (taxi_table.column("fare_amount").values > 25.0)
@@ -370,8 +378,8 @@ def test_aggregate_survives_osd_failure(populated):
     ds = dataset(fs, "/d")
     fs.store.fail_osd(fs.store.osds[0].osd_id)
     fs.store.fail_osd(fs.store.osds[3].osd_id)
-    out = ds.scanner(format="adaptive", num_threads=4).aggregate(
-        [("sum", "trip_id"), ("count", None)])
+    out = ds.query(format="adaptive", num_threads=4).aggregate(
+        [("sum", "trip_id"), ("count", None)]).to_table()
     n = len(tbl)
     assert out.column("count").values[0] == n
     assert out.column("sum_trip_id").values[0] == n * (n - 1) // 2
@@ -395,9 +403,9 @@ def test_aggregate_nullable_column():
     write_flat(fs, "/n/part0.arw", tbl, row_group_rows=512)
     ds = dataset(fs, "/n")
     for fmt in FORMATS:
-        out = ds.scanner(format=fmt).aggregate(
+        out = ds.query(format=fmt).aggregate(
             ["count", ("count", "v"), ("sum", "v"), ("mean", "v")],
-            group_by="k")
+            group_by="k").to_table()
         for gi, k in enumerate(np.unique(keys)):
             m = keys == k
             mv = m & valid
@@ -429,16 +437,18 @@ def test_sum_over_string_raises(populated):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     with pytest.raises(TypeError):
-        ds.scanner(format="parquet").aggregate([("sum", "payment_type")])
+        ds.query(format="parquet").aggregate(
+            [("sum", "payment_type")]).to_table()
 
 
 def test_unknown_column_raises(populated):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     with pytest.raises(KeyError):
-        ds.scanner(format="pushdown").aggregate([("sum", "nope")])
+        ds.query(format="pushdown").aggregate([("sum", "nope")]).to_table()
     with pytest.raises(KeyError):
-        ds.scanner(format="pushdown").aggregate(["count"], group_by="nope")
+        ds.query(format="pushdown").aggregate(
+            ["count"], group_by="nope").to_table()
 
 
 def test_partial_state_roundtrip_and_merge_associativity():

@@ -10,8 +10,8 @@ import numpy as np
 import pytest
 
 from repro.aformat.expressions import field
-from repro.core import (ParquetFormat, PushdownParquetFormat, dataset,
-                        make_cluster, write_flat, write_split, write_striped)
+from repro.core import (dataset, make_cluster, write_flat, write_split,
+                        write_striped)
 
 WRITERS = {"flat": write_flat, "striped": write_striped,
            "split": write_split}
@@ -46,8 +46,8 @@ def test_scan_equivalence(populated, fmt):
     pred = (field("fare_amount") > 25.0) & (field("passenger_count") >= 4)
     mask = ((tbl.column("fare_amount").values > 25.0)
             & (tbl.column("passenger_count").values >= 4))
-    out = ds.scanner(format=fmt, columns=["trip_id", "fare_amount"],
-                     predicate=pred, num_threads=4).to_table()
+    out = ds.query(format=fmt, num_threads=4).filter(pred).select(
+        "trip_id", "fare_amount").to_table()
     exp = _expected(tbl, mask, ["trip_id", "fare_amount"])
     # row order may differ across parallel scans: sort by key
     o = np.argsort(out.column("trip_id").values)
@@ -62,10 +62,8 @@ def test_both_placements_agree(populated):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     pred = field("payment_type") == "cash"
-    a = ds.scanner(format="parquet", predicate=pred,
-                   num_threads=2).to_table()
-    b = ds.scanner(format="pushdown", predicate=pred,
-                   num_threads=2).to_table()
+    a = ds.query(format="parquet", num_threads=2).filter(pred).to_table()
+    b = ds.query(format="pushdown", num_threads=2).filter(pred).to_table()
     ka = np.sort(a.column("trip_id").values)
     kb = np.sort(b.column("trip_id").values)
     assert np.array_equal(ka, kb)
@@ -76,12 +74,12 @@ def test_pruning_skips_row_groups(populated):
     ds = dataset(fs, "/d")
     # trip_id is monotonically increasing: a range predicate must prune
     pred = field("trip_id") < 1024
-    sc = ds.scanner(format="pushdown", predicate=pred)
-    out = sc.to_table()
+    q = ds.query(format="pushdown").filter(pred)
+    out = q.to_table()
     assert len(out) == 1024
-    assert sc.metrics.fragments_pruned > 0
-    assert sc.metrics.fragments_pruned + len(sc.metrics.tasks) == \
-        sc.metrics.fragments_total
+    assert q.metrics.fragments_pruned > 0
+    assert q.metrics.fragments_pruned + len(q.metrics.tasks) == \
+        q.metrics.fragments_total
 
 
 def test_pushdown_moves_cpu_to_storage(populated):
@@ -97,7 +95,7 @@ def test_pushdown_moves_cpu_to_storage(populated):
     def run(fmt):
         best = None
         for _ in range(3):
-            sc = ds.scanner(format=fmt, columns=cols, num_threads=2)
+            sc = ds.query(format=fmt, num_threads=2).select(cols)
             sc.to_table()
             if best is None or sc.metrics.client_cpu_s < \
                     best.metrics.client_cpu_s:
@@ -118,9 +116,9 @@ def test_pushdown_wire_is_larger_at_full_selectivity(populated):
     """Paper Fig. 5, 100% case: Arrow IPC on the wire > compressed ARW1."""
     fs, tbl, layout = populated
     ds = dataset(fs, "/d")
-    sc_c = ds.scanner(format="parquet", num_threads=2)
+    sc_c = ds.query(format="parquet", num_threads=2)
     sc_c.to_table()
-    sc_p = ds.scanner(format="pushdown", num_threads=2)
+    sc_p = ds.query(format="pushdown", num_threads=2)
     sc_p.to_table()
     assert sc_p.metrics.wire_bytes > sc_c.metrics.wire_bytes
 
@@ -130,15 +128,15 @@ def test_scan_survives_osd_failure(populated):
     ds = dataset(fs, "/d")
     fs.store.fail_osd(fs.store.osds[0].osd_id)
     fs.store.fail_osd(fs.store.osds[3].osd_id)
-    out = ds.scanner(format="pushdown", num_threads=4).to_table()
+    out = ds.query(format="pushdown", num_threads=4).to_table()
     assert len(out) == len(tbl)               # replicas served everything
 
 
 def test_empty_result_schema(populated):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    out = ds.scanner(format="pushdown", columns=["trip_id"],
-                     predicate=field("fare_amount") < -5).to_table()
+    out = ds.query(format="pushdown").filter(
+        field("fare_amount") < -5).select("trip_id").to_table()
     assert len(out) == 0
     assert out.schema.names == ["trip_id"]
 
@@ -151,30 +149,29 @@ def test_count_pushdown_matches_scan(populated):
     pred = field("fare_amount") > 25.0
     exp = int((tbl.column("fare_amount").values > 25.0).sum())
 
-    sc = ds.scanner(format="pushdown", predicate=pred)
-    got = sc.count_rows()
+    q = ds.query(format="pushdown").filter(pred).count()
+    got = q.to_scalar()
     assert got == exp
     # only tiny integer payloads crossed the wire
-    assert all(t.wire_bytes < 64 for t in sc.metrics.tasks)
+    assert all(t.wire_bytes < 64 for t in q.metrics.tasks)
 
     # unfiltered count: pure metadata, zero storage calls
-    sc2 = ds.scanner(format="pushdown")
-    assert sc2.count_rows() == len(tbl)
-    assert not sc2.metrics.tasks
+    q2 = ds.query(format="pushdown").count()
+    assert q2.to_scalar() == len(tbl)
+    assert not q2.metrics.tasks
 
     # range predicate on the monotone column: mix of pruned / ALL / edge
-    sc3 = ds.scanner(format="pushdown", predicate=field("trip_id") < 3000)
-    assert sc3.count_rows() == 3000
-    assert sc3.metrics.fragments_pruned > 0
+    q3 = ds.query(format="pushdown").filter(field("trip_id") < 3000).count()
+    assert q3.to_scalar() == 3000
+    assert q3.metrics.fragments_pruned > 0
     # client format falls back to a materializing count
-    sc4 = ds.scanner(format="parquet", predicate=pred)
-    assert sc4.count_rows() == exp
+    q4 = ds.query(format="parquet").filter(pred).count()
+    assert q4.to_scalar() == exp
 
 
 def test_projection_only(populated):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
-    out = ds.scanner(format="pushdown",
-                     columns=["payment_type"]).to_table()
+    out = ds.query(format="pushdown").select("payment_type").to_table()
     assert out.schema.names == ["payment_type"]
     assert len(out) == len(tbl)

@@ -397,9 +397,9 @@ def flat_ds(taxi_table):
 def test_scanner_decode_backend(flat_ds):
     ds, tbl = flat_ds
     pred = field("passenger_count") >= 4
-    out_np = ds.scanner(format="parquet", predicate=pred).to_table()
-    out_pl = ds.scanner(format="parquet", predicate=pred,
-                        decode_backend="pallas").to_table()
+    out_np = ds.query(format="parquet").filter(pred).to_table()
+    out_pl = ds.query(format="parquet",
+                      decode_backend="pallas").filter(pred).to_table()
     assert_bytes_identical(out_np, out_pl)
     exp = int((tbl.column("passenger_count").values >= 4).sum())
     assert len(out_pl) == exp

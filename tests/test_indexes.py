@@ -306,9 +306,8 @@ def test_index_pruning_soundness_differential(layout, fmt):
         outs = []
         for fs in (fs_a, fs_b):
             ds = dataset(fs, "/d")
-            out = ds.scanner(format=fmt,
-                             predicate=(field("id") == target),
-                             num_threads=2).to_table()
+            out = ds.query(format=fmt, num_threads=2).filter(
+                field("id") == target).to_table()
             outs.append(out)
         for out in outs:
             assert len(out) == expect_rows
@@ -337,12 +336,11 @@ def test_point_lookup_wire_savings_client_format():
     wire = {}
     for name, fs in (("indexed", fs_idx), ("plain", fs_plain)):
         ds = dataset(fs, "/d")
-        sc = ds.scanner(format="parquet",
-                        predicate=(field("id") == target),
-                        num_threads=2)
-        out = sc.to_table()
+        q = ds.query(format="parquet", num_threads=2).filter(
+            field("id") == target)
+        out = q.to_table()
         assert len(out) == 1 and out.column("id").values[0] == target
-        wire[name] = sc.metrics.wire_bytes - sc.metrics.discovery_bytes
+        wire[name] = q.metrics.wire_bytes - q.metrics.discovery_bytes
     assert wire["indexed"] <= 0.10 * wire["plain"], wire
 
 

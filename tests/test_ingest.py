@@ -1,5 +1,6 @@
-"""The ingest plane: deterministic sharding, byte-exact resume through
-the checkpoint layer, elastic re-sharding, and QoS coexistence.
+"""The ingest plane: packing and filtering, deterministic sharding,
+byte-exact resume through the checkpoint layer, elastic re-sharding, QoS
+coexistence, and the background prefetcher.
 
 The contracts under test are what makes the reader trustworthy as the
 training input path: the shard partition is a pure function of the plan
@@ -12,7 +13,9 @@ downsize hands the unconsumed remainder to the survivors exactly once
 bulk tenant that never starves an interactive scanner.
 """
 
+import itertools
 import threading
+import time
 import types
 
 import numpy as np
@@ -25,8 +28,8 @@ from repro.data import synth_corpus, write_corpus
 from repro.dataset.plan import partition_tasks
 from repro.dataset.qos import TenantRegistry, ingest_context
 from repro.distrib import ANY_SHAPE, CheckpointManager, plan_downsize
-from repro.ingest import (ReaderConfig, ReaderState, ShardedReader,
-                          epoch_order, reshard_states)
+from repro.ingest import (Prefetcher, ReaderConfig, ReaderState,
+                          ShardedReader, epoch_order, reshard_states)
 
 FORMATS = ["parquet", "pushdown", "adaptive"]
 
@@ -92,15 +95,17 @@ def test_partition_empty_and_edge_cases():
         partition_tasks([], 0)
 
 
-def test_more_ranks_than_fragments_is_legal(corpus_fs):
-    """The old TokenPipeline crashed here; empty shards must idle."""
+@pytest.mark.parametrize("extra", [3, 5])
+def test_more_ranks_than_fragments_is_legal(corpus_fs, extra):
+    """More ranks than fragments: the starved ranks yield nothing (no
+    crash) and the populated ranks still cover every fragment once."""
     fs, _ = corpus_fs
     ds = dataset(fs, "/c")
     cfg = ReaderConfig(seq_len=32, local_batch=2)
     probe = ShardedReader(ds, cfg)
     n = len(probe.tasks)
     probe.close()
-    dp = n + 5
+    dp = n + extra
     covered = []
     empties = 0
     for rank in range(dp):
@@ -110,8 +115,81 @@ def test_more_ranks_than_fragments_is_legal(corpus_fs):
             empties += 1
             assert list(rd.batches()) == []  # yields nothing, no crash
         rd.close()
-    assert empties == 5
+    assert empties == extra
     assert sorted(covered) == list(range(n))
+
+
+def test_rank_disjoint_and_complete(corpus_fs):
+    fs, _ = corpus_fs
+    ds = dataset(fs, "/c")
+    cfg = ReaderConfig(seq_len=32, local_batch=2)
+    all_frags = {(f.path, f.obj_idx, f.rg_in_object)
+                 for f in ds.fragments()}
+    seen = set()
+    for r in range(4):
+        rd = ShardedReader(ds, cfg, dp_rank=r, dp_size=4)
+        ids = {(t.fragment.path, t.fragment.obj_idx, t.fragment.rg_in_object)
+               for t in rd.shard_tasks}
+        rd.close()
+        assert not ids & seen
+        seen |= ids
+    assert seen == all_frags
+
+
+# ---------------------------------------------------------------------------
+# packing and filtering
+# ---------------------------------------------------------------------------
+
+
+def test_batches_shapes_and_shift(corpus_fs):
+    fs, _ = corpus_fs
+    ds = dataset(fs, "/c")
+    rd = ShardedReader(ds, ReaderConfig(seq_len=64, local_batch=8,
+                                        format="pushdown", num_threads=2))
+    for b in take(rd, 6):
+        assert b["tokens"].shape == (8, 64)
+        assert b["labels"].shape == (8, 64)
+        assert (b["tokens"][:, 1:] == b["labels"][:, :-1]).all()
+        assert b["tokens"].dtype == np.int32
+    rd.close()
+
+
+def test_quality_filter_reduces_stream(corpus_fs):
+    fs, _ = corpus_fs
+    ds = dataset(fs, "/c")
+    r_all = ShardedReader(ds, ReaderConfig(seq_len=64, local_batch=4))
+    r_filt = ShardedReader(ds, ReaderConfig(
+        seq_len=64, local_batch=4, predicate=field("quality") > 0.8))
+    next(r_all.batches())
+    next(r_filt.batches())
+    # the filtered reader ships fewer rows per fragment
+    rows_all = r_all.stats()["rows"] / r_all.stats()["fragments_scanned"]
+    rows_f = r_filt.stats()["rows"] / r_filt.stats()["fragments_scanned"]
+    assert rows_f < rows_all * 0.6
+
+
+def test_filtered_tokens_match_oracle(corpus_fs):
+    """Every token the reader emits must come from a quality>t doc."""
+    fs, tbl = corpus_fs
+    ds = dataset(fs, "/c")
+    cfg = ReaderConfig(seq_len=32, local_batch=2,
+                       predicate=field("quality") > 0.9, seed=5)
+    good = set(tbl.column("token").values[
+        tbl.column("quality").values > 0.9].tolist())
+    rd = ShardedReader(ds, cfg)
+    for b in take(rd, 3):
+        assert set(b["tokens"].ravel().tolist()) <= good
+    rd.close()
+
+
+def test_epoch_determinism(corpus_fs):
+    fs, _ = corpus_fs
+    ds = dataset(fs, "/c")
+    cfg = ReaderConfig(seq_len=32, local_batch=2, seed=11)
+    a, b = ShardedReader(ds, cfg), ShardedReader(ds, cfg)
+    assert_same_batches(take(a, 4), take(b, 4))
+    a.close()
+    b.close()
 
 
 # ---------------------------------------------------------------------------
@@ -433,6 +511,104 @@ def test_reader_context_manager(corpus_fs):
         next(rd)
         thread = rd._prefetcher._thread
     assert not thread.is_alive()  # close() joined the prefetch thread
+
+
+# ---------------------------------------------------------------------------
+# the background prefetcher
+# ---------------------------------------------------------------------------
+
+
+def test_prefetcher_propagates_errors():
+    def gen():
+        yield 1
+        raise RuntimeError("boom")
+
+    p = Prefetcher(gen(), depth=2)
+    assert next(p) == 1
+    with pytest.raises(RuntimeError, match="boom"):
+        next(p)
+
+
+def test_prefetcher_overlap():
+    def slow():
+        for i in range(4):
+            time.sleep(0.02)
+            yield i
+
+    p = Prefetcher(slow(), depth=2)
+    time.sleep(0.1)                     # producer runs ahead while we wait
+    t0 = time.perf_counter()
+    out = list(p)
+    elapsed = time.perf_counter() - t0
+    assert out == [0, 1, 2, 3]
+    assert elapsed < 0.06               # most items were already buffered
+
+
+def test_prefetcher_close_unblocks_producer():
+    """An abandoned iterator must not park its thread on queue.put
+    forever: close() wakes the producer, joins it, and closes the
+    source generator."""
+    closed = []
+
+    def endless():
+        try:
+            for i in itertools.count():
+                yield i
+        finally:
+            closed.append(True)
+
+    p = Prefetcher(endless(), depth=1)
+    assert next(p) == 0                 # producer alive and parked on put
+    p.close()
+    assert not p._thread.is_alive()
+    assert closed == [True]
+    with pytest.raises(StopIteration):  # closed iterator is exhausted
+        next(p)
+    p.close()                           # idempotent
+
+
+def test_prefetcher_context_manager():
+    def gen():
+        while True:
+            yield 1
+
+    with Prefetcher(gen(), depth=1) as p:
+        assert next(p) == 1
+        thread = p._thread
+    assert not thread.is_alive()
+
+
+def test_prefetcher_close_wakes_a_blocked_consumer():
+    """Regression: close() drains the queue, so a consumer parked in
+    next() on another thread used to wait forever for a sentinel that
+    never came.  It must get StopIteration within 1 s of close()."""
+    release = threading.Event()
+
+    def never_yields():
+        release.wait(30.0)
+        yield from ()
+
+    p = Prefetcher(never_yields(), depth=1)
+    outcome = []
+
+    def consume():
+        try:
+            outcome.append(next(p))
+        except StopIteration:
+            outcome.append(time.perf_counter())
+
+    consumer = threading.Thread(target=consume, daemon=True)
+    consumer.start()
+    time.sleep(0.2)                     # the consumer is parked in next()
+    t0 = time.perf_counter()
+    # close() joins the producer, which the source holds until released
+    closer = threading.Thread(target=p.close, daemon=True)
+    closer.start()
+    consumer.join(timeout=1.0)
+    release.set()
+    closer.join(timeout=10.0)
+    assert not consumer.is_alive() and not closer.is_alive()
+    assert len(outcome) == 1 and outcome[0] - t0 < 1.0
 
 
 # ---------------------------------------------------------------------------

@@ -1,12 +1,12 @@
 """The lazy query-plan API: builder IR, optimizer passes, explain, limit
-pushdown, and wrapper equivalence.
+pushdown, and every verb against NumPy.
 
-The tentpole claim: every Scanner verb lowers through ONE logical plan,
+The tentpole claim: every query verb lowers through ONE logical plan,
 ONE optimizer, and ONE streaming executor — so these tests pin (a) the
 builder -> IR mapping, (b) each optimizer pass in isolation, (c) the
 explain() rendering, (d) real end-to-end limit early-exit (fragments past
-the budget are never scanned), and (e) that the compatibility wrappers
-return exactly what the lazy API returns across the layout x format grid.
+the budget are never scanned), and (e) that each verb returns what NumPy
+computes from the source table across the layout x format grid.
 """
 
 import numpy as np
@@ -144,7 +144,7 @@ def test_builder_validation(flat_ds):
 def test_scanner_format_typo_raises_valueerror(flat_ds):
     fs, ds, _ = flat_ds
     with pytest.raises(ValueError, match="parquet"):
-        ds.scanner(format="typo")
+        ds.query(format="typo")
     with pytest.raises(ValueError, match="adaptive"):
         ds.query(format=42)
 
@@ -377,7 +377,7 @@ def test_limit_streams_through_to_batches(flat_ds):
 
 
 # ---------------------------------------------------------------------------
-# wrapper equivalence: every Scanner verb == its query() lowering
+# every verb == NumPy over the source table
 # ---------------------------------------------------------------------------
 
 
@@ -390,16 +390,11 @@ def test_wrapper_equivalence_to_table(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     pred = field("fare_amount") > 25.0
-    sc = ds.scanner(format=fmt, columns=["trip_id"], predicate=pred)
-    via_scanner = sc.to_table()
-    via_query = (
-        ds.query(format=fmt).filter(pred).select("trip_id").to_table()
-    )
-    assert via_scanner.schema.names == via_query.schema.names
-    assert np.array_equal(_sorted_ids(via_scanner), _sorted_ids(via_query))
+    out = ds.query(format=fmt).filter(pred).select("trip_id").to_table()
+    assert out.schema.names == ["trip_id"]
     mask = tbl.column("fare_amount").values > 25.0
     assert np.array_equal(
-        _sorted_ids(via_scanner),
+        _sorted_ids(out),
         np.sort(tbl.column("trip_id").values[mask]),
     )
 
@@ -411,12 +406,12 @@ def test_wrapper_equivalence_to_batches(populated, fmt):
     fs, tbl, _ = populated
     ds = dataset(fs, "/d")
     pred = field("fare_amount") > 30.0
-    sc = ds.scanner(format=fmt, columns=["trip_id"], predicate=pred)
-    streamed = Table.concat(list(sc.to_batches()))
-    materialized = (
-        ds.query(format=fmt).filter(pred).select("trip_id").to_table()
-    )
+    q = ds.query(format=fmt).filter(pred).select("trip_id")
+    streamed = Table.concat(list(q.to_batches()))
+    materialized = q.to_table()
     assert np.array_equal(_sorted_ids(streamed), _sorted_ids(materialized))
+    mask = tbl.column("fare_amount").values > 30.0
+    assert len(streamed) == int(mask.sum())
 
 
 @pytest.mark.parametrize("fmt", FORMATS)
@@ -425,20 +420,36 @@ def test_wrapper_equivalence_aggregate(populated, fmt):
     ds = dataset(fs, "/d")
     pred = field("fare_amount") > 25.0
     aggs = ["count", ("sum", "fare_amount"), ("mean", "fare_amount")]
-    a = ds.scanner(format=fmt, predicate=pred).aggregate(
-        aggs, group_by="passenger_count"
+    out = (
+        ds.query(format=fmt)
+        .filter(pred)
+        .aggregate(aggs, group_by="passenger_count")
+        .to_table()
     )
-    q = ds.query(format=fmt).filter(pred)
-    b = q.aggregate(aggs, group_by="passenger_count").to_table()
-    assert a.schema.names == b.schema.names
-    assert np.array_equal(
-        a.column("passenger_count").values,
-        b.column("passenger_count").values,
+    assert out.schema.names == [
+        "passenger_count",
+        "count",
+        "sum_fare_amount",
+        "mean_fare_amount",
+    ]
+    order = np.argsort(out.column("passenger_count").values)
+    fare = tbl.column("fare_amount").values
+    mask = fare > 25.0
+    keys, inv = np.unique(
+        tbl.column("passenger_count").values[mask], return_inverse=True
     )
-    for name in ("count", "sum_fare_amount", "mean_fare_amount"):
-        assert np.allclose(
-            a.column(name).values, b.column(name).values, rtol=1e-12
-        )
+    count = np.bincount(inv)
+    total = np.bincount(inv, weights=fare[mask])
+    assert np.array_equal(out.column("passenger_count").values[order], keys)
+    assert np.array_equal(out.column("count").values[order], count)
+    assert np.allclose(
+        out.column("sum_fare_amount").values[order], total, rtol=1e-12
+    )
+    assert np.allclose(
+        out.column("mean_fare_amount").values[order],
+        total / count,
+        rtol=1e-12,
+    )
 
 
 @pytest.mark.parametrize("fmt", FORMATS)
@@ -447,7 +458,6 @@ def test_wrapper_equivalence_count(populated, fmt):
     ds = dataset(fs, "/d")
     pred = field("fare_amount") > 25.0
     exp = int((tbl.column("fare_amount").values > 25.0).sum())
-    assert ds.scanner(format=fmt, predicate=pred).count_rows() == exp
     assert ds.query(format=fmt).filter(pred).count().to_scalar() == exp
     assert ds.query(format=fmt).count().to_scalar() == len(tbl)
 
@@ -458,33 +468,33 @@ def test_wrapper_equivalence_count(populated, fmt):
 
 
 def test_scanner_metrics_do_not_accumulate_across_runs(flat_ds):
-    """Regression: a second run on the same Scanner used to double-count
+    """Regression: a second run of the same query used to double-count
     rows / pruned fragments / tasks into one ScanMetrics."""
     fs, ds, tbl = flat_ds
     pred = field("trip_id") < 1024
-    sc = ds.scanner(format="pushdown", predicate=pred)
-    sc.to_table()
-    first = sc.metrics
+    q = ds.query(format="pushdown").filter(pred)
+    q.to_table()
+    first = q.metrics
     n_tasks, n_pruned, n_rows = (
         len(first.tasks),
         first.fragments_pruned,
         first.rows,
     )
-    sc.to_table()
-    assert len(sc.metrics.tasks) == n_tasks
-    assert sc.metrics.fragments_pruned == n_pruned
-    assert sc.metrics.rows == n_rows
+    q.to_table()
+    assert len(q.metrics.tasks) == n_tasks
+    assert q.metrics.fragments_pruned == n_pruned
+    assert q.metrics.rows == n_rows
     # the first run's record is a frozen snapshot, not a shared object
-    assert sc.metrics is not first
+    assert q.metrics is not first
 
 
 def test_aggregate_metrics_do_not_accumulate(flat_ds):
     fs, ds, _ = flat_ds
-    sc = ds.scanner(format="pushdown")
-    sc.aggregate(["count", ("min", "trip_id")])
-    first_rows = sc.metrics.rows
-    sc.aggregate(["count", ("min", "trip_id")])
-    assert sc.metrics.rows == first_rows
+    q = ds.query(format="pushdown").aggregate(["count", ("min", "trip_id")])
+    q.to_table()
+    first_rows = q.metrics.rows
+    q.to_table()
+    assert q.metrics.rows == first_rows
 
 
 @pytest.mark.parametrize("fmt", FORMATS)
@@ -493,17 +503,17 @@ def test_count_rows_records_wall_and_fragments(flat_ds, fmt):
     count never set fragments_total.  The unified executor records both
     for every verb."""
     fs, ds, tbl = flat_ds
-    sc = ds.scanner(format=fmt, predicate=field("fare_amount") > 25.0)
-    sc.count_rows()
-    assert sc.metrics.fragments_total == len(ds.fragments())
-    assert sc.metrics.wall_s > 0
-    assert sc.metrics.admission != {}
+    q = ds.query(format=fmt).filter(field("fare_amount") > 25.0).count()
+    q.to_scalar()
+    assert q.metrics.fragments_total == len(ds.fragments())
+    assert q.metrics.wall_s > 0
+    assert q.metrics.admission != {}
 
 
 def test_metadata_count_records_fragments_without_tasks(flat_ds):
     fs, ds, tbl = flat_ds
-    sc = ds.scanner(format="pushdown")
-    assert sc.count_rows() == len(tbl)
-    assert not sc.metrics.tasks
-    assert sc.metrics.fragments_total == len(ds.fragments())
-    assert sc.metrics.metadata_answers == len(ds.fragments())
+    q = ds.query(format="pushdown").count()
+    assert q.to_scalar() == len(tbl)
+    assert not q.metrics.tasks
+    assert q.metrics.fragments_total == len(ds.fragments())
+    assert q.metrics.metadata_answers == len(ds.fragments())

@@ -1,5 +1,5 @@
 """Multi-tenant QoS: weighted-fair admission, priority lanes, deadline
-shedding, per-tenant caching/metrics, and the TaskContext compat shim.
+shedding, and per-tenant caching/metrics.
 
 The grant policy itself (weighted fairness, lane priority, preemption)
 is pinned deterministically against ``_OsdSlots`` — single-threaded
@@ -12,19 +12,17 @@ point.
 
 import threading
 import time
-import warnings
 
 import numpy as np
 import pytest
 
 from repro.aformat.expressions import field
-from repro.core import (ParquetFormat, Shed, TaskContext, TenantRegistry,
-                        dataset, make_cluster, write_flat, write_split,
+from repro.core import (Shed, TaskContext, TenantRegistry, dataset,
+                        make_cluster, write_flat, write_split,
                         write_striped)
 from repro.dataset import MutableDataset, ResultCache
 from repro.dataset.admission import (LANE_PRIORITY, AdmissionController,
                                      AdmissionTimeout, _OsdSlots, _Waiter)
-from repro.dataset.qos import resolve_context
 
 
 @pytest.fixture
@@ -269,11 +267,11 @@ def test_tenant_tagged_query_and_rollup(flat_ds):
 
 def test_scan_metrics_surface_wait_time(flat_ds):
     fs, ds, _ = flat_ds
-    sc = ds.scanner(format="pushdown", columns=["trip_id"],
-                    num_threads=16, queue_depth=1)
-    sc.to_table()
-    adm = sc.metrics.admission
-    assert adm["admitted"] == len(sc.metrics.tasks)
+    q = ds.query(format="pushdown", num_threads=16,
+                 queue_depth=1).select("trip_id")
+    q.to_table()
+    adm = q.metrics.admission
+    assert adm["admitted"] == len(q.metrics.tasks)
     assert "wait_s" in adm and "preemptions" in adm and "sheds" in adm
     if adm["waits"]:
         assert adm["wait_s"] > 0.0
@@ -424,67 +422,6 @@ def test_cache_entries_are_tenant_scoped():
     assert cache.get(("k",), tenant="b") is None
     assert cache.get(("k",), tenant="a") == b"v"
     assert cache.contains(("k",))           # any-tenant probe
-
-
-# ---------------------------------------------------------------------------
-# TaskContext compat shim
-# ---------------------------------------------------------------------------
-
-
-def test_legacy_kwarg_tail_warns_and_adapts(flat_ds):
-    fs, ds, _ = flat_ds
-    fmt = ParquetFormat()
-    frag = ds.fragments()[0]
-    ctrl = AdmissionController(fs.store)
-    with pytest.warns(DeprecationWarning):
-        tbl, _ = fmt.scan_fragment(fs, frag, ["trip_id"], None,
-                                   admission=ctrl)
-    assert len(tbl) == frag.num_rows
-    assert ctrl.stats()["admitted"] == 1
-    with pytest.warns(DeprecationWarning):
-        tbl2, _ = fmt.scan_fragment(fs, frag, ["trip_id"], None, limit=7)
-    assert len(tbl2) == 7
-
-
-def test_legacy_positional_admission_warns(flat_ds):
-    fs, ds, _ = flat_ds
-    fmt = ParquetFormat()
-    frag = ds.fragments()[0]
-    ctrl = AdmissionController(fs.store)
-    with pytest.warns(DeprecationWarning):
-        tbl, _ = fmt.scan_fragment(fs, frag, ["trip_id"], None, ctrl)
-    assert len(tbl) == frag.num_rows
-    assert ctrl.stats()["admitted"] == 1
-
-
-def test_legacy_override_subclass_still_executes(flat_ds):
-    """A format subclass written before TaskContext (old kwarg-tail
-    signature) keeps working through the executor, with one warning."""
-    fs, ds, tbl = flat_ds
-
-    class OldStyleFormat(ParquetFormat):
-        calls = 0
-
-        def scan_fragment(self, fs, frag, columns, predicate,
-                          admission=None, limit=None):
-            OldStyleFormat.calls += 1
-            return ParquetFormat.scan_fragment(
-                self, fs, frag, columns, predicate,
-                TaskContext(admission=admission, limit=limit))
-
-    with warnings.catch_warnings(record=True) as caught:
-        warnings.simplefilter("always")
-        out = ds.query(format=OldStyleFormat()).select("trip_id").to_table()
-    assert len(out) == len(tbl)
-    assert OldStyleFormat.calls == len(ds.fragments())
-    assert any(issubclass(w.category, DeprecationWarning) for w in caught)
-
-
-def test_resolve_context_rejects_unknown_kwargs():
-    with pytest.raises(TypeError):
-        resolve_context(None, {"bogus": 1})
-    with pytest.raises(TypeError):
-        resolve_context(object())
 
 
 # ---------------------------------------------------------------------------

@@ -53,8 +53,8 @@ def test_adaptive_matches_static(populated):
     pred = (field("fare_amount") > 25.0) & (field("passenger_count") >= 4)
     mask = ((tbl.column("fare_amount").values > 25.0)
             & (tbl.column("passenger_count").values >= 4))
-    out = ds.scanner(format="adaptive", columns=["trip_id", "fare_amount"],
-                     predicate=pred, num_threads=4).to_table()
+    out = ds.query(format="adaptive", num_threads=4).filter(pred).select(
+        "trip_id", "fare_amount").to_table()
     exp = tbl.filter(mask).select(["trip_id", "fare_amount"])
     o = np.argsort(out.column("trip_id").values)
     e = np.argsort(exp.column("trip_id").values)
@@ -75,9 +75,8 @@ def test_low_load_prefers_storage(flat_ds):
     should be pushed down."""
     fs, ds, _ = flat_ds
     fmt = AdaptiveFormat()
-    sc = ds.scanner(format=fmt, columns=["trip_id"],
-                    predicate=field("fare_amount") > 30.0, num_threads=4)
-    sc.to_table()
+    ds.query(format=fmt, num_threads=4).filter(
+        field("fare_amount") > 30.0).select("trip_id").to_table()
     dec = fmt.stats()["decisions"]
     assert dec["osd"] > dec["client"]
 
@@ -89,12 +88,12 @@ def test_saturation_prefers_client(flat_ds):
     for osd in fs.store.osds:
         osd.background_load = 32 * osd.threads      # ~32 tenants deep
     fmt = AdaptiveFormat()
-    sc = ds.scanner(format=fmt, columns=["trip_id"],
-                    predicate=field("fare_amount") > 30.0, num_threads=4)
-    out = sc.to_table()
+    q = ds.query(format=fmt, num_threads=4).filter(
+        field("fare_amount") > 30.0).select("trip_id")
+    out = q.to_table()
     dec = fmt.stats()["decisions"]
     assert dec["osd"] == 0
-    assert dec["client"] == len(sc.metrics.tasks)
+    assert dec["client"] == len(q.metrics.tasks)
     assert len(out) == int((tbl.column("fare_amount").values > 30.0).sum())
 
 
@@ -125,18 +124,17 @@ def test_hedging_fires_on_straggler(flat_ds):
     fs, ds, tbl = flat_ds
     fmt = AdaptiveFormat()
     # warm the latency history on an idle cluster
-    ds.scanner(format=fmt, columns=["trip_id"],
-               predicate=field("fare_amount") > 30.0,
-               num_threads=2).to_table()
+    ds.query(format=fmt, num_threads=2).filter(
+        field("fare_amount") > 30.0).select("trip_id").to_table()
     # now one node straggles pathologically; min-pressure over replicas
     # keeps the placement storage-side, so hedging must save the tail
     name = fs.object_names("/d/part0.arw")[0]
     straggler = fs.store.primary_of(name)
     straggler.straggle_factor = 1e6
-    sc = ds.scanner(format=fmt, columns=["trip_id"],
-                    predicate=field("fare_amount") > 60.0, num_threads=2)
-    out = sc.to_table()
-    assert sc.metrics.hedged_tasks > 0
+    q = ds.query(format=fmt, num_threads=2).filter(
+        field("fare_amount") > 60.0).select("trip_id")
+    out = q.to_table()
+    assert q.metrics.hedged_tasks > 0
     assert fmt.stats()["hedges"] > 0
     # every hedged task was ultimately served: the result is complete
     assert len(out) == int((tbl.column("fare_amount").values > 60.0).sum())
@@ -201,22 +199,22 @@ def test_adaptive_count_rows_matches_scan(flat_ds):
     fmt = AdaptiveFormat()
     pred = field("fare_amount") > 25.0
     exp = int((tbl.column("fare_amount").values > 25.0).sum())
-    sc = ds.scanner(format=fmt, predicate=pred)
-    assert sc.count_rows() == exp
-    assert sc.count_rows() == len(
-        ds.scanner(format=fmt, predicate=pred).to_table())
+    q = ds.query(format=fmt).filter(pred).count()
+    assert q.to_scalar() == exp
+    assert q.to_scalar() == len(
+        ds.query(format=fmt).filter(pred).to_table())
     # the adaptive count ships integers, not materialized fragments
-    assert sc.metrics.tasks
-    assert all(t.wire_bytes < 64 for t in sc.metrics.tasks)
+    assert q.metrics.tasks
+    assert all(t.wire_bytes < 64 for t in q.metrics.tasks)
 
 
 def test_adaptive_count_rows_is_cached(flat_ds):
     fs, ds, tbl = flat_ds
     fmt = AdaptiveFormat()
     pred = field("fare_amount") > 25.0
-    first = ds.scanner(format=fmt, predicate=pred)
-    second = ds.scanner(format=fmt, predicate=pred)
-    assert first.count_rows() == second.count_rows()
+    first = ds.query(format=fmt).filter(pred).count()
+    second = ds.query(format=fmt).filter(pred).count()
+    assert first.to_scalar() == second.to_scalar()
     assert sum(1 for t in second.metrics.tasks if t.cached) == \
         len(second.metrics.tasks)
     assert all(t.wire_bytes == 0 for t in second.metrics.tasks)
@@ -224,9 +222,9 @@ def test_adaptive_count_rows_is_cached(flat_ds):
 
 def test_adaptive_count_rows_metadata_only_without_predicate(flat_ds):
     fs, ds, tbl = flat_ds
-    sc = ds.scanner(format=AdaptiveFormat())
-    assert sc.count_rows() == len(tbl)
-    assert not sc.metrics.tasks                 # zero storage calls
+    q = ds.query(format=AdaptiveFormat()).count()
+    assert q.to_scalar() == len(tbl)
+    assert not q.metrics.tasks                  # zero storage calls
 
 
 # ---------------------------------------------------------------------------
@@ -238,39 +236,37 @@ def test_cache_hits_on_repeat_scan(flat_ds):
     fs, ds, _ = flat_ds
     fmt = AdaptiveFormat()
     pred = field("fare_amount") > 30.0
-    a = ds.scanner(format=fmt, columns=["trip_id"], predicate=pred,
-                   num_threads=2).to_table()
-    sc = ds.scanner(format=fmt, columns=["trip_id"], predicate=pred,
-                    num_threads=2)
-    b = sc.to_table()
-    assert sc.metrics.cache_hits == len(sc.metrics.tasks)
+    a = ds.query(format=fmt, num_threads=2).filter(pred).select(
+        "trip_id").to_table()
+    q = ds.query(format=fmt, num_threads=2).filter(pred).select("trip_id")
+    b = q.to_table()
+    assert q.metrics.cache_hits == len(q.metrics.tasks)
     assert np.array_equal(np.sort(a.column("trip_id").values),
                           np.sort(b.column("trip_id").values))
     # a different projection/predicate must not hit the same entries
-    sc2 = ds.scanner(format=fmt, columns=["trip_id", "fare_amount"],
-                     predicate=pred, num_threads=2)
-    sc2.to_table()
-    assert sc2.metrics.cache_hits == 0
+    q2 = ds.query(format=fmt, num_threads=2).filter(pred).select(
+        "trip_id", "fare_amount")
+    q2.to_table()
+    assert q2.metrics.cache_hits == 0
 
 
 def test_cache_invalidated_by_overwrite(flat_ds):
     fs, ds, _ = flat_ds
     fmt = AdaptiveFormat()
     pred = field("fare_amount") > 30.0
-    ds.scanner(format=fmt, columns=["trip_id"], predicate=pred,
-               num_threads=2).to_table()
+    ds.query(format=fmt, num_threads=2).filter(pred).select(
+        "trip_id").to_table()
     # touch one object in place: same bytes, new version
     name = fs.object_names("/d/part0.arw")[0]
     before = fs.store.version_of(name)
     fs.store.put(name, fs.store.get(name))
     assert fs.store.version_of(name) > before
-    sc = ds.scanner(format=fmt, columns=["trip_id"], predicate=pred,
-                    num_threads=2)
-    out = sc.to_table()
+    q = ds.query(format=fmt, num_threads=2).filter(pred).select("trip_id")
+    out = q.to_table()
     # fragments of the touched object miss; everything else still hits
-    assert 0 < sc.metrics.cache_hits < len(sc.metrics.tasks)
-    assert len(out) == len(ds.scanner(format="parquet", columns=["trip_id"],
-                                      predicate=pred).to_table())
+    assert 0 < q.metrics.cache_hits < len(q.metrics.tasks)
+    assert len(out) == len(ds.query(format="parquet").filter(pred).select(
+        "trip_id").to_table())
 
 
 def test_result_cache_lru_eviction():
@@ -307,8 +303,7 @@ def test_load_of_pressure_signals():
 
 def test_inflight_returns_to_zero(flat_ds):
     fs, ds, _ = flat_ds
-    ds.scanner(format="pushdown", columns=["trip_id"],
-               num_threads=4).to_table()
+    ds.query(format="pushdown", num_threads=4).select("trip_id").to_table()
     assert all(o.inflight == 0 for o in fs.store.osds)
 
 

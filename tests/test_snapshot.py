@@ -111,7 +111,7 @@ def test_empty_dataset_answers_or_refuses_cleanly():
     schema-free queries and refuse column-referencing ones loudly."""
     fs = make_cluster(4)
     md = MutableDataset.create(fs, "/fresh")
-    assert md.scanner(format="pushdown").count_rows() == 0
+    assert md.query(format="pushdown").count().to_scalar() == 0
     assert md.query(format="pushdown").to_table().num_rows == 0
     agg = md.query(format="pushdown").aggregate(["count"]).to_table()
     assert int(agg.column("count").values[0]) == 0
@@ -238,13 +238,14 @@ def test_deleted_rows_never_resurface_any_format(mut):
         out = md.query(format=fmt).to_table()
         assert keys_of(out) == live
         check_values(out)
-        n = md.scanner(format=fmt).count_rows()
+        n = md.query(format=fmt).count().to_scalar()
         assert n == len(live)
     # aggregates see the tombstones too
     agg = md.query(format="pushdown").aggregate([("sum", "k")]).to_table()
     assert int(agg.column("sum_k").values[0]) == sum(live)
     # the pre-delete snapshot still has them
-    assert md.as_of(pre).scanner(format="pushdown").count_rows() == 800
+    assert md.as_of(pre).query(format="pushdown").count().to_scalar() \
+        == 800
 
 
 def test_tombstone_applies_only_to_older_files(mut):

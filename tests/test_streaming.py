@@ -1,6 +1,6 @@
 """The concurrent streaming execution engine and per-OSD admission.
 
-``Scanner.to_batches`` must stream with *bounded in-flight fragments*
+``Query.to_batches`` must stream with *bounded in-flight fragments*
 driven by consumption (backpressure), ``to_table`` must be a faithful
 materialization of the same stream, and the unified admission controller
 must gate every placement's per-OSD concurrency — the properties the
@@ -61,8 +61,8 @@ class _CountingFormat(ParquetFormat):
 def test_to_batches_bounds_inflight(flat_ds):
     fs, ds, _ = flat_ds
     fmt = _CountingFormat(delay_s=0.002)
-    sc = ds.scanner(format=fmt, columns=["trip_id"], num_threads=16)
-    batches = list(sc.to_batches(max_inflight=3))
+    q = ds.query(format=fmt, num_threads=16).select("trip_id")
+    batches = list(q.to_batches(max_inflight=3))
     assert fmt.peak <= 3
     assert fmt.started == len(ds.fragments())
     assert sum(len(b) for b in batches) == ds.num_rows
@@ -74,8 +74,8 @@ def test_to_batches_backpressure(flat_ds):
     the one refill triggered by the consumed batch)."""
     fs, ds, _ = flat_ds
     fmt = _CountingFormat()
-    sc = ds.scanner(format=fmt, columns=["trip_id"], num_threads=16)
-    it = sc.to_batches(max_inflight=2)
+    q = ds.query(format=fmt, num_threads=16).select("trip_id")
+    it = q.to_batches(max_inflight=2)
     next(it)
     started_after_one = fmt.started
     assert started_after_one <= 3       # 2 in window + 1 refill
@@ -86,11 +86,10 @@ def test_to_batches_backpressure(flat_ds):
 def test_to_batches_matches_to_table(flat_ds):
     fs, ds, tbl = flat_ds
     pred = field("fare_amount") > 30.0
-    streamed = Table.concat(list(
-        ds.scanner(format="pushdown", columns=["trip_id"], predicate=pred,
-                   num_threads=4).to_batches()))
-    materialized = ds.scanner(format="pushdown", columns=["trip_id"],
-                              predicate=pred, num_threads=4).to_table()
+    q = ds.query(format="pushdown", num_threads=4).filter(pred) \
+        .select("trip_id")
+    streamed = Table.concat(list(q.to_batches()))
+    materialized = q.to_table()
     assert np.array_equal(np.sort(streamed.column("trip_id").values),
                           np.sort(materialized.column("trip_id").values))
 
@@ -98,9 +97,9 @@ def test_to_batches_matches_to_table(flat_ds):
 def test_to_batches_skips_empty_fragments(flat_ds):
     fs, ds, tbl = flat_ds
     # trip_id < 100 matches only the very first row group
-    batches = list(ds.scanner(format="pushdown", columns=["trip_id"],
-                              predicate=field("trip_id") < 100,
-                              num_threads=4).to_batches())
+    batches = list(ds.query(format="pushdown", num_threads=4)
+                   .filter(field("trip_id") < 100).select("trip_id")
+                   .to_batches())
     assert all(len(b) for b in batches)
     assert sum(len(b) for b in batches) == 100
 
@@ -109,8 +108,8 @@ def test_to_table_preserves_plan_order(flat_ds):
     """to_table rides the completion-ordered stream but must reassemble
     fragments in plan order (clients relied on it pre-streaming)."""
     fs, ds, tbl = flat_ds
-    out = ds.scanner(format="parquet", columns=["trip_id"],
-                     num_threads=8).to_table()
+    out = ds.query(format="parquet", num_threads=8).select(
+        "trip_id").to_table()
     vals = out.column("trip_id").values
     assert np.array_equal(vals, np.sort(vals))
 
@@ -152,23 +151,23 @@ def test_all_formats_honour_admission(flat_ds, fmt):
     per node and a wide thread pool, the scan still completes and the
     controller reports real contention."""
     fs, ds, tbl = flat_ds
-    sc = ds.scanner(format=fmt, columns=["trip_id"], num_threads=16,
-                    queue_depth=1)
-    out = sc.to_table()
+    q = ds.query(format=fmt, num_threads=16, queue_depth=1).select(
+        "trip_id")
+    out = q.to_table()
     assert len(out) == len(tbl)
-    assert sc.metrics.admission["admitted"] == len(sc.metrics.tasks)
-    assert sc.metrics.admission["slots_per_osd"] == 1
+    assert q.metrics.admission["admitted"] == len(q.metrics.tasks)
+    assert q.metrics.admission["slots_per_osd"] == 1
 
 
 def test_adaptive_cache_hits_skip_admission(flat_ds):
     from repro.core import AdaptiveFormat
     fs, ds, _ = flat_ds
     fmt = AdaptiveFormat()
-    ds.scanner(format=fmt, columns=["trip_id"], num_threads=4).to_table()
-    sc = ds.scanner(format=fmt, columns=["trip_id"], num_threads=4)
-    sc.to_table()
-    assert sc.metrics.cache_hits == len(sc.metrics.tasks)
-    assert sc.metrics.admission["admitted"] == 0   # never touched a node
+    ds.query(format=fmt, num_threads=4).select("trip_id").to_table()
+    q = ds.query(format=fmt, num_threads=4).select("trip_id")
+    q.to_table()
+    assert q.metrics.cache_hits == len(q.metrics.tasks)
+    assert q.metrics.admission["admitted"] == 0    # never touched a node
 
 
 # ---------------------------------------------------------------------------

@@ -92,13 +92,13 @@ def test_scan_consistency_under_failure_and_hedging():
     ds = dataset(fs, "/c")
     pred = field("domain") == 2
 
-    ref = ds.scanner(format="parquet", columns=["token"],
-                     predicate=pred, num_threads=1).to_table()
+    ref = ds.query(format="parquet", num_threads=1).filter(pred) \
+        .select("token").to_table()
     fs.store.fail_osd(1)
     fs.store.osds[2].straggle_factor = 50.0
     from repro.dataset import PushdownParquetFormat
-    sc = ds.scanner(format=PushdownParquetFormat(hedge_threshold_s=1e-4),
-                    columns=["token"], predicate=pred, num_threads=2)
+    sc = ds.query(format=PushdownParquetFormat(hedge_threshold_s=1e-4),
+                  num_threads=2).filter(pred).select("token")
     out = sc.to_table()
     assert np.array_equal(np.sort(out.column("token").values),
                           np.sort(ref.column("token").values))
