@@ -27,6 +27,13 @@ comes out.  Two backends implement that contract:
     ``tests/test_decode.py`` pins that equivalence across the encoding
     x dtype x validity x predicate grid.
 
+Where a predicate is given, a projected column that the predicate does
+not read may be decoded after the selection (late materialization): its
+codes are compacted with the other projected columns and only the kept
+codes are decoded.  The backend decides per chunk (``defers``): the
+Pallas backend defers exactly the chunks its gather kernel decodes, the
+host path defers none.
+
 A row group's column chunks are read on the task's thread, in column
 order (``read_chunks``).  Where the source is client-side
 (``FileSource.client_side``), every buffer of at least
@@ -64,7 +71,7 @@ import numpy as np
 
 from repro.aformat import compression, encodings
 from repro.aformat.expressions import And, Cmp, Expr, Not, Or
-from repro.aformat.schema import Field, to_physical
+from repro.aformat.schema import Field, Schema, to_physical
 from repro.aformat.table import Column, Table
 from repro.trace import span
 
@@ -230,6 +237,37 @@ def _host_decode(chunk: ChunkData) -> np.ndarray:
                                 chunk.field.numpy_dtype)
 
 
+def _dict_codes(chunk: ChunkData) -> np.ndarray:
+    """A DICT or DICTP chunk's codes as int32 (DICTP's width-bit unpack
+    is a byte-stream transform, on the host)."""
+    buf = chunk.data_bufs[0]
+    if chunk.encoding == encodings.DICT:
+        return np.frombuffer(buf, np.int32)[:chunk.num_rows]
+    return encodings.unpack_width(buf[1:], chunk.num_rows,
+                                  buf[0]).astype(np.int32)
+
+
+def _bucket(n: int) -> int:
+    """``n`` rounded up to a power of two (0 stays 0): the kernels are
+    jitted per shape, so a selection's shapes are bucketed to keep the
+    trace cache hot, and a slice restores the exact length."""
+    return 1 << (n - 1).bit_length() if n else 0
+
+
+def _decode_kept(chunk: ChunkData, codes: Column, decode) -> Column:
+    """``chunk``'s values at the kept rows, whose compacted codes
+    ``codes`` holds, decoded by ``decode`` through the chunk's dictionary.
+    The codes are padded with code 0 to the compaction's bucket, a shape
+    the warm-up compiled, and the values cut back to the kept rows."""
+    k = len(codes)
+    padded = np.zeros(_bucket(k), np.int32)
+    padded[:k] = codes.values
+    col = decode(ChunkData(chunk.field, encodings.DICT,
+                           [padded.tobytes(), *chunk.data_bufs[1:]],
+                           len(padded)))
+    return Column(chunk.field, col.values[:k], codes.validity)
+
+
 # ---------------------------------------------------------------------------
 # Backend interface
 # ---------------------------------------------------------------------------
@@ -256,37 +294,71 @@ class DecodeBackend:
                 report: dict | None = None) -> Table:
         raise NotImplementedError
 
+    def defers(self, chunk: ChunkData) -> bool:
+        """Whether ``chunk``, projected but not read by the predicate, is
+        decoded after the selection: its codes are compacted with the
+        other projected columns and only the kept codes are decoded.  A
+        backend says True only for a DICT or DICTP chunk, and only where
+        that never does more work than decoding every row first; this
+        one never defers."""
+        return False
+
     def scan_row_group(self, src, meta, rg,
                        columns: Sequence[str] | None = None,
                        predicate: Expr | None = None,
                        report: dict | None = None) -> Table:
         """Decode + filter + project one row group (the scan_op payload).
+        A projected column the predicate does not read is decoded after
+        the selection, at the kept rows alone, where ``defers`` says so.
         ``report``, when given, is filled with the per-column / predicate
-        routing this call actually took (kernel vs host fallback) and
-        with where its buffers were inflated (``read_chunks``)."""
+        routing this call actually took (kernel vs host fallback), the
+        columns it deferred (``"deferred"``) and where its buffers were
+        inflated (``read_chunks``)."""
         names = list(columns) if columns is not None else meta.schema.names
-        needed = set(names)
-        if predicate is not None:
-            needed |= predicate.columns()
-        order = sorted(needed, key=meta.schema.index)
+        read = predicate.columns() if predicate is not None else set()
+        order = sorted(set(names) | read, key=meta.schema.index)
+        routes: dict[str, str] = {}
+
+        def decode(chunk: ChunkData) -> Column:
+            col = self.decode_column(chunk)
+            routes[chunk.field.name] = col.__dict__.pop("_decode_route",
+                                                        "host")
+            return col
+
+        cols: dict[str, Column] = {}
+        deferred: dict[str, ChunkData] = {}
         chunks = read_chunks(src, meta, rg, order, report)
         try:
-            cols = {c.field.name: self.decode_column(c) for c in chunks}
+            for c in chunks:
+                n = c.field.name
+                if predicate is not None and n not in read \
+                        and self.defers(c):
+                    deferred[n] = c
+                else:
+                    cols[n] = decode(c)
         finally:
             chunks.close()
-        if report is not None:
-            report["columns"] = {n: getattr(cols[n], "_decode_route",
-                                            "host") for n in order}
-            for n in order:
-                if hasattr(cols[n], "_decode_route"):
-                    del cols[n]._decode_route
-        tbl = Table(meta.schema.select(order), [cols[n] for n in order])
         if predicate is None:
-            return tbl.select(names)
-        mask = np.asarray(self.evaluate_predicate(tbl, predicate, report),
-                          "?")
-        # a column the predicate alone reads is not compacted
-        return self.compact(tbl.select(names), mask, report)
+            out = Table(meta.schema.select(names), [cols[n] for n in names])
+        else:
+            eager = [n for n in order if n in cols]
+            mask = np.asarray(self.evaluate_predicate(
+                Table(meta.schema.select(eager), [cols[n] for n in eager]),
+                predicate, report), "?")
+            # a column the predicate alone reads is not compacted; a
+            # deferred one is compacted as its codes
+            proj = [cols[n] if n in cols else
+                    Column(Field(n, "int32"), _dict_codes(deferred[n]),
+                           deferred[n].validity()) for n in names]
+            kept = self.compact(Table(Schema(tuple(c.field for c in proj)),
+                                      proj), mask, report)
+            out = Table(meta.schema.select(names), [
+                _decode_kept(deferred[n], c, decode) if n in deferred
+                else c for n, c in zip(names, kept.columns)])
+        if report is not None:
+            report["columns"] = {n: routes[n] for n in order}
+            report["deferred"] = list(deferred)
+        return out
 
     def describe(self, meta, rg, columns: Sequence[str] | None,
                  predicate: Expr | None) -> str:
@@ -355,6 +427,18 @@ def _f32_exact_scalar(v) -> bool:
     return np.isfinite(f) and float(np.float32(f)) == f
 
 
+def _kernel_dictionary(chunk: ChunkData) -> np.ndarray | None:
+    """The dictionary of a chunk that the gather kernel decodes: DICT or
+    DICTP, of a kernel type, every entry f32-exact (an int dictionary
+    outside that domain is the host-fallback condition); None for any
+    other chunk."""
+    if (chunk.encoding not in (encodings.DICT, encodings.DICTP)
+            or not _kernel_type(chunk.field)):
+        return None
+    dic = np.frombuffer(chunk.data_bufs[1], chunk.field.numpy_dtype)
+    return dic if _f32_exact_values(dic) else None
+
+
 def _flatten(pred: Expr):
     """Flatten an expression into (leaves, combine, negate) when it is a
     flat AND- or OR-tree of Cmp leaves (optionally under one Not); None
@@ -389,32 +473,22 @@ class PallasBackend(DecodeBackend):
     # (TPC-H Q6 cell) of its HBM roofline.  The EWMA corrects from there.
     decode_rate_prior = 1.5e9
 
+    def defers(self, chunk: ChunkData) -> bool:
+        # exactly the kernel route: a gather of the kept codes is never
+        # more work than a gather of every row
+        return _kernel_dictionary(chunk) is not None
+
     def decode_column(self, chunk: ChunkData) -> Column:
-        route = "host"
-        values = None
-        if (chunk.encoding in (encodings.DICT, encodings.DICTP)
-                and _kernel_type(chunk.field)):
+        dic = _kernel_dictionary(chunk)
+        if dic is None:
+            values, route = _host_decode(chunk), "host"
+        else:
             from repro.kernels import decode_dictionary
 
-            if chunk.encoding == encodings.DICT:
-                codes = np.frombuffer(chunk.data_bufs[0],
-                                      np.int32)[:chunk.num_rows]
-            else:
-                # DICTP: width-bit unpack is a byte-stream transform
-                # (host), the gather itself still runs on the kernel
-                buf = chunk.data_bufs[0]
-                codes = encodings.unpack_width(
-                    buf[1:], chunk.num_rows, buf[0]).astype(np.int32)
-            dic = np.frombuffer(chunk.data_bufs[1],
-                                chunk.field.numpy_dtype)
-            # an int dictionary outside the f32-exact domain is the
-            # host-fallback condition; a kernel error propagates
-            if _f32_exact_values(dic):
-                with span("repro.kernel.dict_decode"):
-                    values = _fetch(decode_dictionary(codes, dic))
-                route = "kernel"
-        if values is None:
-            values = _host_decode(chunk)
+            # a kernel error propagates
+            with span("repro.kernel.dict_decode"):
+                values = _fetch(decode_dictionary(_dict_codes(chunk), dic))
+            route = "kernel"
         col = Column(chunk.field, values, chunk.validity())
         col._decode_route = route        # scraped into the scan report
         return col
@@ -477,11 +551,9 @@ class PallasBackend(DecodeBackend):
 
         idx = np.flatnonzero(mask)
         n_sel = len(idx)
-        # round the pack capacity up to a power of two: the kernel is
-        # jitted per (n, capacity) shape, so exact capacities would
-        # retrace on every new selectivity — bucketing keeps the trace
-        # cache hot and the [:n_sel] slice restores the exact result
-        capacity = 1 << (n_sel - 1).bit_length() if n_sel else 0
+        # the kernel is jitted per (n, capacity) shape: exact capacities
+        # would retrace on every new selectivity
+        capacity = _bucket(n_sel)
         routes = {}
         out_cols = []
         for c in tbl.columns:

@@ -57,10 +57,12 @@ def assert_bytes_identical(a: Table, b: Table):
         assert np.array_equal(va, vb), ca.field.name
 
 
-def scan_both(tbl, columns=None, predicate=None, row_group_rows=256):
+def scan_both(tbl, columns=None, predicate=None, row_group_rows=256,
+              advise=False):
     """Scan every row group with both backends; assert byte-identity and
     return (numpy result, pallas result, last pallas routing report)."""
-    data = parquet.write_table(tbl, row_group_rows=row_group_rows)
+    data = parquet.write_table(tbl, row_group_rows=row_group_rows,
+                               advise=advise)
     src = parquet.BytesSource(data)
     meta = parquet.read_footer(src)
     outs_np, outs_pl, report = [], [], {}
@@ -196,6 +198,86 @@ def test_large_dictionaries_and_dict_predicate_stay_on_kernels():
     assert report["compact"] == {"id": "kernel", "amount": "kernel",
                                  "count": "kernel"}
     assert 0 < len(out) < n
+
+
+def deferral_table(n=16_384, seed=3, with_nulls=False):
+    """One row group for late materialization: ``key`` and ``row`` for
+    the predicates, two kernel-route DICT columns on either side of
+    ONEHOT_MAX (``small`` one-hot, ``large`` the XLA gather), and two
+    host-route columns (``big`` past the f32 domain, ``f64`` PLAIN)."""
+    rng = np.random.default_rng(seed)
+    cols = {
+        "row": np.arange(n, dtype=np.int32),                     # DELTA
+        "key": rng.integers(0, 8, n).astype(np.int32),           # DICT
+        "small": (rng.integers(0, 300, n) * 7).astype(np.int32),
+        "large": rng.integers(0, 3_000, n).astype(np.int32),
+        "big": rng.integers(0, 4, n).astype(np.int64) * 2 ** 30 + 7,
+        "f64": rng.normal(0, 10, n),
+    }
+    if not with_nulls:
+        return Table.from_pydict(cols)
+    nullable = ("key", "small", "large")
+    sch = schema(*[(k, Table.from_pydict({k: v}).schema.fields[0].type)
+                   for k, v in cols.items()], nullable=nullable)
+    return Table(sch, [Column(f, cols[f.name], rng.random(n) > 0.2
+                              if f.name in nullable else None)
+                       for f in sch])
+
+
+#: case -> (projection, predicate, columns deferred, advisor encodings)
+DEFERRAL = {
+    "onehot-dict": (["small"], field("key") >= 2, ["small"], False),
+    "gather-dict": (["large"], field("key") >= 2, ["large"], False),
+    # the advisor packs ``small`` as DICTP and ``large`` as BITPACK
+    "dictp": (["small", "large"], field("key") >= 2, ["small"], True),
+    "empty-selection": (["small", "large"], field("key") > 99,
+                        ["small", "large"], False),
+    "one-row": (["small", "large"], field("row") == 17,
+                ["small", "large"], False),
+    "all-rows": (["small", "large"], field("row") >= 0,
+                 ["small", "large"], False),
+    "no-predicate": (["small", "large"], None, [], False),
+    "projected-and-read": (["key", "small"], field("key") >= 2, ["small"],
+                           False),
+    "host-beside": (["big", "small", "f64", "large"], field("key") >= 2,
+                    ["small", "large"], False),
+}
+
+
+@pytest.mark.parametrize("nulls", [False, True], ids=["dense", "nulls"])
+@pytest.mark.parametrize("case", sorted(DEFERRAL))
+def test_deferred_decode_byte_identical(case, nulls):
+    """A projected DICT/DICTP column that the predicate does not read is
+    compacted as its codes and decoded at the kept rows alone; the result
+    is NumPy's byte for byte, and the report lists the deferred columns
+    with every route as it was."""
+    from repro.kernels.dict_decode.dict_decode import ONEHOT_MAX
+
+    columns, pred, deferred, advise = DEFERRAL[case]
+    tbl = deferral_table(with_nulls=nulls)
+    assert len(np.unique(tbl.column("small").values)) <= ONEHOT_MAX \
+        < len(np.unique(tbl.column("large").values))
+    data = parquet.write_table(tbl, row_group_rows=len(tbl), advise=advise)
+    meta = parquet.read_footer(parquet.BytesSource(data))
+    enc = {n: meta.row_groups[0].chunks[meta.schema.index(n)].encoding
+           for n in ("small", "large")}
+    assert enc == ({"small": encodings.DICTP, "large": encodings.BITPACK}
+                   if advise else {"small": encodings.DICT,
+                                   "large": encodings.DICT})
+    _, out, report = scan_both(tbl, columns, pred, len(tbl), advise)
+    assert report["deferred"] == deferred
+    kept = {"empty-selection": 0, "one-row": 1,
+            "all-rows": len(tbl)}.get(case)
+    if kept is not None:
+        assert len(out) == kept
+    for n in deferred:
+        assert report["columns"][n] == "kernel"
+        assert report["compact"][n] == ("kernel" if len(out) else "host")
+    for n in ("big", "f64"):
+        if n in columns:
+            assert report["columns"][n] == report["compact"][n] == "host"
+    if pred is not None:
+        assert report["predicate"] == "kernel"
 
 
 @pytest.mark.parametrize("pred_name,reason", [
