@@ -32,7 +32,17 @@ not read may be decoded after the selection (late materialization): its
 codes are compacted with the other projected columns and only the kept
 codes are decoded.  The backend decides per chunk (``defers``): the
 Pallas backend defers exactly the chunks its gather kernel decodes, the
-host path defers none.
+host path defers none.  An aggregate's group keys (``codes``) are never
+decoded row by row: a dictionary-encoded key goes through the selection
+as its codes, with its dictionary, and the fold reads its keys once per
+group.
+
+After the scan a client's aggregate folds the kept rows into a partial
+state through the backend's ``fold``: the host path runs
+``aggregate.partial_aggregate``; the Pallas backend sums the measures
+per group in the ``fold_groups`` kernel where every key is a dictionary
+of codes and every factor is f32-exact, else on the host, and says
+which in ``report["fold"]``.
 
 A row group's column chunks are read on the task's thread, in column
 order (``read_chunks``).  Where the source is client-side
@@ -48,8 +58,9 @@ per buffer, on the thread that inflates it (a pool thread or the task's),
 ``repro.decode.wait`` per pooled buffer where the task's thread blocks on
 its inflate, ``repro.decode.host`` per host route (a column's decode, a
 predicate, a host ``take``), ``repro.kernel.dict_decode`` /
-``repro.kernel.predicate`` / ``repro.kernel.pack`` per kernel call, from
-the host casts to the NumPy result, and nested in those
+``repro.kernel.predicate`` / ``repro.kernel.pack`` / ``repro.kernel.fold``
+per kernel call, from the host casts to the NumPy result, and nested in
+those
 ``repro.kernel.fetch``, the blocking read of the result from the device.
 A client task's wall time thus holds the decompression that the pool did
 not hide (``repro.decode.wait``), not the inflate of every buffer.
@@ -62,6 +73,7 @@ seeds the client-side EWMA in ``repro.dataset.scheduler``.
 from __future__ import annotations
 
 import dataclasses
+import math
 import os
 import threading
 from concurrent.futures import Future, ThreadPoolExecutor, wait
@@ -70,8 +82,11 @@ from typing import Iterator, Sequence
 import numpy as np
 
 from repro.aformat import compression, encodings
-from repro.aformat.expressions import And, Cmp, Expr, Not, Or
-from repro.aformat.schema import Field, Schema, to_physical
+from repro.aformat.aggregate import (AggSpec, AggState, key_names,
+                                     measure_terms, measure_type,
+                                     partial_aggregate, state_from_sums)
+from repro.aformat.expressions import And, Cmp, Expr, Measure, Not, Or
+from repro.aformat.schema import Field, Schema, physical_type, to_physical
 from repro.aformat.table import Column, Table
 from repro.trace import span
 
@@ -274,10 +289,11 @@ def _decode_kept(chunk: ChunkData, codes: Column, decode) -> Column:
 
 
 class DecodeBackend:
-    """Decode + filter + select one row group.  Subclasses override the
-    three hooks (column decode, mask evaluation, selection compaction);
-    the row-group template is shared so the backends can never disagree
-    about column ordering, validity handling, or projection."""
+    """Decode + filter + select one row group, and fold an aggregate's
+    rows.  Subclasses override the hooks (column decode, mask evaluation,
+    selection compaction, and the fold); the row-group template is
+    shared so the backends can never disagree about column ordering,
+    validity handling, or projection."""
 
     name = "abstract"
     #: stored-bytes/s prior seeding the scheduler's client-side EWMA
@@ -294,6 +310,19 @@ class DecodeBackend:
                 report: dict | None = None) -> Table:
         raise NotImplementedError
 
+    def fold(self, table: Table, specs: Sequence[AggSpec], group_by,
+             report: dict | None = None) -> AggState:
+        """Fold a client scan's table into a partial aggregate state (the
+        fold of ``partial_aggregate(..., backend=self)``).  ``report``,
+        when given, gets ``"fold"``, the route (``"kernel"``, or
+        ``"host"`` with the reason the kernel could not take it), and
+        ``"groups"``, the groups of the state.  This one folds on the
+        host."""
+        state = partial_aggregate(table, specs, group_by)
+        if report is not None:
+            report.update(fold="host", groups=state.num_groups)
+        return state
+
     def defers(self, chunk: ChunkData) -> bool:
         """Whether ``chunk``, projected but not read by the predicate, is
         decoded after the selection: its codes are compacted with the
@@ -306,14 +335,19 @@ class DecodeBackend:
     def scan_row_group(self, src, meta, rg,
                        columns: Sequence[str] | None = None,
                        predicate: Expr | None = None,
-                       report: dict | None = None) -> Table:
+                       report: dict | None = None,
+                       codes: Sequence[str] = ()) -> Table:
         """Decode + filter + project one row group (the scan_op payload).
         A projected column the predicate does not read is decoded after
         the selection, at the kept rows alone, where ``defers`` says so.
-        ``report``, when given, is filled with the per-column / predicate
-        routing this call actually took (kernel vs host fallback), the
-        columns it deferred (``"deferred"``) and where its buffers were
-        inflated (``read_chunks``)."""
+        A projected DICT or DICTP column named in ``codes`` (an
+        aggregate's group key) that the predicate does not read is not
+        decoded: it comes back as its int32 codes, with its dictionary
+        (``Column.dictionary``).  ``report``, when given, is filled with
+        the per-column / predicate routing this call actually took (kernel
+        vs host fallback), the columns it deferred (``"deferred"``) and
+        kept as codes (``"codes"``), and where its buffers were inflated
+        (``read_chunks``)."""
         names = list(columns) if columns is not None else meta.schema.names
         read = predicate.columns() if predicate is not None else set()
         order = sorted(set(names) | read, key=meta.schema.index)
@@ -331,7 +365,13 @@ class DecodeBackend:
         try:
             for c in chunks:
                 n = c.field.name
-                if predicate is not None and n not in read \
+                if n in codes and n not in read and c.encoding in (
+                        encodings.DICT, encodings.DICTP):
+                    cols[n] = Column(
+                        Field(n, "int32"), _dict_codes(c), c.validity(),
+                        encodings.dictionary(c.field.type, c.data_bufs,
+                                             c.field.numpy_dtype))
+                elif predicate is not None and n not in read \
                         and self.defers(c):
                     deferred[n] = c
                 else:
@@ -339,7 +379,7 @@ class DecodeBackend:
         finally:
             chunks.close()
         if predicate is None:
-            out = Table(meta.schema.select(names), [cols[n] for n in names])
+            out_cols = [cols[n] for n in names]
         else:
             eager = [n for n in order if n in cols]
             mask = np.asarray(self.evaluate_predicate(
@@ -352,13 +392,15 @@ class DecodeBackend:
                            deferred[n].validity()) for n in names]
             kept = self.compact(Table(Schema(tuple(c.field for c in proj)),
                                       proj), mask, report)
-            out = Table(meta.schema.select(names), [
-                _decode_kept(deferred[n], c, decode) if n in deferred
-                else c for n, c in zip(names, kept.columns)])
+            out_cols = [_decode_kept(deferred[n], c, decode)
+                        if n in deferred else c
+                        for n, c in zip(names, kept.columns)]
         if report is not None:
-            report["columns"] = {n: routes[n] for n in order}
+            report["columns"] = {n: routes[n] for n in order if n in routes}
             report["deferred"] = list(deferred)
-        return out
+            report["codes"] = [n for n in order if n in cols
+                               and n not in routes]
+        return Table(Schema(tuple(c.field for c in out_cols)), out_cols)
 
     def describe(self, meta, rg, columns: Sequence[str] | None,
                  predicate: Expr | None) -> str:
@@ -461,6 +503,54 @@ def _flatten(pred: Expr):
     return leaves, (kinds.pop() if kinds else "and"), negate
 
 
+def _fold_plan(table: Table, specs: Sequence[AggSpec], group_by):
+    """What the fold kernel computes for ``specs`` over ``table``:
+    (key columns, factor columns' names, monomials, each spec's
+    polynomial {monomial: coefficient} or None for a count), or (None,
+    the reason it cannot).  It takes keys that are dictionary codes, sums
+    and means of integer and decimal measures, counts, no nulls, every
+    factor f32-exact (the gate of every kernel route) and every monomial
+    below 2**31 in magnitude."""
+    from repro.kernels.group_fold.group_fold import MAX_GROUPS, MAX_ROWS
+
+    if not len(table) or len(table) > MAX_ROWS:
+        return None, f"rows:{len(table)}"
+    keys = [table.column(k) for k in key_names(group_by)]
+    for k in keys:
+        if k.dictionary is None or k.validity is not None:
+            return None, f"key:{k.field.name}"
+    if math.prod(len(k.dictionary) for k in keys) > MAX_GROUPS:
+        return None, "groups"
+    polys = []
+    for s in specs:
+        if s.op == "count":
+            polys.append(None)
+        elif s.op in ("sum", "mean"):
+            mtype = measure_type(s.column, table.schema)
+            if mtype == "date32" or physical_type(mtype) not in (
+                    "int32", "int64", "bool"):
+                return None, f"{s.name}:{mtype}"
+            m = s.column if isinstance(s.column, Measure) \
+                else Measure("field", (s.column,))
+            polys.append(measure_terms(m, table.schema))
+        else:
+            return None, f"op:{s.op}"
+        for c in s.columns():
+            if table.column(c).validity is not None:
+                return None, f"{c}:validity"
+    monos = sorted({m for p in polys if p for m in p if m})
+    factors = sorted({c for m in monos for c in m})
+    bound = {}
+    for f in factors:
+        v = table.column(f).values
+        bound[f] = max(-int(v.min()), int(v.max()))
+        if bound[f] >= F32_EXACT:
+            return None, f"{f}:f32-domain"
+    if any(math.prod(bound[c] for c in m) >= 2 ** 31 for m in monos):
+        return None, "range"
+    return (keys, factors, monos, polys), None
+
+
 class PallasBackend(DecodeBackend):
     """The accelerator decode path (``repro.kernels``), with per-column /
     per-predicate host fallback for everything the kernels cannot express
@@ -477,6 +567,44 @@ class PallasBackend(DecodeBackend):
         # exactly the kernel route: a gather of the kept codes is never
         # more work than a gather of every row
         return _kernel_dictionary(chunk) is not None
+
+    def fold(self, table, specs, group_by, report=None):
+        """The fold on the chip (``fold_groups``) where ``_fold_plan``
+        says the kernel can fold exactly, else on the host."""
+        plan, reason = _fold_plan(table, specs, group_by)
+        if plan is None:
+            state = partial_aggregate(table, specs, group_by)
+        else:
+            from repro.kernels import fold_groups
+
+            keys, factors, monos, polys = plan
+            sizes = tuple(len(k.dictionary) for k in keys)
+            with span("repro.kernel.fold"):
+                sums, counts = fold_groups(
+                    [k.values for k in keys],
+                    [table.column(f).values for f in factors], sizes=sizes,
+                    terms=[tuple(factors.index(c) for c in m)
+                           for m in monos])
+                groups = np.flatnonzero(counts)
+                rows = counts[groups].tolist()
+                # a constant term sums to itself times the rows
+                terms = {(): rows, **{m: sums[i][groups].tolist()
+                                      for i, m in enumerate(monos)}}
+                spec_sums = [None if p is None else [
+                    sum(c * terms[m][g] for m, c in p.items())
+                    for g in range(len(groups))] for p in polys]
+                keyvals = [None]
+                if keys:
+                    vals = [k.dictionary[c].tolist() for k, c in
+                            zip(keys, np.unravel_index(groups, sizes))]
+                    keyvals = vals[0] if len(keys) == 1 else list(zip(*vals))
+                state = state_from_sums(specs, group_by, keyvals, rows,
+                                        spec_sums, len(table))
+            reason = None
+        if report is not None:
+            report.update(fold="kernel" if reason is None
+                          else f"host:{reason}", groups=state.num_groups)
+        return state
 
     def decode_column(self, chunk: ChunkData) -> Column:
         dic = _kernel_dictionary(chunk)
@@ -563,7 +691,8 @@ class PallasBackend(DecodeBackend):
                     packed, _ = pack_tokens(c.values, mask, capacity)
                     values = _fetch(packed)[:n_sel]
                 validity = None if c.validity is None else c.validity[idx]
-                out_cols.append(Column(c.field, values, validity))
+                out_cols.append(Column(c.field, values, validity,
+                                       c.dictionary))
                 routes[c.field.name] = "kernel"
             else:
                 with span("repro.decode.host"):

@@ -163,6 +163,16 @@ def encode(field_type: str, encoding: str, values: np.ndarray) -> list[bytes]:
     raise ValueError(encoding)
 
 
+def dictionary(field_type: str, bufs: list[bytes],
+               numpy_dtype) -> np.ndarray:
+    """The dictionary of a DICT or DICTP chunk (the buffers after its
+    codes): strings as an object array, else values of ``numpy_dtype``."""
+    if field_type == "string":
+        dict_n = (len(np.frombuffer(bufs[1], np.int64)) - 1)
+        return _string_from_buffers(bufs[1:], dict_n)
+    return np.frombuffer(bufs[1], numpy_dtype)
+
+
 def decode(field_type: str, encoding: str, bufs: list[bytes], n: int,
            numpy_dtype) -> np.ndarray:
     if encoding == PLAIN:
@@ -182,12 +192,7 @@ def decode(field_type: str, encoding: str, bufs: list[bytes], n: int,
         else:
             width = bufs[0][0]
             idx = unpack_width(bufs[0][1:], n, width).astype(np.int64)
-        if field_type == "string":
-            dict_n = (len(np.frombuffer(bufs[1], np.int64)) - 1)
-            uniq = _string_from_buffers(bufs[1:], dict_n)
-        else:
-            uniq = np.frombuffer(bufs[1], numpy_dtype)
-        return uniq[idx]
+        return dictionary(field_type, bufs, numpy_dtype)[idx]
     if encoding == DELTA:
         base = np.frombuffer(bufs[0], np.int64)
         out = np.empty(n, np.int64)

@@ -9,8 +9,9 @@ or ``decimal.Decimal`` constant converted, exactly, to its column's stored
 value (``schema.to_physical``); a query binds its predicate when it is
 built, so pruning, the wire and the kernels compare plain numbers.
 
-``field(a) * field(b)`` is a :class:`Product`, a measure that an
-aggregate sums (``repro.aformat.aggregate``), not a predicate.
+``field(a) * (1 - field(b))`` is a :class:`Measure`, a tree of columns,
+exact literals and ``+ - *`` that an aggregate sums
+(``repro.aformat.aggregate``), not a predicate.
 """
 
 from __future__ import annotations
@@ -441,35 +442,118 @@ class Not(Expr):
         return {"kind": "not", "expr": self.expr.to_json()}
 
 
-@dataclasses.dataclass(frozen=True)
-class Product:
-    """A measure: the row-wise product of two columns,
-    ``field(a) * field(b)``, which an aggregate sums exactly
-    (``repro.aformat.aggregate``)."""
+#: a measure's binary operators and their JSON kinds
+_MEASURE_KINDS = {"+": "add", "-": "sub", "*": "mul"}
 
-    lhs: str
-    rhs: str
+
+@dataclasses.dataclass(frozen=True)
+class Measure:
+    """A measure that an aggregate folds: a small exact tree of columns
+    (``op`` "field", ``args`` its name), exact literals (``op`` "lit",
+    ``args`` an ``int`` or ``decimal.Decimal``) and ``+``, ``-``, ``*``
+    of two measures.  Built with ``field``: ``field("p") * (1 -
+    field("d"))``.  Its type follows SQL's decimal rules, exactly
+    (``repro.aformat.aggregate.measure_type``): a sum or difference takes
+    the larger scale, a literal aligned to its operand's scale as ``Cmp``
+    binds its constants, and a product the sum of the scales."""
+
+    op: str
+    args: tuple
+
+    @staticmethod
+    def lit(value) -> "Measure":
+        if isinstance(value, bool) or not isinstance(
+                value, (int, np.integer, decimal.Decimal)):
+            raise TypeError(f"a measure is field(...)s and exact literals "
+                            f"(int, decimal.Decimal) under + - *, not "
+                            f"{value!r}")
+        if isinstance(value, decimal.Decimal) and not value.is_finite():
+            raise ValueError(f"literal {value} is not finite")
+        return Measure("lit", (value if isinstance(value, decimal.Decimal)
+                               else int(value),))
+
+    @staticmethod
+    def of(x) -> "Measure":
+        """``x`` as a measure: a measure, a ``field``, or a literal."""
+        if isinstance(x, Measure):
+            return x
+        if isinstance(x, _FieldRef):
+            return Measure("field", (x.name,))
+        return Measure.lit(x)
+
+    def _bin(self, op, lhs, rhs) -> "Measure":
+        return Measure(op, (Measure.of(lhs), Measure.of(rhs)))
+
+    def __add__(self, o):
+        return self._bin("+", self, o)
+
+    def __radd__(self, o):
+        return self._bin("+", o, self)
+
+    def __sub__(self, o):
+        return self._bin("-", self, o)
+
+    def __rsub__(self, o):
+        return self._bin("-", o, self)
+
+    def __mul__(self, o):
+        return self._bin("*", self, o)
+
+    def __rmul__(self, o):
+        return self._bin("*", o, self)
 
     @property
     def name(self) -> str:
-        return f"{self.lhs}*{self.rhs}"
+        """The measure as written: ``l_extendedprice*(1-l_discount)``."""
+        if self.op == "field":
+            return self.args[0]
+        if self.op == "lit":
+            return str(self.args[0])
+        lhs, rhs = self.args
+        a, b = lhs.name, rhs.name
+        # parenthesize a sum under a product, and one right of a minus
+        if self.op == "*" and lhs.op in "+-":
+            a = f"({a})"
+        if rhs.op in "+-" and self.op in "*-":
+            b = f"({b})"
+        return f"{a}{self.op}{b}"
 
     def columns(self) -> set[str]:
-        return {self.lhs, self.rhs}
+        if self.op == "field":
+            return {self.args[0]}
+        if self.op == "lit":
+            return set()
+        return self.args[0].columns() | self.args[1].columns()
 
     def to_json(self) -> dict:
-        return {"kind": "mul", "columns": [self.lhs, self.rhs]}
+        if self.op == "field":
+            return {"kind": "field", "name": self.args[0]}
+        if self.op == "lit":
+            return {"kind": "lit", "value": str(self.args[0])}
+        return {"kind": _MEASURE_KINDS[self.op],
+                "args": [a.to_json() for a in self.args]}
 
     @staticmethod
-    def from_json(d: dict) -> "Product":
-        if d.get("kind") != "mul":
+    def from_json(d: dict) -> "Measure":
+        kind = d.get("kind")
+        if kind == "field":
+            return Measure("field", (d["name"],))
+        if kind == "lit":
+            v = decimal.Decimal(d["value"])
+            return Measure.lit(int(v) if "." not in d["value"] else v)
+        ops = {k: op for op, k in _MEASURE_KINDS.items()}
+        if kind not in ops:
             raise ValueError(f"not a measure: {d!r}")
-        return Product(*d["columns"])
+        if "columns" in d:              # the two-column product's form
+            args = [Measure("field", (c,)) for c in d["columns"]]
+        else:
+            args = [Measure.from_json(a) for a in d["args"]]
+        return Measure(ops[kind], tuple(args))
 
 
 def field(name: str):
-    """field("x") > 3  -> Cmp(">", "x", 3); field("a") * field("b") ->
-    Product("a", "b")."""
+    """field("x") > 3  -> Cmp(">", "x", 3); field("a") * (1 - field("b"))
+    -> a :class:`Measure`."""
     return _FieldRef(name)
 
 
@@ -498,8 +582,21 @@ class _FieldRef:
     def isin(self, values):
         return IsIn(self.name, list(values))
 
+    # arithmetic builds a Measure
+    def __add__(self, other):
+        return Measure.of(self) + other
+
+    def __radd__(self, other):
+        return other + Measure.of(self)
+
+    def __sub__(self, other):
+        return Measure.of(self) - other
+
+    def __rsub__(self, other):
+        return other - Measure.of(self)
+
     def __mul__(self, other):
-        if not isinstance(other, _FieldRef):
-            raise TypeError("a measure multiplies two columns: "
-                            "field(a) * field(b)")
-        return Product(self.name, other.name)
+        return Measure.of(self) * other
+
+    def __rmul__(self, other):
+        return other * Measure.of(self)

@@ -268,13 +268,17 @@ def _n_data_buffers(field_type: str, encoding: str) -> int:
 
 def scan_row_group(src: RandomAccessSource, meta: FileMeta, rg: RowGroupMeta,
                    columns: Sequence[str] | None = None,
-                   predicate=None, backend=None) -> Table:
+                   predicate=None, backend=None, codes=()) -> Table:
     """Decode + filter + project one row group (the scan_op payload).
     ``backend`` picks the decode engine (None -> the NumPy host path;
     "pallas" routes DICT decode / predicate / selection through the
-    ``repro.kernels`` Pallas ops with per-column host fallback)."""
+    ``repro.kernels`` Pallas ops with per-column host fallback).
+    ``codes`` names the columns that may come back as dictionary codes
+    (``DecodeBackend.scan_row_group``); the backend is asked for them
+    only where there are any."""
+    extra = {"codes": tuple(codes)} if codes else {}
     return decode_mod.resolve_backend(backend).scan_row_group(
-        src, meta, rg, columns, predicate)
+        src, meta, rg, columns, predicate, **extra)
 
 
 def scan_file(src: RandomAccessSource, columns=None, predicate=None,

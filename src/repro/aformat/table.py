@@ -56,9 +56,15 @@ def _from_string_buffers(offsets: np.ndarray, payload: bytes) -> np.ndarray:
 
 @dataclasses.dataclass
 class Column:
+    """One column's values.  Where ``dictionary`` is set, ``values`` are
+    the int32 codes of a dictionary-encoded column, each row's value
+    being ``dictionary[code]``: an aggregate's group key travels so from
+    the decode plane to the fold, which reads a key once per group."""
+
     field: Field
     values: np.ndarray                    # object array for strings
     validity: np.ndarray | None = None    # bool mask; None = all valid
+    dictionary: np.ndarray | None = None
 
     def __post_init__(self):
         if self.field.type == "string":
@@ -79,7 +85,7 @@ class Column:
 
     def take(self, idx) -> "Column":
         v = None if self.validity is None else self.validity[idx]
-        return Column(self.field, self.values[idx], v)
+        return Column(self.field, self.values[idx], v, self.dictionary)
 
     def nbytes(self) -> int:
         if self.field.type == "string":
@@ -149,11 +155,7 @@ class Table:
         return Table(self.schema, [c.take(idx) for c in self.columns])
 
     def slice(self, start: int, length: int) -> "Table":
-        idx = slice(start, start + length)
-        return Table(self.schema, [Column(c.field, c.values[idx],
-                                          None if c.validity is None
-                                          else c.validity[idx])
-                                   for c in self.columns])
+        return self.take(slice(start, start + length))
 
     def head(self, n: int) -> "Table":
         """The first min(n, len) rows — the LIMIT row-budget slice; a

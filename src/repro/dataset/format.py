@@ -31,7 +31,8 @@ from typing import Any, Sequence
 from repro.aformat import decode as decode_mod
 from repro.aformat import parquet
 from repro.aformat.aggregate import (AggSpec, AggState, DEFAULT_MAX_GROUPS,
-                                     needed_columns, partial_aggregate)
+                                     key_names, needed_columns,
+                                     partial_aggregate)
 from repro.aformat.expressions import Expr
 from repro.aformat.table import Table
 from repro.dataset.fragment import Fragment
@@ -82,7 +83,7 @@ class FileFormat:
         raise NotImplementedError
 
     def aggregate_fragment(self, fs: CephFS, frag: Fragment,
-                           specs: Sequence[AggSpec], group_by: str | None,
+                           specs: Sequence[AggSpec], group_by,
                            predicate: Expr | None, *, schema,
                            max_groups: int = DEFAULT_MAX_GROUPS,
                            ctx: TaskContext) -> tuple[AggState, TaskRecord]:
@@ -155,8 +156,7 @@ def resolve_format(format: "FileFormat | str",
         f"{sorted(choices)} or a FileFormat instance")
 
 
-def is_degenerate_count(specs: Sequence[AggSpec],
-                        group_by: str | None) -> bool:
+def is_degenerate_count(specs: Sequence[AggSpec], group_by) -> bool:
     """Ungrouped bare COUNT(*): the case with the tiny ``rowcount_op``
     ``{"rows": n}`` wire contract (an integer, not a partial state)."""
     return (group_by is None and len(specs) == 1
@@ -173,16 +173,24 @@ def aggregate_client(fmt: FileFormat, fs: CephFS, frag: Fragment,
                      ctx: TaskContext) -> "tuple[AggState, TaskRecord]":
     """Client-side aggregation over any format's scan path: pull only the
     referenced columns through ``scan_fragment`` and fold them locally
-    (no cardinality bound — the client owns its memory).  The fold is the
+    (no cardinality bound — the client owns its memory).  A group key
+    that no aggregate reads may come back as its dictionary codes
+    (``TaskContext.codes``), and the format's decode backend, where it
+    has one, runs the fold (``DecodeBackend.fold``).  The fold is the
     host span ``repro.agg.fold``, on the task's thread."""
     cols = needed_columns(specs, group_by, schema, predicate)
+    measured = set().union(*(s.columns() for s in specs))
     # an aggregate folds the fragment's full matching rows — the scan
     # below must not inherit a row budget from the context
-    scan_ctx = dataclasses.replace(ctx, limit=None)
+    scan_ctx = dataclasses.replace(
+        ctx, limit=None,
+        codes=tuple(k for k in key_names(group_by) if k not in measured))
     tbl, rec = fmt.scan_fragment(fs, frag, cols, predicate, scan_ctx)
     t0 = time.perf_counter()
     with span("repro.agg.fold"):
-        state = partial_aggregate(tbl, specs, group_by)
+        state = partial_aggregate(
+            tbl, specs, group_by,
+            backend=getattr(fmt, "decode_backend", None))
     fold = time.perf_counter() - t0
     # the fold burns client CPU; it counts toward cpu_s only when the
     # record's `where` IS the client (a pushdown spill keeps its cpu_s as
@@ -242,7 +250,8 @@ class ParquetFormat(FileFormat):
                 meta = parquet.read_footer(src)
             rg = meta.row_groups[frag.client_rg_index]
             tbl = parquet.scan_row_group(src, meta, rg, columns, predicate,
-                                         backend=self.decode_backend)
+                                         backend=self.decode_backend,
+                                         codes=ctx.codes)
             if ctx.limit is not None:
                 # the raw chunk bytes already crossed the wire (client
                 # placement decodes whole chunks); the slice only trims
@@ -295,7 +304,7 @@ def scan_payload(frag: Fragment, columns, predicate,
 
 
 def agg_payload(frag: Fragment, specs: Sequence[AggSpec],
-                group_by: str | None, predicate: Expr | None,
+                group_by, predicate: Expr | None,
                 max_groups: int) -> dict[str, Any]:
     """The ``agg_op`` request for one fragment — shared by the static
     pushdown format and the adaptive scheduler (same wire-contract rule

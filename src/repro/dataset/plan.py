@@ -47,8 +47,10 @@ from repro.aformat.aggregate import (
     AggSpec,
     AggState,
     DEFAULT_MAX_GROUPS,
+    key_names,
     measure_type,
     needed_columns,
+    normalize_group_by,
     parse_aggs,
     partial_from_stats,
 )
@@ -111,7 +113,7 @@ class Project(PlanNode):
 class Aggregate(PlanNode):
     input: PlanNode
     specs: tuple[AggSpec, ...]
-    group_by: str | None = None
+    group_by: str | tuple[str, ...] | None = None
     max_groups: int = DEFAULT_MAX_GROUPS
 
     def children(self):
@@ -198,7 +200,8 @@ def render_plan(root: PlanNode) -> list[str]:
             return f"Project[{', '.join(n.columns)}]"
         if isinstance(n, Aggregate):
             aggs = ", ".join(s.name for s in n.specs)
-            by = f", group_by={n.group_by}" if n.group_by else ""
+            keys = ", ".join(key_names(n.group_by))
+            by = f", group_by={keys}" if keys else ""
             return f"Aggregate[{aggs}{by}]"
         if isinstance(n, Limit):
             return f"Limit[n={n.n}]"
@@ -408,7 +411,7 @@ def prune_fragments(
 def rewrite_metadata_aggregate(
     survivors: Sequence[tuple[Fragment, Expr | None]],
     specs: Sequence[AggSpec],
-    group_by: str | None,
+    group_by,
     schema,
 ) -> tuple[
     list[tuple[Fragment, Expr | None]], AggState, list[FragmentDecision]
@@ -492,7 +495,7 @@ class FragmentTask:
     columns: Sequence[str] | None = None
     predicate: Expr | None = None
     specs: Sequence[AggSpec] | None = None
-    group_by: str | None = None
+    group_by: str | tuple[str, ...] | None = None
     max_groups: int = DEFAULT_MAX_GROUPS
     schema: Any = None
     limit: int | None = None
@@ -514,7 +517,7 @@ class PhysicalPlan:
     passes: list[str]
     columns: list[str] | None = None  # scan output projection
     specs: list[AggSpec] | None = None
-    group_by: str | None = None
+    group_by: str | tuple[str, ...] | None = None
     max_groups: int = DEFAULT_MAX_GROUPS
     limit: int | None = None
     metadata_state: AggState | None = None
@@ -1316,16 +1319,22 @@ class Query:
         self,
         aggs,
         *,
-        group_by: str | None = None,
+        group_by=None,
         max_groups: int = DEFAULT_MAX_GROUPS,
     ) -> "Query":
-        """SUM/MIN/MAX/MEAN/COUNT, optionally GROUP BY one key column."""
+        """SUM/MIN/MAX/MEAN/COUNT, optionally GROUP BY one key column or a
+        sequence of them (``group_by=["a", "b"]``): one result row per key
+        tuple, in key order, the key columns first.  An aggregate's column
+        is a column name or a measure (``field("p") * (1 - field("d"))``).
+        ``max_groups`` bounds the key tuples a storage node folds before
+        it spills to a scan."""
         self._require_relational("aggregate()")
         self._require_no_join("aggregate()")
         self._require_unlimited("aggregate()")
         specs = parse_aggs(aggs)
         if not specs:
             raise ValueError("aggregate() needs at least one aggregate")
+        group_by = normalize_group_by(group_by)
         refs_columns = group_by is not None or any(
             s.column is not None for s in specs
         )
@@ -1337,8 +1346,8 @@ class Query:
         for s in specs:
             if s.column is not None:
                 measure_type(s.column, self.ds.schema)  # validate early
-        if group_by is not None:
-            self.ds.schema.field(group_by)
+        for name in key_names(group_by):
+            self.ds.schema.field(name)
         return self._derive(
             Aggregate(self._root, tuple(specs), group_by, max_groups)
         )

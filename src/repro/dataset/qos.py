@@ -48,8 +48,9 @@ class TaskContext:
     """Everything a fragment task runs *as*: identity (tenant, lane,
     weight), obligations (deadline, shed policy), and the per-task
     options the executor threads through (admission controller, live row
-    budget, selectivity hint).  ``TaskContext()`` is the default tenant
-    and reproduces the historic single-tenant behavior exactly."""
+    budget, selectivity hint, columns a scan may return as codes).
+    ``TaskContext()`` is the default tenant and reproduces the historic
+    single-tenant behavior exactly."""
 
     tenant: str = "default"
     lane: str = DEFAULT_LANE
@@ -59,6 +60,9 @@ class TaskContext:
     admission: AdmissionController | None = None
     limit: int | None = None
     selectivity_hint: float | None = None
+    #: columns a client scan may return as their dictionary codes (an
+    #: aggregate's group keys, which its fold reads once per group)
+    codes: tuple[str, ...] = ()
     registry: "TenantRegistry | None" = None
     started_at: float | None = None      # perf_counter at execution start
 

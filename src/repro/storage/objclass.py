@@ -22,8 +22,8 @@ from collections import OrderedDict
 
 from repro.aformat import parquet
 from repro.aformat.aggregate import (AggSpec, AggState, CardinalityError,
-                                     needed_columns, partial_aggregate,
-                                     partial_from_stats)
+                                     needed_columns, normalize_group_by,
+                                     partial_aggregate, partial_from_stats)
 from repro.aformat.expressions import Expr, NONE
 from repro.aformat.table import Table
 from repro.storage.objstore import ObjectStore, ObjectHandle
@@ -123,7 +123,7 @@ def stat_op(obj: ObjectHandle, payload: dict) -> bytes:
 
 
 def _run_agg(obj: ObjectHandle, meta: parquet.FileMeta,
-             specs: list[AggSpec], group_by: str | None, pred,
+             specs: list[AggSpec], group_by, pred,
              metas, max_groups: int | None) -> AggState:
     """The shared storage-side aggregation kernel: per row group, answer
     from footer stats where provable (ungrouped + no predicate), else
@@ -156,7 +156,8 @@ def agg_op(obj: ObjectHandle, payload: dict) -> bytes:
     columns, filter, fold into an AggState, ship back the compact
     serialized partial state (the client merges states across objects).
 
-    payload: {"aggs": [AggSpec json...], "group_by": str|None,
+    payload: {"aggs": [AggSpec json...],
+              "group_by": str|[str, ...]|None (one key or a key tuple),
               "predicate": expr-json|None, "row_groups": [...]|None,
               "footer": serialized FileMeta|None,
               "max_groups": int|None (group-cardinality bound)}
@@ -168,7 +169,7 @@ def agg_op(obj: ObjectHandle, payload: dict) -> bytes:
     COUNT(*) case of this method."""
     meta = _payload_footer(obj, payload)
     specs = [AggSpec.from_json(s) for s in payload["aggs"]]
-    group_by = payload.get("group_by")
+    group_by = normalize_group_by(payload.get("group_by"))
     pred = Expr.from_json(payload.get("predicate"))
     row_groups = payload.get("row_groups")
     metas = (meta.row_groups if row_groups is None

@@ -502,3 +502,124 @@ def test_prompt_lengths_ships_counts_not_tokens():
     # counts on the wire, not token columns
     token_bytes = tbl.select(["token"]).nbytes()
     assert sum(t.wire_bytes for t in metrics.tasks) < token_bytes
+
+
+# ---------------------------------------------------------------------------
+# group-by on several keys
+# ---------------------------------------------------------------------------
+
+
+def _two_key_table(n=600, seed=5):
+    rng = np.random.default_rng(seed)
+    return Table.from_pydict({
+        "s": np.asarray(["x", "b", "m"], object)[rng.integers(0, 3, n)],
+        "k": rng.integers(0, 4, n).astype(np.int32),
+        "v": rng.integers(-1000, 1000, n).astype(np.int64),
+    })
+
+
+def _numpy_groups(tbl, names):
+    rows = {}
+    for i in range(len(tbl)):
+        key = tuple(tbl.column(n).values[i] for n in names)
+        rows.setdefault(key, []).append(int(tbl.column("v").values[i]))
+    return {k: (len(v), sum(v)) for k, v in sorted(rows.items())}
+
+
+@pytest.mark.parametrize("names", [("s", "k"), ("k", "s")],
+                         ids=["string_then_int", "int_then_string"])
+def test_two_keys_group_in_key_order(names):
+    """Keys in a column order that is not the schema's: the result's key
+    columns come out in key order, the rows sorted by the key tuple."""
+    tbl = _two_key_table()
+    specs = parse_aggs(["count", ("sum", "v")])
+    out = partial_aggregate(tbl, specs, list(names)).finalize(tbl.schema)
+    assert out.schema.names[:2] == list(names)
+    want = _numpy_groups(tbl, names)
+    got = {tuple(out.column(n).values[i] for n in names):
+           (out.column("count").values[i], out.column("sum_v").values[i])
+           for i in range(len(out))}
+    assert got == want
+    keys = [tuple(out.column(n).values[i] for n in names)
+            for i in range(len(out))]
+    assert keys == sorted(keys) == list(want)
+
+
+def test_max_groups_counts_key_tuples():
+    tbl = _two_key_table()
+    specs = parse_aggs(["count"])
+    # 3 strings and 4 ints: 12 tuples, though no key alone passes 4
+    assert partial_aggregate(tbl, specs, ["s", "k"]).num_groups == 12
+    with pytest.raises(CardinalityError):
+        partial_aggregate(tbl, specs, ["s", "k"], max_groups=11)
+    assert partial_aggregate(tbl, specs, ["s", "k"],
+                             max_groups=12).num_groups == 12
+
+
+def test_two_key_state_serializes_and_merges():
+    tbl = _two_key_table()
+    specs = parse_aggs(["count", ("sum", "v"), ("mean", "v"), ("min", "v")])
+    parts = [partial_aggregate(tbl.slice(i, 200), specs, ("s", "k"))
+             for i in (0, 200, 400)]
+    merged = AggState.empty(specs, ["s", "k"])
+    for p in parts:
+        merged.merge(AggState.deserialize(p.serialize()))
+    whole = partial_aggregate(tbl, specs, ["s", "k"])
+    assert merged.groups == whole.groups
+    assert all(isinstance(k, tuple) for k in merged.groups)
+    assert json.loads(whole.serialize())["group_by"] == ["s", "k"]
+    assert merged.finalize(tbl.schema).equals(whole.finalize(tbl.schema))
+    with pytest.raises(ValueError):
+        merged.merge(partial_aggregate(tbl, specs, "s"))
+
+
+def test_a_single_key_is_a_name_or_a_sequence_of_one():
+    tbl = _two_key_table()
+    specs = parse_aggs(["count", ("sum", "v")])
+    one = partial_aggregate(tbl, specs, "s")
+    assert one.group_by == "s" and set(one.groups) == {"b", "m", "x"}
+    seq = partial_aggregate(tbl, specs, ["s"])
+    assert seq.group_by == "s" and seq.groups == one.groups
+    with pytest.raises(ValueError):
+        partial_aggregate(tbl, specs, ["s", "s"])
+
+
+def test_a_key_as_dictionary_codes_groups_as_its_values():
+    """A key column of codes into its dictionary folds to the groups its
+    decoded values give, a dictionary that repeats a value included."""
+    tbl = _two_key_table()
+    strings = tbl.column("s").values
+    dictionary = np.asarray(["b", "m", "x", "m"], object)
+    codes = np.searchsorted(["b", "m", "x"], strings.astype(str))
+    codes[::7] = np.where(codes[::7] == 1, 3, codes[::7])
+    key = Column(Field("s", "int32"), codes.astype(np.int32), None,
+                 dictionary)
+    coded = Table(Schema((key.field, tbl.schema.field("k"),
+                          tbl.schema.field("v"))),
+                  [key, tbl.column("k"), tbl.column("v")])
+    specs = parse_aggs(["count", ("sum", "v")])
+    for keys in ("s", ["s", "k"]):
+        assert partial_aggregate(coded, specs, keys).groups == \
+            partial_aggregate(tbl, specs, keys).groups
+
+
+def test_query_groups_on_two_keys_at_every_placement(populated):
+    fs, tbl, _ = populated
+    ds = dataset(fs, "/d")
+    names = ["payment_type", "passenger_count"]
+    out = {fmt: ds.query(format=fmt).aggregate(
+        ["count", ("sum", "trip_id")], group_by=names).to_table()
+        for fmt in FORMATS}
+    want = {}
+    for i in range(len(tbl)):
+        key = tuple(tbl.column(n).values[i] for n in names)
+        c, s = want.get(key, (0, 0))
+        want[key] = (c + 1, s + int(tbl.column("trip_id").values[i]))
+    for fmt, res in out.items():
+        assert res.schema.names[:2] == names, fmt
+        got = {tuple(res.column(n).values[i] for n in names):
+               (res.column("count").values[i],
+                res.column("sum_trip_id").values[i])
+               for i in range(len(res))}
+        assert got == dict(sorted(want.items())), fmt
+        assert res.equals(out["parquet"]), fmt

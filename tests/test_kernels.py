@@ -1,5 +1,7 @@
 """Per-kernel shape/dtype sweeps: pallas (interpret) vs pure-jnp oracle."""
 
+import math
+
 import numpy as np
 import pytest
 
@@ -14,6 +16,9 @@ import jax.numpy as jnp
 
 from repro.kernels.dict_decode import ops as dd_ops
 from repro.kernels.dict_decode.ref import dict_decode_ref
+from repro.kernels.group_fold import group_fold as gf
+from repro.kernels.group_fold import ops as gf_ops
+from repro.kernels.group_fold.ref import fold_groups_ref
 from repro.kernels.predicate_fused import ops as pf_ops
 from repro.kernels.predicate_fused.predicate_fused import Program, Term
 from repro.kernels.predicate_fused.ref import predicate_mask_ref
@@ -214,3 +219,73 @@ def test_pack_preserves_order():
     kept = np.asarray(got)[: int(cnt)]
     assert np.array_equal(kept, vals[mask][:1024])
     assert (np.diff(kept) > 0).all()
+
+
+# ---------------------------------------------------------------------------
+# group_fold
+# ---------------------------------------------------------------------------
+
+F32_MAX_INT = 2 ** 24 - 1     # the largest f32-exact integer
+
+
+def _fold_case(n, sizes, seed=0):
+    rng = np.random.default_rng(seed)
+    keys = [rng.integers(0, s, n) for s in sizes]
+    factors = [rng.integers(-F32_MAX_INT, F32_MAX_INT + 1, n),
+               rng.integers(0, 101, n), rng.integers(-9, 10, n)]
+    return keys, factors
+
+
+FOLD_TERMS = [(0,), (1,), (2,), (0, 2), (1, 2), (0, 1)]
+
+
+def _assert_fold(keys, factors, sizes, terms):
+    got = gf_ops.fold_groups(keys, factors, sizes=sizes, terms=terms)
+    want = fold_groups_ref(keys, factors, sizes=sizes, terms=terms)
+    for g, w in zip(got, want):
+        assert g.dtype == np.int64 and np.array_equal(g, w)
+
+
+@pytest.mark.parametrize("n", [1, 2047, 2049, 5000])
+def test_group_fold_any_row_count(n):
+    """N not a multiple of the tile: the padding matches no group."""
+    keys, factors = _fold_case(n, (3, 2), seed=n)
+    _assert_fold(keys, factors, (3, 2), FOLD_TERMS)
+
+
+@pytest.mark.parametrize("sizes", [(), (1,), (16, 16)],
+                         ids=["ungrouped", "one_group", "the_bound"])
+def test_group_fold_one_group_and_the_bound(sizes):
+    assert math.prod(sizes) in (1, gf.MAX_GROUPS)
+    keys, factors = _fold_case(3000, sizes, seed=7)
+    _assert_fold(keys, factors, sizes, FOLD_TERMS)
+
+
+def test_group_fold_factors_at_the_largest_exact_value():
+    """Every factor at +-(2**24 - 1), every row in one group: each limb
+    of every tile is at its largest, and the sums are still exact."""
+    n = 3 * gf.TILE + 5
+    keys = [np.full(n, 1)]
+    factors = [np.full(n, F32_MAX_INT), np.full(n, -F32_MAX_INT),
+               np.full(n, 127)]
+    _assert_fold(keys, factors, (2,), [(0,), (1,), (0, 2), (1, 2)])
+    sums, counts = gf_ops.fold_groups(keys, factors, sizes=(2,),
+                                      terms=[(1, 2)])
+    assert sums.tolist() == [[0, -n * F32_MAX_INT * 127]]
+    assert counts.tolist() == [0, n]
+
+
+def test_group_fold_empty_group_sums_to_zero():
+    keys, factors = _fold_case(4000, (3, 2), seed=3)
+    keys[0][keys[0] == 1] = 2              # no row in groups 2 and 3
+    sums, counts = gf_ops.fold_groups(keys, factors, sizes=(3, 2),
+                                      terms=FOLD_TERMS)
+    assert counts[2] == counts[3] == 0 and not sums[:, 2:4].any()
+    _assert_fold(keys, factors, (3, 2), FOLD_TERMS)
+
+
+def test_group_fold_refuses_what_it_cannot_hold():
+    keys, factors = _fold_case(10, (3, 2))
+    with pytest.raises(ValueError):
+        gf_ops.fold_groups([np.zeros(10)] * 2, factors, sizes=(17, 16),
+                           terms=[(0,)])

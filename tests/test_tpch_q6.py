@@ -22,8 +22,7 @@ from repro.aformat import encodings, parquet  # noqa: E402
 from repro.aformat.aggregate import (AggSpec, AggState,  # noqa: E402
                                      partial_aggregate, result_fields)
 from repro.aformat.decode import PallasBackend  # noqa: E402
-from repro.aformat.expressions import (ALL, SOME, Expr,  # noqa: E402
-                                       Product, field)
+from repro.aformat.expressions import ALL, SOME, Expr, field  # noqa: E402
 from repro.aformat.schema import (Field, Schema, decimal64,  # noqa: E402
                                   schema, to_physical)
 from repro.aformat.statistics import ColumnStats  # noqa: E402
@@ -208,19 +207,20 @@ def test_stats_prune_a_date_range():
 
 def test_the_product_measure_is_named_and_travels():
     m = field("l_extendedprice") * field("l_discount")
-    assert m == Product("l_extendedprice", "l_discount")
+    assert m.columns() == {"l_extendedprice", "l_discount"}
     spec = AggSpec("sum", m)
     assert spec.name == reference.NAME
     assert AggSpec.from_json(spec.to_json()) == spec
+    # a literal is exact: an int or a decimal, never a float
     with pytest.raises(TypeError):
-        field("a") * 2
+        field("a") * 2.5
 
 
 def test_result_types_follow_the_decimals():
     sch = schema(("p", DEC), ("d", DEC), ("day", "date32"), ("n", "int32"))
-    specs = [AggSpec("sum", Product("p", "d")), AggSpec("sum", "p"),
+    specs = [AggSpec("sum", field("p") * field("d")), AggSpec("sum", "p"),
              AggSpec("max", "p"), AggSpec("min", "day"),
-             AggSpec("mean", "p"), AggSpec("sum", Product("p", "n"))]
+             AggSpec("mean", "p"), AggSpec("sum", field("p") * field("n"))]
     assert [f.type for f in result_fields(specs, None, sch)] == [
         "decimal64(18,4)", "decimal64(18,2)", DEC, "date32", "float64",
         "decimal64(18,2)"]
@@ -245,7 +245,7 @@ def test_the_sum_of_the_product_is_exact():
     tbl = Table.from_pydict({"a": a, "b": b,
                              "g": rng.integers(0, 3, n).astype(np.int32)},
                             sch)
-    spec = [AggSpec("sum", Product("a", "b"))]
+    spec = [AggSpec("sum", field("a") * field("b"))]
     want = sum(int(x) * int(y) for x, y in zip(a, b))
     assert want != int(np.sum(a.astype(np.float64) * b))
     out = partial_aggregate(tbl, spec).finalize(sch).columns[0]
@@ -266,11 +266,11 @@ def test_an_overflow_raises():
     big = Table.from_pydict({"a": np.array([10**10]),
                              "b": np.array([10**10])}, sch)
     with pytest.raises(OverflowError):
-        partial_aggregate(big, [AggSpec("sum", Product("a", "b"))])
+        partial_aggregate(big, [AggSpec("sum", field("a") * field("b"))])
     # products that fit, summed past 18 digits
     many = Table.from_pydict({"a": np.full(200, 10**8),
                               "b": np.full(200, 10**8)}, sch)
-    state = partial_aggregate(many, [AggSpec("sum", Product("a", "b"))])
+    state = partial_aggregate(many, [AggSpec("sum", field("a") * field("b"))])
     assert state.cells == [200 * 10**16]
     with pytest.raises(OverflowError):
         state.finalize(sch)

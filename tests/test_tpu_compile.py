@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.kernels.dict_decode.dict_decode import (ONEHOT_MAX, dict_decode,
                                                    padded_dict_size)
 from repro.kernels.dict_decode.dict_decode import TILE as DD_TILE
+from repro.kernels.group_fold import group_fold as gf
 from repro.kernels.predicate_fused.predicate_fused import (Program, Term,
                                                            predicate_mask)
 from repro.kernels.predicate_fused.predicate_fused import TILE as PF_TILE
@@ -101,3 +102,19 @@ def test_pack_tokens_merge_has_no_loop(one_chip):
                              interpret=False).compile().as_text()
     assert "tpu_custom_call" in hlo
     assert not re.search(r"\swhile\(", hlo)
+
+
+@pytest.mark.parametrize("sizes,terms", [
+    ((3, 2), ((0,), (1,), (2,), (1, 2), (1, 3), (1, 2, 3))),
+    ((), ((0, 1),)),
+    ((16, 16), ((0,),)),
+], ids=["q1", "q6_ungrouped", "most_groups"])
+def test_group_fold_compiles(one_chip, sizes, terms):
+    # a row group's kept rows, padded to the next power of two
+    width = 1 << (ROWS - 1).bit_length()
+    rows = len(sizes) + 1 + max(max(t) for t in terms)
+    hlo = gf.group_fold.lower(_struct((rows, width), jnp.int32, one_chip),
+                              _struct((), jnp.int32, one_chip),
+                              nkeys=len(sizes), sizes=sizes, terms=terms,
+                              interpret=False).compile().as_text()
+    assert "tpu_custom_call" in hlo
